@@ -1,7 +1,7 @@
 # Developer entry points (the reference drives everything through
 # per-component Makefiles; here one root Makefile covers the repo).
 
-.PHONY: test test-slow test-all e2e smoke conformance bench bench-gate dryrun native verify-all obs-check profile-check serving-check fleet-check kernels-check tenancy-check chaos-check train-check train-obs-check disagg-check cache-check cache-tier-check control-check rollout-check scenario-check
+.PHONY: test test-slow test-all e2e smoke conformance bench bench-gate chip-smoke dryrun native verify-all obs-check profile-check serving-check fleet-check kernels-check tenancy-check chaos-check train-check train-obs-check disagg-check cache-check cache-tier-check control-check rollout-check scenario-check
 
 verify-all:  ## the full evidence sweep, one command
 	python -m pytest tests -q -m "slow or not slow"
@@ -119,8 +119,11 @@ tenancy-check: ## multi-tenant QoS gate: unit suite + noisy-neighbor A/B loadtes
 	JAX_PLATFORMS=cpu python loadtest/serving_loadtest.py --mode tenants \
 	  --tenant-bulk-clients 8 --tenant-live-requests 6
 
-bench:       ## perf sweep on the local device (CPU falls back safely)
+bench:       ## perf sweep on the device JAX attaches (one process; no fallback)
 	python bench.py
+
+chip-smoke:  ## TPU host only: kernels + serve + train at llama3-1b widths
+	python chip_smoke.py
 
 bench-gate:  ## perf sweep + regression compare vs ci/bench_baseline.json
 	python bench.py --json-out /tmp/bench_run.json

@@ -80,14 +80,16 @@ COMPONENTS: dict[str, dict[str, Any]] = {
                   "loadtest/serving_loadtest.py"],
         "tests": "python -m pytest tests/test_fleet.py -q",
     },
-    # The driver evidence pipeline (bench.py + __graft_entry__) runs its
-    # FULL tier including the slow subprocess armoring tests: these are
-    # the round-3-postmortem regression guards (wedged-TPU fallback,
-    # backend-free dryrun parent) and must execute somewhere on every
-    # change to those files, not just sit behind the opt-in marker.
+    # The driver entry points (bench.py, __graft_entry__, chip_smoke)
+    # run their FULL tier including the slow subprocess tests (the
+    # backend-free dryrun parent): these must execute somewhere on
+    # every change to those files, not just sit behind the opt-in
+    # marker. What only a chip can show is chip_smoke.py's to prove.
     "driver": {
-        "paths": ["bench.py", "__graft_entry__.py"],
+        "paths": ["bench.py", "__graft_entry__.py", "chip_smoke.py",
+                  "tools/smoke_*.py", "kubeflow_tpu/compile_cache.py"],
         "tests": ("python -m pytest tests/test_driver_armor.py "
+                  "tests/test_chip_contract.py "
                   "-q -m \"slow or not slow\""),
     },
 }

@@ -1,0 +1,42 @@
+"""Where JAX's persistent compilation cache lives.
+
+Every entry point that touches JAX calls `enable()` first thing, so a
+second start of the same program finds what the first one compiled
+(server boot on the tiny CPU model alone is 60-90 s of compiles). The
+directory is part of what a cached program is found by, so it never
+carries a temporary name, a pid or a time: it is what the environment
+says, or one fixed place inside the checkout.
+
+This module imports JAX only inside `enable()`: `chip_smoke.py`'s parent
+reads `cache_dir()` to count entries and must stay off JAX.
+"""
+
+from __future__ import annotations
+
+import os
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def cache_dir() -> str:
+    """`JAX_COMPILATION_CACHE_DIR` where set (JAX reads it itself),
+    otherwise `<checkout>/.jax_cache`, found from this package's own
+    location — the same answer from any working directory."""
+    return os.environ.get(ENV_VAR) or os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def enable() -> str:
+    """Turn the persistent cache on and return its directory. Where the
+    environment names the directory it also owns the policy and this
+    sets nothing. Otherwise every program is kept, however fast it
+    compiled: at JAX's default 1 s floor a program near the floor is
+    written by one run and not the next, and a warm start would still
+    add entries."""
+    path = cache_dir()
+    if not os.environ.get(ENV_VAR):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path

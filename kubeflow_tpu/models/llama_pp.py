@@ -25,7 +25,6 @@ from kubeflow_tpu.models import llama
 from kubeflow_tpu.models.llama import LlamaConfig, Params
 from kubeflow_tpu.ops.norms import rms_norm
 from kubeflow_tpu.ops.rotary import rope_frequencies
-from kubeflow_tpu.parallel import mesh as mesh_lib
 from kubeflow_tpu.parallel import pipeline as pp
 from kubeflow_tpu.train import trainer as trainer_lib
 
@@ -206,11 +205,11 @@ class PipelineTrainer:
         )
 
     def init(self, rng: jax.Array) -> trainer_lib.TrainState:
-        with mesh_lib.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self._jit_init(rng)
 
     def step(self, state: trainer_lib.TrainState, tokens, targets):
-        with mesh_lib.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self._jit_step(state, tokens, targets)
 
 

@@ -3,8 +3,10 @@
 Design: one public `dot_product_attention` that dispatches by backend.
 - CPU / debugging: pure-XLA grouped-query attention with fp32 logits.
 - TPU: Pallas flash attention kernel (kubeflow_tpu.ops.pallas.flash_attention)
-  for long sequences; falls back to XLA for short ones (XLA's fused
-  attention is already good below ~1k tokens).
+  for long sequences, XLA for short ones (XLA's fused attention is
+  already good below ~1k tokens). Every choice "auto" makes is a rule on
+  the platform and the call's shapes, written here; nothing is chosen by
+  catching an error.
 
 The XLA path never materializes repeated KV heads: queries are reshaped to
 [batch, q_per_kv, kv_heads, ...] and contracted against the kv heads
@@ -20,8 +22,8 @@ NEG_INF = -2.0**30  # large-but-finite: avoids NaNs from (-inf) - (-inf)
 
 # Trace-time dispatch counters. `dot_product_attention` runs in Python at
 # trace time, so these count how many traced call sites took each impl —
-# which is how bench.py *proves* the long-seq preset routed through the
-# Pallas flash kernel instead of silently falling back to XLA.
+# which is how bench.py and chip_smoke.py *prove* which kernel a step
+# was traced with.
 _impl_counts = {"flash": 0, "xla": 0, "decode": 0, "paged": 0,
                 "paged_xla": 0, "paged_pallas": 0, "paged_prefill": 0,
                 "paged_prefill_xla": 0, "paged_prefill_pallas": 0}
@@ -75,74 +77,43 @@ def _xla_attention(
     return out.reshape(b, sq, n_q, hd).astype(q.dtype)
 
 
-def _flash_kernel_available() -> bool:
-    try:
-        from kubeflow_tpu.ops.pallas import flash_attention  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
-def _decode_kernel_available() -> bool:
-    try:
-        from kubeflow_tpu.ops.pallas import decode_attention  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
-def _paged_kernel_available() -> bool:
-    try:
-        from kubeflow_tpu.ops.pallas import paged_attention  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
-def _prefill_append_kernel_available() -> bool:
-    try:
-        from kubeflow_tpu.ops.pallas import prefill_append  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
-def resolve_paged_prefill_impl(impl: str) -> str:
-    """Resolve a `paged_prefill_attention` impl request to "xla" or
-    "pallas" — same policy as `resolve_paged_attention_impl`: "auto" is
-    the fused Pallas kernel on TPU when it imports, the XLA
-    scatter+gather everywhere else (CPU runs the kernel only in
-    interpret mode, the numerics/test vehicle)."""
+def _check_impl(impl: str, what: str) -> None:
     if impl not in ("auto", "xla", "pallas"):
         raise ValueError(
-            f"paged prefill impl must be 'auto', 'xla' or 'pallas', "
-            f"got {impl!r}")
-    if impl == "auto":
-        if (jax.default_backend() == "tpu"
-                and _prefill_append_kernel_available()):
-            return "pallas"
-        return "xla"
-    return impl
+            f"{what} impl must be 'auto', 'xla' or 'pallas', got {impl!r}")
+
+
+def resolve_paged_prefill_impl(impl: str, *, vmem_bytes: int = 0) -> str:
+    """Resolve a `paged_prefill_attention` impl request to "xla" or
+    "pallas". "auto" is a rule on platform and shape, nothing else: the
+    fused kernel on TPU while the VMEM it needs for the call's shapes
+    (`prefill_append.vmem_bytes`, which grows with the chunk's
+    `s * n_q` query rows) fits `VMEM_BUDGET_BYTES`; the XLA
+    scatter+gather for longer chunks and on every other backend.
+    Without `vmem_bytes` only the platform is judged — what an engine
+    can say before it has seen a chunk."""
+    _check_impl(impl, "paged prefill")
+    if impl != "auto":
+        return impl
+    from kubeflow_tpu.ops.pallas.prefill_append import VMEM_BUDGET_BYTES
+
+    if jax.default_backend() == "tpu" and vmem_bytes <= VMEM_BUDGET_BYTES:
+        return "pallas"
+    return "xla"
 
 
 def resolve_paged_attention_impl(impl: str) -> str:
     """Resolve a `paged_attention` impl request to "xla" or "pallas".
 
-    "auto" picks the fused Pallas kernel on TPU when present (falling
-    back to the gather if the kernel fails to import), the XLA gather
-    everywhere else — CPU runs the kernel only in interpret mode, which
-    is a numerics/test vehicle, not a fast path. Resolving once at
-    engine construction (rather than per trace) is what lets serving
-    label its metrics with the impl that actually runs.
+    "auto" is the fused Pallas kernel on TPU and the XLA gather on
+    every other backend (there the kernel runs only where a test asks
+    for interpret mode). Resolving once at engine construction (rather
+    than per trace) is what lets serving label its metrics with the
+    impl that actually runs.
     """
-    if impl not in ("auto", "xla", "pallas"):
-        raise ValueError(
-            f"paged attention impl must be 'auto', 'xla' or 'pallas', "
-            f"got {impl!r}")
+    _check_impl(impl, "paged attention")
     if impl == "auto":
-        if jax.default_backend() == "tpu" and _paged_kernel_available():
-            return "pallas"
-        return "xla"
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
 
 
@@ -164,8 +135,8 @@ def dot_product_attention(
     supported by both impls, position-based in XLA, index-based in flash.
 
     impl: "auto" | "xla" | "flash" | "decode". "auto" picks, on TPU:
-    the Pallas flash kernel for long sequences when safe (kernel
-    present, no kv_mask, positions declared contiguous), or the fused
+    the Pallas flash kernel for long sequences when safe (no kv_mask,
+    positions declared contiguous), or the fused
     decode kernel for single-token causal steps against a >=256-cell
     cache (again only with `contiguous_positions=True` — it masks by
     cache cell index against each row's cursor). Packed sequences with
@@ -193,10 +164,9 @@ def dot_product_attention(
         decode_step = (q.shape[1] == 1 and k.shape[1] >= 256
                        and causal and contiguous_positions)
         if (on_tpu and long_seq and same_len and causal
-                and kv_mask is None and contiguous_positions
-                and _flash_kernel_available()):
+                and kv_mask is None and contiguous_positions):
             impl = "flash"
-        elif on_tpu and decode_step and _decode_kernel_available():
+        elif on_tpu and decode_step:
             impl = "decode"
         else:
             impl = "xla"
@@ -273,9 +243,10 @@ def paged_attention(
       fill instead of the full window. Causal-only (it masks by cell
       index against the cursor, so it also requires the pool's
       cell-index == token-position invariant, which insert-time
-      compaction guarantees). `interpret` forces Pallas interpret mode
-      (default: on for non-TPU backends) — the CPU test vehicle.
-    - "auto": pallas on TPU when the kernel imports, xla otherwise.
+      compaction guarantees). `interpret=True` runs the kernel in
+      Pallas interpret mode — the tests' CPU vehicle; without it a
+      non-TPU backend is an error.
+    - "auto": pallas on TPU, xla on every other backend.
 
     The two impls agree to fp32 tolerance (online-softmax merge vs
     single-pass softmax); tests/test_paged_attention_kernel.py pins
@@ -326,11 +297,12 @@ def paged_attention(
             kv_mask, window=window, interpret=interpret)
     k = k_pool[block_table].reshape(b, width, n_kv, hd)
     v = v_pool[block_table].reshape(b, width, n_kv, hd)
-    # Cell index == logical token position by construction (insert-time
-    # compaction strips prefill padding), so positions are contiguous.
+    # impl="xla" said explicitly: "auto" would hand this single-token
+    # step to the Pallas decode kernel on TPU, and "xla" must mean XLA
+    # on every platform (it is the kernels' oracle).
     return dot_product_attention(
         q, k, v, q_positions, kv_positions, causal=causal,
-        kv_mask=kv_mask, window=window, contiguous_positions=True,
+        kv_mask=kv_mask, window=window, impl="xla",
     )
 
 
@@ -371,9 +343,9 @@ def paged_prefill_attention(
       the new tokens into each live block in-register, writes the pool
       in place (input_output_aliases) and attends in the same pass —
       one read+write of `ceil((q_start+s)/block_size)` blocks per row.
-      Causal-only. `interpret` forces interpret mode (default: on for
-      non-TPU backends) — the CPU test vehicle.
-    - "auto": pallas on TPU when the kernel imports, xla otherwise.
+      Causal-only. `interpret`: as for `paged_attention`.
+    - "auto": pallas on TPU while the chunk's VMEM need fits the
+      kernel's budget (`resolve_paged_prefill_impl`), xla otherwise.
     """
     b, s, n_q, hd = q.shape
     n_kv = k_pool.shape[2]
@@ -395,14 +367,16 @@ def paged_prefill_attention(
             f"kv_mask shape {kv_mask.shape} does not match "
             f"blocks_per_slot * block_size = {blocks_per_slot} * "
             f"{block_size} = {width}")
-    impl = resolve_paged_prefill_impl(impl)
+    from kubeflow_tpu.ops.pallas.prefill_append import (
+        paged_prefill_append,
+        vmem_bytes,
+    )
+
+    impl = resolve_paged_prefill_impl(impl, vmem_bytes=vmem_bytes(
+        s, n_q, n_kv, hd, block_size, q.dtype.itemsize))
     _impl_counts["paged_prefill"] += 1
     _impl_counts["paged_prefill_" + impl] += 1
     if impl == "pallas":
-        from kubeflow_tpu.ops.pallas.prefill_append import (
-            paged_prefill_append,
-        )
-
         return paged_prefill_append(
             q, k_new, v_new, k_pool, v_pool, block_table,
             q_start, q_lens, kv_mask, window=window,

@@ -13,13 +13,19 @@ tail through HBM every token, and decode MBU is the whole game
   FILL, not max_len;
 - GQA stays at KV resolution in memory (queries reshape to
   [n_kv, group] inside the kernel; the cache never repeats);
-- per-cell validity (the engines' left-pad holes) rides in as a mask
-  block; causality and sliding windows mask by absolute cell index
-  against the prefetched cursor.
+- per-cell validity (the engines' left-pad holes) rides in as an int32
+  mask laid out `[b, kv blocks, 1, block_k]` (see paged_attention.py
+  for why not a bool `(1, block_k)` block); causality and sliding
+  windows mask by absolute cell index against the prefetched cursor.
 
 Numerics match ops.attention._xla_attention exactly in structure:
 fp32 logits, one softmax over the visible set (single-pass here — the
 online-softmax merge is algebraically the same sum).
+
+A row whose visible set is empty (pad holes over its whole causal
+prefix) comes out as exact zeros; the XLA path gives such a row the mean
+of V. Only host-masked filler rows are ever in that state, and
+tools/smoke_kernels.py pins the convention on the chip.
 
 Reference parity: the reference has no attention code (SURVEY.md §2b);
 this is the serving-side sibling of flash_attention.py, pinned against
@@ -37,8 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kubeflow_tpu.ops.attention import NEG_INF
 from kubeflow_tpu.ops.pallas.flash_attention import (
-    _interpret_default,
     _pick_block,
+    resolve_interpret,
 )
 
 DEFAULT_BLOCK_K = 256
@@ -81,7 +87,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
 
         idx = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (n_q, block_k), 1)
-        visible = (idx <= pos) & mask_ref[0]          # causal & pad holes
+        visible = (idx <= pos) & (mask_ref[0, 0] != 0)  # causal & pad holes
         if window is not None:
             visible &= (pos - idx) < window
         logits = jnp.where(visible, logits, NEG_INF)
@@ -124,8 +130,7 @@ def decode_attention(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One-token-per-row attention over each row's cache prefix."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     b, sq, n_q, hd = q.shape
     if sq != 1:
         raise ValueError(f"decode_attention is s=1 only, got sq={sq}")
@@ -155,7 +160,7 @@ def decode_attention(
         return (b_i, _clamp(ki, pos_ref[b_i]), 0, 0)
 
     def mask_map(b_i, ki, pos_ref):
-        return (b_i, _clamp(ki, pos_ref[b_i]))
+        return (b_i, _clamp(ki, pos_ref[b_i]), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -165,7 +170,7 @@ def decode_attention(
                          lambda b_i, ki, pos_ref: (b_i, 0, 0, 0)),
             pl.BlockSpec((1, block_k, n_kv, hd), kv_map),
             pl.BlockSpec((1, block_k, n_kv, hd), kv_map),
-            pl.BlockSpec((1, block_k), mask_map),
+            pl.BlockSpec((1, 1, 1, block_k), mask_map),
         ],
         out_specs=pl.BlockSpec((1, 1, n_q, hd),
                                lambda b_i, ki, pos_ref: (b_i, 0, 0, 0)),
@@ -184,4 +189,5 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(positions, q, k, v, kv_mask)
+    )(positions, q, k, v,
+      kv_mask.astype(jnp.int32).reshape(b, nk, 1, block_k))

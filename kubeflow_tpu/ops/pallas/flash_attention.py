@@ -23,6 +23,7 @@ tokens/sec/chip metric exercises.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -61,8 +62,39 @@ DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+# Interpret mode is the tests' vehicle and only theirs: the kernels in
+# this package compile for the TPU, and nothing on the serving/training
+# path may infer "interpret" from the backend — a host whose chip failed
+# to attach would then run the "kernels" interpreted on the CPU and
+# report success. tests/conftest.py turns this on for the CPU suite.
+_forced_interpret = False
+
+
+@contextlib.contextmanager
+def force_interpret(on: bool = True):
+    """Run every kernel of this package in Pallas interpret mode inside
+    the block (unless a call passes `interpret=` itself). Resolved at
+    trace time: a jit cached outside the block keeps what it traced."""
+    global _forced_interpret
+    prev, _forced_interpret = _forced_interpret, on
+    try:
+        yield
+    finally:
+        _forced_interpret = prev
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """`interpret=None` means compiled, unless a test forced interpret
+    mode. Compiling a TPU kernel on another backend is an error, never
+    a quiet switch to the interpreter."""
+    if interpret is None:
+        interpret = _forced_interpret
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"Pallas TPU kernel requested on backend "
+            f"{jax.default_backend()!r}: use the XLA impl, or pass "
+            "interpret=True (tests: ops.pallas.force_interpret)")
+    return interpret
 
 
 def _pick_block(s: int, block: int) -> int:
@@ -407,12 +439,10 @@ def flash_attention(
     """Flash attention with GQA, differentiable (custom VJP).
 
     Layout contract matches ops.attention.dot_product_attention:
-    [batch, seq, heads, head_dim] in/out. `interpret=None` auto-selects
-    interpreter mode off-TPU so the same code path is testable on the
-    hermetic CPU backend.
+    [batch, seq, heads, head_dim] in/out. `interpret`: see
+    `resolve_interpret` (compiled unless a test says otherwise).
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     if window is not None and not causal:
         raise ValueError("sliding window requires causal attention")
     if window is not None and window < 1:
@@ -423,8 +453,20 @@ def flash_attention(
         raise ValueError(f"n_q={n_q} not a multiple of n_kv={n_kv}")
     if k.shape[1] != s:
         raise ValueError("flash kernel requires equal q/kv sequence lengths")
-    q4 = jnp.transpose(q, (0, 2, 1, 3))
-    k4 = jnp.transpose(k, (0, 2, 1, 3))
-    v4 = jnp.transpose(v, (0, 2, 1, 3))
-    o4 = _flash(q4, k4, v4, causal, window, block_q, block_k, interpret)
-    return jnp.transpose(o4, (0, 2, 1, 3))
+    def kernel(q, k, v):
+        q4 = jnp.transpose(q, (0, 2, 1, 3))
+        k4 = jnp.transpose(k, (0, 2, 1, 3))
+        v4 = jnp.transpose(v, (0, 2, 1, 3))
+        o4 = _flash(q4, k4, v4, causal, window, block_q, block_k,
+                    interpret)
+        return jnp.transpose(o4, (0, 2, 1, 3))
+
+    # lazy: parallel/ imports ops.attention, which imports this module
+    from kubeflow_tpu.parallel.sharding import per_shard
+
+    # batch rows and heads are independent, and contiguous head shards
+    # keep each query group with its KV head — the layout the model's
+    # own activation constraints already ask for
+    q_axes = ("batch", "seq", "act_heads", None)
+    kv_axes = ("batch", "seq", "act_kv_heads", None)
+    return per_shard(kernel, (q_axes, kv_axes, kv_axes), q_axes)(q, k, v)

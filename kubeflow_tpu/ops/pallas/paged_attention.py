@@ -22,13 +22,21 @@ in-kernel instead:
   the kernel; the pool never repeats heads);
 - per-block partials merge with the same online softmax as
   flash_attention.py / decode_attention.py; per-cell validity (left-pad
-  holes) rides in as a mask block indexed by LOGICAL block, causality
-  masks by absolute cell index against the prefetched cursor.
+  holes) rides in as an int32 mask laid out `[b, blocks, 1, block_size]`
+  and indexed by LOGICAL block (Mosaic takes neither an i1 VMEM operand
+  nor a `(1, block_size)` block over `[b, width]`: the last two block
+  dims must be (8, 128)-divisible or the array's own), causality masks
+  by absolute cell index against the prefetched cursor.
 
 Cell index == logical token position is a precondition (the pool's
 insert-time compaction guarantees it — see serving/paged.py); callers
 with rotated/packed layouts must use the XLA gather path, which masks
 by the actual position tensors.
+
+A row whose visible set is empty (pad holes over its whole causal
+prefix) comes out as exact zeros; the XLA path gives such a row the mean
+of V. Only host-masked filler rows are ever in that state, and
+tools/smoke_kernels.py pins the convention on the chip.
 
 The trash-block-0 convention costs nothing here: clamping confines j
 to live blocks, so the table's trash tail is never even read.
@@ -47,7 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kubeflow_tpu.ops.attention import NEG_INF
-from kubeflow_tpu.ops.pallas.flash_attention import _interpret_default
+from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
 
 
 def _kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
@@ -89,7 +97,7 @@ def _kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
         # Logical cell index == token position (pool compaction).
         idx = bj * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (n_q, block_size), 1)
-        visible = (idx <= pos) & mask_ref[0]          # causal & pad holes
+        visible = (idx <= pos) & (mask_ref[0, 0] != 0)  # causal & pad holes
         if window is not None:
             visible &= (pos - idx) < window
         logits = jnp.where(visible, logits, NEG_INF)
@@ -137,8 +145,7 @@ def paged_decode_attention(
     (bounded below by the sliding window's first block), not the full
     `blocks_per_slot` window the XLA gather touches.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     b, sq, n_q, hd = q.shape
     if sq != 1:
         raise ValueError(
@@ -191,7 +198,7 @@ def paged_decode_attention(
 
     def mask_map(b_i, bj, pos_ref, tab_ref):
         # The mask is laid out logically, so no table lookup here.
-        return (b_i, _clamp(bj, pos_ref[b_i]))
+        return (b_i, _clamp(bj, pos_ref[b_i]), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -201,7 +208,7 @@ def paged_decode_attention(
                          lambda b_i, bj, pos_ref, tab_ref: (b_i, 0, 0, 0)),
             pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
             pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, block_size), mask_map),
+            pl.BlockSpec((1, 1, 1, block_size), mask_map),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, n_q, hd),
@@ -221,4 +228,5 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(positions, table, q, k_pool, v_pool, kv_mask)
+    )(positions, table, q, k_pool, v_pool,
+      kv_mask.astype(jnp.int32).reshape(b, nb, 1, block_size))

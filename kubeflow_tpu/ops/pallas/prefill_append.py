@@ -58,7 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kubeflow_tpu.ops.attention import NEG_INF
-from kubeflow_tpu.ops.pallas.flash_attention import _interpret_default
+from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
 
 
 def _kernel(qs_ref, ql_ref, tab_ref, q_ref, kn_ref, vn_ref, kp_ref,
@@ -133,7 +133,7 @@ def _kernel(qs_ref, ql_ref, tab_ref, q_ref, kn_ref, vn_ref, kp_ref,
             jnp.int32, (s, block_size), 1)
         qpos = start + jax.lax.broadcasted_iota(
             jnp.int32, (s, block_size), 0)
-        visible = (idx <= qpos) & mask_ref[0]      # causal & pad holes
+        visible = (idx <= qpos) & (mask_ref[0, 0] != 0)  # causal, pads
         if window is not None:
             visible &= (qpos - idx) < window
         vis = jnp.broadcast_to(
@@ -170,6 +170,32 @@ def _kernel(qs_ref, ql_ref, tab_ref, q_ref, kn_ref, vn_ref, kp_ref,
             s, n_q, hd).astype(o_ref.dtype)
 
 
+def vmem_bytes(s: int, n_q: int, n_kv: int, hd: int, block_size: int,
+               itemsize: int) -> int:
+    """Upper estimate of the VMEM one grid step holds, from the shapes:
+    the kernel keeps all `s * n_q` query rows resident. The call asks
+    for this much as `vmem_limit_bytes` where it exceeds Mosaic's 16 MiB
+    default scoped limit (s=256 at n_q=16 needs 22.5 MiB), and
+    `ops.attention`'s auto rule compares it with `VMEM_BUDGET_BYTES`."""
+    rows = s * n_q
+    lanes = max(block_size, 128)          # fp32 [rows, block_size] tiles
+    scratch = rows * (hd + 2 * 128) * 4   # acc + lane-replicated m, l
+    # double-buffered blocks: q and out rows, the new K/V, and the pool
+    # block four times (K and V, in and out)
+    blocks = 2 * itemsize * hd * (2 * rows + 2 * s * n_kv
+                                  + 4 * block_size * n_kv)
+    # fp32 body values: q and its head-major copy, pv and the acc read,
+    # logits / probs / visibility, the merged K and V blocks
+    body = 4 * (4 * rows * hd + 3 * rows * lanes
+                + 4 * block_size * n_kv * hd)
+    return scratch + blocks + body
+
+
+# What `auto` lets this kernel ask for: half of the 128 MiB a v5e core
+# has, so the rest of the program's fusions keep their share.
+VMEM_BUDGET_BYTES = 64 * 2**20
+
+
 def paged_prefill_append(
     q: jnp.ndarray,            # [b, s, n_q, hd]
     k_new: jnp.ndarray,        # [b, s, n_kv, hd]
@@ -191,8 +217,7 @@ def paged_prefill_append(
     read+write of `ceil((q_start + s) / block_size)` blocks — the new
     cells never round-trip, and the table's trash tail is never read.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     b, s, n_q, hd = q.shape
     if k_new.shape != v_new.shape or k_new.shape[:2] != (b, s):
         raise ValueError(
@@ -242,7 +267,7 @@ def paged_prefill_append(
         return (tab_ref[b_i, _clamp(bj, qs_ref[b_i])], 0, 0, 0)
 
     def mask_map(b_i, bj, qs_ref, ql_ref, tab_ref):
-        return (b_i, _clamp(bj, qs_ref[b_i]))
+        return (b_i, _clamp(bj, qs_ref[b_i]), 0, 0)
 
     def row_map(b_i, bj, qs_ref, ql_ref, tab_ref):
         return (b_i, 0, 0, 0)
@@ -256,7 +281,7 @@ def paged_prefill_append(
             pl.BlockSpec((1, s, n_kv, hd), row_map),
             pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
             pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, block_size), mask_map),
+            pl.BlockSpec((1, 1, 1, block_size), mask_map),
         ],
         out_specs=[
             pl.BlockSpec((1, s, n_q, hd), row_map),
@@ -284,5 +309,9 @@ def paged_prefill_append(
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
         input_output_aliases={6: 1, 7: 2},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 * 2**20, vmem_bytes(
+                s, n_q, n_kv, hd, block_size, q.dtype.itemsize))),
         interpret=interpret,
-    )(starts, lens, table, q, k_new, v_new, k_pool, v_pool, kv_mask)
+    )(starts, lens, table, q, k_new, v_new, k_pool, v_pool,
+      kv_mask.astype(jnp.int32).reshape(b, nb, 1, block_size))

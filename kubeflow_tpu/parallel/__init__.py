@@ -14,7 +14,6 @@ from kubeflow_tpu.parallel.mesh import (
     get_abstract_mesh,
     mesh_from_env,
     num_slices_from_env,
-    set_mesh,
 )
 from kubeflow_tpu.parallel.sharding import (
     ShardingRules,

@@ -48,56 +48,12 @@ NUM_SLICES_ENV = "KFTPU_NUM_SLICES"
 MEGASCALE_NUM_SLICES_ENV = "MEGASCALE_NUM_SLICES"
 
 
-def set_mesh(mesh: Mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-    `jax.set_mesh` where it exists; on older jax the Mesh object is
-    itself the (legacy global-mesh) context manager with the same
-    scoping behavior for jit + sharding constraints."""
-    fn = getattr(jax, "set_mesh", None)
-    return fn(mesh) if fn is not None else mesh
-
-
 def get_abstract_mesh():
-    """The current ambient mesh, or None when there is no usable mesh
-    context. jax only exports `jax.sharding.get_abstract_mesh` publicly
-    from 0.5; on older versions the equivalent scope is the legacy
-    global-mesh context (what set_mesh above installs there), read from
-    thread_resources. Callers must treat None as "trivial mesh"
-    (gather / no-constraint paths)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        mesh = fn()
-        return mesh if getattr(mesh, "axis_names", ()) else None
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-    except Exception:  # noqa: BLE001 — private layout changed; no mesh
-        return None
-    return mesh if getattr(mesh, "axis_names", ()) else None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-              axis_names=None):
-    """`jax.shard_map` with the modern keyword surface, bridged to
-    `jax.experimental.shard_map` on older jax: `check_vma` maps to
-    `check_rep`, and `axis_names` (the manual axes) maps to its
-    complement `auto` (the axes left to the partitioner)."""
-    fn = getattr(jax, "shard_map", None)
-    kwargs = {}
-    if fn is not None:
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, **kwargs)
+    """The ambient mesh (`jax.set_mesh`), or None outside any mesh
+    context. Callers treat None as "trivial mesh" (gather /
+    no-constraint paths)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -254,7 +254,7 @@ def moe_mlp_sharded(
         "w_down": P(expert_axis),
     }
     x_spec = P(batch_axes, None, None)
-    fn = mesh_lib.shard_map(
+    fn = jax.shard_map(
         functools.partial(
             moe_mlp_expert_parallel, cfg=cfg, axis_name=expert_axis,
             token_axes=tuple(batch_axes),

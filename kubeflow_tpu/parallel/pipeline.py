@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubeflow_tpu.parallel import mesh as mesh_lib
 
 
 def pipeline(
@@ -136,7 +135,7 @@ def pipeline_sharded(
     # sharding — and their gradient reductions — inside the stage loop.
     # This is what lets PP compose with a (stage, data) mesh and the real
     # Trainer optimizer without hand-written data-parallel psums.
-    fn = mesh_lib.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs, P()),

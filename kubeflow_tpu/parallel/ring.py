@@ -143,7 +143,7 @@ def ring_attention_sharded(
             f"{seq_axis}={n}"
         )
     spec = P(None, seq_axis, None, None)
-    fn = mesh_lib.shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=seq_axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -301,12 +301,10 @@ def ring_flash_attention(
     """Ring attention with Pallas flash blocks. Call inside shard_map;
     same contract as `ring_attention` (global sequence = shard
     concatenation in axis order), differentiable via the ring-flash
-    custom VJP. `interpret=None` auto-selects interpreter mode off-TPU."""
-    if interpret is None:
-        from kubeflow_tpu.ops.pallas.flash_attention import (
-            _interpret_default)
+    custom VJP. `interpret`: see flash_attention.resolve_interpret."""
+    from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
 
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     q4 = jnp.transpose(q, (0, 2, 1, 3))
     k4 = jnp.transpose(k, (0, 2, 1, 3))
     v4 = jnp.transpose(v, (0, 2, 1, 3))
@@ -332,7 +330,7 @@ def ring_flash_attention_sharded(
             f"{seq_axis}={n}"
         )
     spec = P(None, seq_axis, None, None)
-    fn = mesh_lib.shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_flash_attention, axis_name=seq_axis,
                           causal=causal),
         mesh=mesh,
@@ -414,7 +412,7 @@ def ulysses_attention_sharded(
 ) -> jnp.ndarray:
     """shard_map wrapper for `ulysses_attention` (see ring_attention_sharded)."""
     spec = P(None, seq_axis, None, None)
-    fn = mesh_lib.shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention, axis_name=seq_axis,
                           causal=causal, impl=impl),
         mesh=mesh,

@@ -11,6 +11,7 @@ parallelism layer (SURVEY.md §2b).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Mapping
 
 import jax
@@ -175,6 +176,14 @@ def make_shard_and_gather_fns(shardings: Any):
     )
 
 
+def _auto_axes(mesh) -> set[str]:
+    return {
+        name
+        for name, t in zip(mesh.axis_names, mesh.axis_types)
+        if t == jax.sharding.AxisType.Auto
+    }
+
+
 def _filter_spec_to_mesh(spec: P) -> P:
     """Drop mesh axes the current context can't constrain.
 
@@ -188,18 +197,7 @@ def _filter_spec_to_mesh(spec: P) -> P:
     mesh = mesh_lib.get_abstract_mesh()
     if mesh is None:
         return spec  # no mesh context; with_sharding_constraint will no-op
-    axis_types = getattr(mesh, "axis_types", None)
-    axis_type_cls = getattr(jax.sharding, "AxisType", None)
-    if axis_types and axis_type_cls is not None:
-        auto = {
-            name
-            for name, t in zip(mesh.axis_names, axis_types)
-            if t == axis_type_cls.Auto
-        }
-    else:
-        # legacy global-mesh context (pre-AxisType jax): every axis is
-        # auto-sharded, so only filter axes absent from the mesh
-        auto = set(mesh.axis_names)
+    auto = _auto_axes(mesh)
 
     def filt(entry):
         if entry is None:
@@ -229,3 +227,29 @@ def with_sharding_constraint(x: Any, logical_axes: tuple[str | None, ...],
         if "empty mesh" in msg or "mesh context" in msg or "requires a mesh" in msg:
             return x
         raise
+
+
+def per_shard(fn, in_logical, out_logical,
+              rules: ShardingRules = LLAMA_RULES):
+    """`fn` run once per shard of the ambient mesh, its operands split by
+    their logical axes — or `fn` itself where there is nothing to split
+    over (no mesh, one device, or every axis already manual).
+
+    This is how a Pallas TPU kernel sits inside a GSPMD-partitioned
+    step: the partitioner cannot split a Mosaic call ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map"), so the call names its own partitioning. Only the
+    mesh's Auto axes become manual, so it nests inside a shard_map that
+    already took some (the pipeline's stage axis)."""
+    mesh = mesh_lib.get_abstract_mesh()
+    auto = _auto_axes(mesh) if mesh is not None else set()
+    if math.prod(mesh.shape[a] for a in auto) == 1:
+        return fn
+
+    def spec(logical_axes):
+        return _filter_spec_to_mesh(rules.resolve(logical_axes))
+
+    return jax.shard_map(
+        fn, in_specs=tuple(spec(a) for a in in_logical),
+        out_specs=spec(out_logical), axis_names=frozenset(auto),
+        check_vma=False)

@@ -187,8 +187,8 @@ def main(argv=None) -> int:
                    help="decode attention over the paged KV pool "
                         "(continuous only): xla gathers each row's "
                         "full window through the block table, pallas "
-                        "walks the table in-kernel (interpret mode "
-                        "off-TPU), auto = pallas on TPU")
+                        "walks the table in-kernel (TPU only), auto = "
+                        "pallas on TPU, xla elsewhere")
     p.add_argument("--quant", choices=("", "int8"), default="")
     p.add_argument("--tokenizer", default="",
                    help="data.bpe tokenizer file (text mode); 'auto' "
@@ -196,8 +196,8 @@ def main(argv=None) -> int:
                         "present (tools/prepare_data.py's output name), "
                         "byte fallback otherwise")
     p.add_argument("--cpu", action="store_true",
-                   help="pin the CPU backend (hermetic smoke; pins "
-                        "jax.config BEFORE backend init)")
+                   help="pin the CPU backend (hermetic smoke) — the one "
+                        "explicit way to serve without the accelerator")
     p.add_argument("--drain-grace-s", type=float, default=30.0,
                    help="shutdown waits this long for in-flight "
                         "generations before closing")
@@ -262,8 +262,12 @@ def main(argv=None) -> int:
 
     import jax
 
+    from kubeflow_tpu import compile_cache
+    from kubeflow_tpu.utils import device_stamp
+
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    compile_cache.enable()
 
     from aiohttp import web
 
@@ -352,9 +356,15 @@ def main(argv=None) -> int:
         enable_fleet_registration(
             app, args.fleet_router,
             args.advertise or f"http://{args.host}:{args.port}")
+    # The device as JAX reports it: a server that came up on the wrong
+    # backend says so on its first line (--cpu is the one way to ask
+    # for the CPU; nothing here falls back to it).
+    device = device_stamp()
     print(f"serving {args.name or args.model} "
           f"({'random' if args.random else args.checkpoint}) on "
-          f"{args.host}:{args.port} backend={jax.default_backend()} "
+          f"{args.host}:{args.port} backend={device['platform']} "
+          f"device_kind={device['kind']!r} "
+          f"devices={device['count']} jax={jax.__version__} "
           f"tokenizer={tok_ref or 'byte'}",
           flush=True)
     web.run_app(app, host=args.host, port=args.port, print=None)

@@ -64,6 +64,7 @@ from kubeflow_tpu.ops.attention import (
     resolve_paged_prefill_impl,
 )
 from kubeflow_tpu.ops.norms import rms_norm
+from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
 from kubeflow_tpu.ops.rotary import rope_frequencies
 from kubeflow_tpu.serving.engine import (
     DecodeState,
@@ -228,10 +229,14 @@ class ContinuousEngine:
         self.attention_impl = resolve_paged_attention_impl(
             paged_attention_impl)
         # chunked-prefill / draft-verify writes go through the fused
-        # prefill/append op — same knob, separately resolved (the
-        # prefill kernel has its own availability probe)
+        # prefill/append op — same knob. This is the platform's answer;
+        # each trace re-resolves the REQUEST with its chunk shape,
+        # because "auto" also bounds the kernel's VMEM need.
         self.prefill_impl = resolve_paged_prefill_impl(
             paged_attention_impl)
+        if "pallas" in (self.attention_impl, self.prefill_impl):
+            # fail at construction, not at the first request's trace
+            resolve_interpret(None)
         self.engine = engine
         self.S = max_slots
         # Paged KV geometry. The cache is a POOL of fixed-size blocks
@@ -868,7 +873,7 @@ class ContinuousEngine:
                     q, kn, vn, kp, vp, table, start, n_valid,
                     kv_mask=kv_valid,
                     window=getattr(cfg, "sliding_window", None),
-                    impl=self.prefill_impl)
+                    impl=self.paged_attention_impl)
                 cell["k"] = jax.lax.dynamic_update_index_in_dim(
                     k_all, kp2, li, 0)
                 cell["v"] = jax.lax.dynamic_update_index_in_dim(

@@ -708,7 +708,7 @@ def create_serving_app(engines: dict[str, InferenceEngine],
     docs/operator-guide.md). `paged_attention_impl`
     (continuous only) selects decode's attention path: "xla" (gather
     through the block table), "pallas" (fused kernel walking the table
-    in-kernel; interpret mode off-TPU), or "auto" (pallas on TPU, xla
+    in-kernel; TPU only), or "auto" (pallas on TPU, xla
     elsewhere) — the resolved choice is exported as the
     `serving_attention_impl` info gauge. `registry`/`tracer`
     share an external metric registry / span tracer; by default the app

@@ -24,7 +24,6 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu import obs
-from kubeflow_tpu.parallel import mesh as mesh_lib
 from kubeflow_tpu.parallel import sharding as sharding_lib
 from kubeflow_tpu.parallel.sharding import ShardingRules
 
@@ -417,7 +416,7 @@ class Trainer:
         return TrainState(params, opt_state, state.step + 1), loss
 
     def init(self, rng: jax.Array) -> TrainState:
-        with mesh_lib.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self._jit_init(rng)
 
     def init_from_params(self, params: Params) -> TrainState:
@@ -425,7 +424,7 @@ class Trainer:
         (fine-tuning from a checkpoint). Params are a jit argument, not
         a closure constant — closing over an 8B tree would bake it into
         the executable."""
-        with mesh_lib.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self._jit_build_state(params)
 
     @property
@@ -495,7 +494,7 @@ class Trainer:
             self.profiler.record("host_gap", t0 - self._last_step_end)
         with self.tracer.span("train.step", batch=int(tokens.shape[0]),
                               compile=compiling):
-            with mesh_lib.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 with self.profiler.phase(
                         "step", tokens=int(tokens.shape[0])
                         * int(tokens.shape[1])):
@@ -520,9 +519,7 @@ def _opt_state_shardings(opt_shapes, params_shapes, param_shardings, mesh):
     wo's adam moments wrong and force per-step resharding over ICI.
     """
     param_by_path: dict[tuple, Any] = {}
-    # jax.tree.leaves_with_path only landed in 0.4.35+aliases; the
-    # tree_util spelling works across the versions we support.
-    flat_params = jax.tree_util.tree_leaves_with_path(params_shapes)
+    flat_params = jax.tree.leaves_with_path(params_shapes)
     flat_shard = jax.tree.leaves(param_shardings)
     for (path, leaf), sh in zip(flat_params, flat_shard):
         param_by_path[tuple(str(p) for p in path)] = (leaf.shape, sh)

@@ -33,6 +33,14 @@ def pod_start_time() -> float:
         return _PROCESS_START
 
 
+def device_stamp() -> dict:
+    """The device as JAX reports it. Every result a run prints carries
+    it, so a number can never be read under the wrong device's name."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
 def time_to_first_compile(
     fn: Callable[..., Any], *args: Any, **kwargs: Any
 ) -> tuple[float, Any]:

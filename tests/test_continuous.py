@@ -464,7 +464,7 @@ def test_continuous_engine_under_tensor_parallel_mesh():
     the collectives, the engine code is mesh-oblivious (the SPMD
     contract the whole compute layer is built on)."""
     from kubeflow_tpu.parallel import (
-        LLAMA_RULES, MeshSpec, create_mesh, set_mesh, shard_pytree_specs)
+        LLAMA_RULES, MeshSpec, create_mesh, shard_pytree_specs)
 
     cfg = llama.LLAMA_TINY
     params = dict(llama.init(jax.random.key(0), cfg))
@@ -486,7 +486,7 @@ def test_continuous_engine_under_tensor_parallel_mesh():
     engine = InferenceEngine(sharded, cfg, LLAMA_FAMILY,
                              EngineConfig(max_len=64))
     ce = ContinuousEngine(engine, max_slots=2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = ce.init_slots()
         got = [[] for _ in prompts]
         for i, p in enumerate(prompts):

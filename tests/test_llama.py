@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from kubeflow_tpu.models import llama
-from kubeflow_tpu.parallel import MeshSpec, create_mesh, set_mesh
+from kubeflow_tpu.parallel import MeshSpec, create_mesh
 from kubeflow_tpu.train.trainer import Trainer, TrainConfig, cross_entropy_loss
 
 CFG = llama.LLAMA_TINY
@@ -71,7 +71,7 @@ def test_fsdp_tp_parity():
     ref = llama.apply(params, CFG, tokens)
 
     mesh = create_mesh(MeshSpec(data=1, fsdp=4, tensor=2))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sharded = jax.jit(lambda p, t: llama.apply(p, CFG, t))(params, tokens)
     np.testing.assert_allclose(ref, sharded, atol=2e-4, rtol=1e-3)
 

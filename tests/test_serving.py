@@ -479,7 +479,7 @@ def test_sharded_gemma_scale_vocab_decode_matches_unsharded():
     import dataclasses
 
     from kubeflow_tpu.parallel import (
-        LLAMA_RULES, MeshSpec, create_mesh, set_mesh, shard_pytree_specs)
+        LLAMA_RULES, MeshSpec, create_mesh, shard_pytree_specs)
 
     # Gemma-2B's 256k vocabulary on otherwise-tiny dims (the sharding
     # semantics depend on the table's vocab axis, not the block sizes).
@@ -502,7 +502,7 @@ def test_sharded_gemma_scale_vocab_decode_matches_unsharded():
     assert sharded_params["embed"].sharding.spec[0] == "tensor"
     engine = InferenceEngine(sharded_params, cfg, LLAMA_FAMILY,
                              EngineConfig(max_len=32))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = engine.generate(prompt, max_new=4)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

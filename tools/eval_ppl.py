@@ -45,16 +45,18 @@ def main(argv=None) -> int:
     p.add_argument("--max-batches", type=int, default=0,
                    help="0 = one full epoch")
     p.add_argument("--cpu", action="store_true",
-                   help="pin the CPU backend (pins jax.config BEFORE "
-                        "backend init)")
+                   help="pin the CPU backend")
     args = p.parse_args(argv)
     if not args.checkpoint and not args.random:
         p.error("pass --checkpoint DIR or --random")
 
     import jax
 
+    from kubeflow_tpu import compile_cache
+
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    compile_cache.enable()
 
     import jax.numpy as jnp
     import numpy as np
