@@ -167,21 +167,26 @@ def _attention_half(cfg, x, p, positions, inv_freq, kv_mask,
     """Attention sub-block + residual (shared by the dense, pipelined,
     and MoE models — cfg needs the llama attention attrs only)."""
     b, s, D = x.shape
+    # the parts carry the serving block's scope names
+    # (serving/engine.py `transformer_block`); `rms_norm` and the
+    # attention call open their own
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q = (h @ p["wq"].astype(cfg.dtype)).reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = (h @ p["wk"].astype(cfg.dtype)).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = (h @ p["wv"].astype(cfg.dtype)).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
-    q = wsc(q, ("batch", "seq", "act_heads", None))
-    k = wsc(k, ("batch", "seq", "act_kv_heads", None))
+    with jax.named_scope("attn_proj"):
+        q = (h @ p["wq"].astype(cfg.dtype)).reshape(b, s, cfg.num_heads, cfg.head_dim)
+        k = (h @ p["wk"].astype(cfg.dtype)).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        v = (h @ p["wv"].astype(cfg.dtype)).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
+        q = wsc(q, ("batch", "seq", "act_heads", None))
+        k = wsc(k, ("batch", "seq", "act_kv_heads", None))
     attn = dot_product_attention(q, k, v, positions, positions,
                                  causal=True, kv_mask=kv_mask,
                                  window=cfg.sliding_window,
                                  contiguous_positions=contiguous_positions)
-    attn = attn.reshape(b, s, cfg.q_dim)
-    x = x + attn @ p["wo"].astype(cfg.dtype)
-    return wsc(x, ("batch", "seq", "act_embed"))
+    with jax.named_scope("attn_proj"):
+        attn = attn.reshape(b, s, cfg.q_dim)
+        x = x + attn @ p["wo"].astype(cfg.dtype)
+        return wsc(x, ("batch", "seq", "act_embed"))
 
 
 def _block(cfg: LlamaConfig, x, layer_params, positions, inv_freq, kv_mask,
@@ -194,12 +199,14 @@ def _block(cfg: LlamaConfig, x, layer_params, positions, inv_freq, kv_mask,
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     # checkpoint_name is inert unless cfg.remat_policy == "mlp" selects
     # these tensors as the save set (see _REMAT_POLICIES).
-    gate = jax.nn.silu(
-        checkpoint_name(h @ p["w_gate"].astype(cfg.dtype), "mlp_gate"))
-    up = checkpoint_name(h @ p["w_up"].astype(cfg.dtype), "mlp_up")
-    ff = wsc(gate * up, ("batch", "seq", "act_mlp"))
-    x = x + checkpoint_name(ff @ p["w_down"].astype(cfg.dtype), "mlp_down")
-    return wsc(x, ("batch", "seq", "act_embed"))
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(
+            checkpoint_name(h @ p["w_gate"].astype(cfg.dtype), "mlp_gate"))
+        up = checkpoint_name(h @ p["w_up"].astype(cfg.dtype), "mlp_up")
+        ff = wsc(gate * up, ("batch", "seq", "act_mlp"))
+        x = x + checkpoint_name(ff @ p["w_down"].astype(cfg.dtype),
+                                "mlp_down")
+        return wsc(x, ("batch", "seq", "act_embed"))
 
 
 # Mesh-aware lookup (gather on trivial meshes, one-hot MXU contraction
@@ -223,8 +230,9 @@ def hidden(
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     inv_freq = rope_frequencies(cfg.head_dim, theta=cfg.rope_theta)
 
-    x = _embed_lookup(params["embed"], tokens, cfg.dtype)
-    x = wsc(x, ("batch", "seq", "act_embed"))
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], tokens, cfg.dtype)
+        x = wsc(x, ("batch", "seq", "act_embed"))
 
     block_fn = lambda x, lp: (
         _block(cfg, x, lp, positions, inv_freq, kv_mask,
@@ -251,9 +259,10 @@ def apply(
 ) -> jnp.ndarray:
     """Forward pass → logits [b, s, vocab] (fp32)."""
     x = hidden(params, cfg, tokens, positions, kv_mask)
-    head = unembed_matrix(params, cfg)
-    logits = x.astype(jnp.float32) @ head.astype(jnp.float32)
-    return wsc(logits, ("batch", "seq", "act_vocab"))
+    with jax.named_scope("head"):
+        head = unembed_matrix(params, cfg)
+        logits = x.astype(jnp.float32) @ head.astype(jnp.float32)
+        return wsc(logits, ("batch", "seq", "act_vocab"))
 
 
 def num_params(cfg: LlamaConfig) -> int:

@@ -9,9 +9,13 @@ ways —
   1. `serving_step_phase_seconds{phase}` / `serving_step_tokens{phase}`
      histograms (the server binds them through `on_phase`, zero-seeded
      so dashboards see every phase from the first scrape),
-  2. a goodput ledger: decode device-time over total non-idle step
-     time, bubble fraction (host-gap share), and occupancy / KV-pool
-     high-water marks,
+  2. a goodput ledger: host wall time in the dispatching phases over
+     total non-idle step time, bubble fraction (host-gap share), and
+     occupancy / KV-pool high-water marks. Every time here is the
+     HOST's: a dispatch is asynchronous, so the wall time around one
+     is the enqueue plus whatever the host then waited for, not the
+     device's time. Device time comes from the profiler's trace alone
+     (below),
   3. Chrome-trace COUNTER tracks (`"ph": "C"`) merged into the same
      `/debug/traces` payload as the span events, so one trace shows
      phase budgets and pool fill over time next to the spans.
@@ -34,6 +38,16 @@ phases measure what the HOST does around it):
 
 Phase and fn label values are CLOSED SETS behind `LabelGuard`s: an
 unknown name collapses to `other` instead of minting a series.
+
+The same phases go into the JAX profiler's trace where the owner hands
+the profiler an `annotate` factory (`jax.profiler.TraceAnnotation`; obs
+itself imports no jax): `phase(name)` also opens the span
+`sched.<name>` (`train.<name>` under `TRAIN_PHASES`) with the stat
+`tokens`, and `begin_iteration`/`end_iteration` open and close
+`sched.iteration` with the counts they are given. The spans land in
+the same `.xplane.pb`, on the same clock, as the device's operations;
+`host_gap` needs none, being the iteration's self time there. Without a
+profiler session an annotation records nothing.
 
 The compile-watch wraps jitted callables and keys every call by the
 ABSTRACT signature of its arguments (shape/dtype for arrays, value for
@@ -70,8 +84,9 @@ SERVING_PHASES = ("admit", "prefill", "prefill_chunk", "decode",
 # The training step anatomy (Trainer.step): one device phase plus the
 # host gap between consecutive steps (input pipeline, checkpointing).
 TRAIN_PHASES = ("step", "host_gap")
-# Goodput numerator per anatomy: the phase that is useful device work
-# (draft/verify are the speculative round's token-producing legs).
+# Goodput numerator per anatomy: the phases that dispatch useful device
+# work and wait for it (draft/verify are the speculative round's
+# token-producing legs). Their time is host wall time.
 GOODPUT_PHASES = ("decode", "draft", "verify", "step")
 # Phases excluded from the goodput denominator: an empty batcher
 # parked on its wake event is not a bubble, it has no work.
@@ -147,8 +162,15 @@ class PhaseProfiler:
     def __init__(self, *, phases: tuple[str, ...] = SERVING_PHASES,
                  clock: Callable[[], float] | None = None,
                  wall_clock: Callable[[], float] | None = None,
-                 window: int | None = 512):
+                 window: int | None = 512,
+                 annotate: Callable[..., Any] | None = None):
         self.phases = tuple(phases)
+        # annotate(name, **stats) -> context manager: the profiler's
+        # own trace (module docstring). None: the phases stay here.
+        self._annotate = annotate
+        self._span_prefix = ("train." if self.phases == TRAIN_PHASES
+                             else "sched.")
+        self._iter_span: Any = None
         self.guard = LabelGuard(seed=self.phases, closed=True)
         self._clock = clock or time.perf_counter
         self._wall = wall_clock or time.time
@@ -180,25 +202,33 @@ class PhaseProfiler:
 
     # -- recording ---------------------------------------------------------
 
+    def _span(self, name: str, **stats):
+        if self._annotate is None:
+            return contextlib.nullcontext()
+        return self._annotate(self._span_prefix + name, **stats)
+
     @contextlib.contextmanager
     def phase(self, name: str, tokens: int = 0):
-        start = self._clock()
-        if self._t_first is None:
-            # the observed-wall window opens at the first phase START
-            # (record() only back-dates by the EXCLUSIVE duration, which
-            # undercounts when the first record is a nested child)
-            self._t_first = start
-        frame = [name, start, 0.0]
-        self._stack.append(frame)
-        try:
-            yield
-        finally:
-            dur = self._clock() - start
-            if self._stack and self._stack[-1] is frame:
-                self._stack.pop()
-            if self._stack:
-                self._stack[-1][2] += dur
-            self.record(name, max(0.0, dur - frame[2]), tokens=tokens)
+        with self._span(name, tokens=int(tokens)):
+            start = self._clock()
+            if self._t_first is None:
+                # the observed-wall window opens at the first phase
+                # START (record() only back-dates by the EXCLUSIVE
+                # duration, which undercounts when the first record is
+                # a nested child)
+                self._t_first = start
+            frame = [name, start, 0.0]
+            self._stack.append(frame)
+            try:
+                yield
+            finally:
+                dur = self._clock() - start
+                if self._stack and self._stack[-1] is frame:
+                    self._stack.pop()
+                if self._stack:
+                    self._stack[-1][2] += dur
+                self.record(name, max(0.0, dur - frame[2]),
+                            tokens=tokens)
 
     def record(self, name: str, seconds: float, tokens: int = 0) -> None:
         name = self.guard.admit(name)
@@ -245,15 +275,26 @@ class PhaseProfiler:
             except Exception:  # noqa: BLE001 — metrics hook
                 pass
 
-    def begin_iteration(self) -> None:
+    def begin_iteration(self, **counts: int) -> None:
+        """`counts` (the batcher's occupancy at the boundary) become
+        the stats of the trace's `sched.iteration` span."""
+        self._close_iteration_span()   # a pass that never reached its end
         self._iter_t0 = self._clock()
         self._iter_claimed = 0.0
+        self._iter_span = self._span("iteration", **counts)
+        self._iter_span.__enter__()
+
+    def _close_iteration_span(self) -> None:
+        span, self._iter_span = self._iter_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
 
     def end_iteration(self) -> None:
         """Book the loop-pass residual (wall minus every top-level
         phase recorded since begin_iteration) as `host_gap` — the
         attribution invariant `sum(phases) == loop wall` holds by
         construction."""
+        self._close_iteration_span()
         if self._iter_t0 is None:
             return
         residual = (self._clock() - self._iter_t0) - self._iter_claimed
@@ -336,8 +377,10 @@ class PhaseProfiler:
             return self._t_last - self._t_first
 
     def goodput(self) -> dict[str, float]:
-        """The ledger: useful-device-time share of non-idle wall, the
-        bubble (host_gap) share, and the high-water marks."""
+        """The ledger: the share of non-idle wall time the host spent
+        in the dispatching phases (enqueue plus waiting on results —
+        host time, not the device's), the bubble (host_gap) share, and
+        the high-water marks."""
         with self._lock:
             totals = {p: st.total_s for p, st in self._stats.items()}
         busy = sum(s for p, s in totals.items() if p not in IDLE_PHASES)

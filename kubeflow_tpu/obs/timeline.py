@@ -13,6 +13,11 @@ Token timestamps are kept as a flat float list, not event dicts: a
 
 - `queue_wait_s` — enqueue -> admit (the scheduling delay),
 - `ttft_s`      — enqueue -> first token,
+- `prefill_s`   — wall time of the request's own chunked-prefill
+  slices (the batcher adds each slice's; 0 on the monolithic path,
+  whose prefill ends before `admit` is stamped),
+- `prefill_wait_s` — the rest of the time to the first token: admitted,
+  and waiting for its turn at a slice,
 - ITL stats     — gaps between consecutive tokens, EXCLUDING gaps that
   span a preempt/resume hole (those measure scheduling, not decode;
   they are visible as events instead).
@@ -41,7 +46,7 @@ class RequestTimeline:
 
     __slots__ = ("request_id", "model", "tenant", "prompt_tokens",
                  "max_new", "events", "tokens", "_clock", "_itl_break",
-                 "done")
+                 "done", "prefill_s", "prefill_slices")
 
     def __init__(self, request_id: str, *, model: str = "",
                  tenant: str = "", prompt_tokens: int = 0,
@@ -61,6 +66,8 @@ class RequestTimeline:
         # next token gap spans a preempt/resume hole -> not an ITL
         self._itl_break = True  # first token has no predecessor
         self.done = False
+        self.prefill_s = 0.0
+        self.prefill_slices = 0
 
     def event(self, kind: str, **detail: Any) -> None:
         if len(self.events) < MAX_EVENTS:
@@ -101,6 +108,21 @@ class RequestTimeline:
         t0 = self._first("enqueue")
         return (self.tokens[0] - t0) \
             if t0 is not None and self.tokens else None
+
+    @property
+    def prefill_reused(self) -> int:
+        """Prompt cells the first admission took from the cache."""
+        for _, kind, detail in self.events:
+            if kind == "admit":
+                return int(detail.get("prefill_reused", 0))
+        return 0
+
+    @property
+    def prefill_wait_s(self) -> float | None:
+        ttft, queued = self.ttft_s, self.queue_wait_s
+        if ttft is None or queued is None:
+            return None
+        return max(0.0, ttft - queued - self.prefill_s)
 
     def itls(self) -> list[float]:
         """Inter-token gaps, excluding gaps across preempt/resume
@@ -151,6 +173,9 @@ class RequestTimeline:
             "token_times": [round(t - t0, 6) for t in self.tokens],
             "queue_wait_s": self.queue_wait_s,
             "ttft_s": self.ttft_s,
+            "prefill_s": self.prefill_s,
+            "prefill_slices": self.prefill_slices,
+            "prefill_wait_s": self.prefill_wait_s,
             "itl": {
                 "count": len(itls),
                 "mean_s": (sum(itls) / len(itls)) if itls else None,

@@ -11,6 +11,11 @@ Design: one public `dot_product_attention` that dispatches by backend.
 The XLA path never materializes repeated KV heads: queries are reshaped to
 [batch, q_per_kv, kv_heads, ...] and contracted against the kv heads
 directly — keeps HBM traffic at the GQA level, which is the point of GQA.
+
+Each public entry opens the `jax.named_scope` of the kernel whose work
+it does — `flash_attention`, `decode_attention`, `paged_attention`,
+`prefill_append` — whichever implementation runs it, so a device trace
+reads the same work under "xla" and "pallas" by one name.
 """
 
 from __future__ import annotations
@@ -171,6 +176,19 @@ def dot_product_attention(
         else:
             impl = "xla"
     _impl_counts[impl] = _impl_counts.get(impl, 0) + 1
+    # the scope names the work by its shape, not by who does it: a
+    # full-sequence call is flash attention's, a one-token step against
+    # a cache the decode kernel's
+    with jax.named_scope("decode_attention" if q.shape[1] == 1
+                         and k.shape[1] > 1 else "flash_attention"):
+        return _attention(q, k, v, q_positions, kv_positions, causal=causal,
+                          kv_mask=kv_mask, window=window, impl=impl,
+                          contiguous_positions=contiguous_positions)
+
+
+def _attention(q, k, v, q_positions, kv_positions, *, causal, kv_mask,
+               window, impl, contiguous_positions):
+    """`dot_product_attention` once the impl is resolved."""
     if impl == "decode":
         if q.shape[1] != 1:
             raise ValueError("impl='decode' is for single-token steps")
@@ -209,6 +227,7 @@ def dot_product_attention(
     )
 
 
+@jax.named_scope("paged_attention")
 def paged_attention(
     q: jnp.ndarray,            # [b, 1, n_q, hd] — single decode step
     k_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
@@ -306,6 +325,7 @@ def paged_attention(
     )
 
 
+@jax.named_scope("prefill_append")
 def paged_prefill_attention(
     q: jnp.ndarray,            # [b, s, n_q, hd] — s new tokens per row
     k_new: jnp.ndarray,        # [b, s, n_kv, hd]

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("norm")
 def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
                eps: float = 1e-6) -> jnp.ndarray:
     """LayerNorm (mean-centered) in fp32 accumulation, cast back.
@@ -21,8 +22,10 @@ def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
             + bias.astype(jnp.float32)).astype(dtype)
 
 
+@jax.named_scope("norm")
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
-    """RMSNorm in fp32 accumulation, cast back to input dtype.
+    """RMSNorm in fp32 accumulation, cast back to input dtype. Opens
+    the scope `norm`, by which a device trace finds it.
 
     XLA fuses this into neighboring ops; no kernel needed. Computed in
     float32 regardless of activation dtype (bf16-safe). Uses the Llama

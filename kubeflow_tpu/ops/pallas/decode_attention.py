@@ -189,5 +189,6 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(positions, q, k, v,
       kv_mask.astype(jnp.int32).reshape(b, nk, 1, block_k))

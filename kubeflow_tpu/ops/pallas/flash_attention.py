@@ -208,6 +208,7 @@ def _fwd(q4, k4, v4, *, causal, window, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q4, k4, v4)
     return o4, lse
 
@@ -343,6 +344,7 @@ def _bwd(causal, window, block_q, block_k, interpret, res, do4):
         out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(q4, k4, v4, do4, lse, delta)
 
     # dk/dv at query-head resolution; kv-head index maps stream the same
@@ -374,6 +376,7 @@ def _bwd(causal, window, block_q, block_k, interpret, res, do4):
             pltpu.VMEM((block_k, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q4, k4, v4, do4, lse, delta)
 
     # Group-sum query-head gradients onto their KV head.

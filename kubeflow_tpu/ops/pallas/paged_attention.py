@@ -228,5 +228,6 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(positions, table, q, k_pool, v_pool,
       kv_mask.astype(jnp.int32).reshape(b, nb, 1, block_size))

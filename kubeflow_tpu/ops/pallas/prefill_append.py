@@ -313,5 +313,6 @@ def paged_prefill_append(
             vmem_limit_bytes=max(16 * 2**20, vmem_bytes(
                 s, n_q, n_kv, hd, block_size, q.dtype.itemsize))),
         interpret=interpret,
+        name="prefill_append",
     )(starts, lens, table, q, k_new, v_new, k_pool, v_pool,
       kv_mask.astype(jnp.int32).reshape(b, nb, 1, block_size))

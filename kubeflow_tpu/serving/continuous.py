@@ -874,10 +874,12 @@ class ContinuousEngine:
                     kv_mask=kv_valid,
                     window=getattr(cfg, "sliding_window", None),
                     impl=self.paged_attention_impl)
-                cell["k"] = jax.lax.dynamic_update_index_in_dim(
-                    k_all, kp2, li, 0)
-                cell["v"] = jax.lax.dynamic_update_index_in_dim(
-                    v_all, vp2, li, 0)
+                with jax.named_scope("kv_write"):
+                    # the layer's pool slice back into the scan carry
+                    cell["k"] = jax.lax.dynamic_update_index_in_dim(
+                        k_all, kp2, li, 0)
+                    cell["v"] = jax.lax.dynamic_update_index_in_dim(
+                        v_all, vp2, li, 0)
                 return out
 
             x, _ = transformer_block(
@@ -1410,8 +1412,12 @@ class ContinuousBatcher:
         # (the on_prefix hook idiom) and `/debug/profile` reads
         # profiler.snapshot(); bench --attribution reads it directly.
         # Shares the injectable clock so tests reconcile profiler
-        # totals against timeline stamps on one timebase.
-        self.profiler = PhaseProfiler(clock=self._clock)
+        # totals against timeline stamps on one timebase. The phases
+        # are also spans of the JAX profiler's trace (`sched.<phase>`
+        # under `sched.iteration`), which record nothing unless a
+        # profiler session is open (docs/observability.md).
+        self.profiler = PhaseProfiler(
+            clock=self._clock, annotate=jax.profiler.TraceAnnotation)
         # Compile-watch: every jitted callable on this batcher's hot
         # path keys calls by abstract shape signature; a novel
         # signature past each fn's first is a retrace — counted here,
@@ -1833,7 +1839,10 @@ class ContinuousBatcher:
         rec.lps.append(lp)
         rec.kv_toks.append(token)  # cache-content log, never trimmed
         if rec.meta is not None and rec.meta.timeline is not None:
+            first = not rec.meta.timeline.tokens
             gap = rec.meta.timeline.token()
+            if first:
+                self._trace_first_token(rec.meta.timeline)
             # first token (and first after a preempt/resume hole)
             # returns None: not an inter-token latency
             if decode and gap is not None and self.on_itl is not None:
@@ -1866,6 +1875,24 @@ class ContinuousBatcher:
         if len(rec.out) >= rec.max_new or (eos is not None
                                            and token == eos):
             self._finish(slot, rec)
+
+    @staticmethod
+    def _trace_first_token(tl: RequestTimeline) -> None:
+        """Where a request's waits end: one instantaneous span in the
+        profiler's trace, with the three parts of its time to the
+        first token as the timeline holds them."""
+        def us(seconds):
+            return round(1e6 * (seconds or 0.0), 1)
+
+        with jax.profiler.TraceAnnotation(
+                "sched.first_token", request=tl.request_id,
+                prompt_tokens=tl.prompt_tokens,
+                reused_tokens=tl.prefill_reused,
+                slices=tl.prefill_slices,
+                queue_wait_us=us(tl.queue_wait_s),
+                prefill_us=us(tl.prefill_s),
+                prefill_wait_us=us(tl.prefill_wait_s)):
+            pass
 
     @staticmethod
     def _fail(fut, queue, exc) -> None:
@@ -2624,13 +2651,18 @@ class ContinuousBatcher:
 
         def run_append(st=self._st, toks=toks, n=n, finish=finish,
                        slot=slot, sp=sp):
-            st, nxt, lp, rng = self.cengine.append_rows(
-                st, [slot], toks, [n], [finish], sp, self._rng)
+            # the enqueue, apart from the one blocking host sync below
+            with jax.profiler.TraceAnnotation(
+                    "dispatch.prefill_chunk", tokens=n,
+                    finish=int(finish)):
+                st, nxt, lp, rng = self.cengine.append_rows(
+                    st, [slot], toks, [n], [finish], sp, self._rng)
             if finish:  # host-sync only the slice that samples
                 return (st, int(np.asarray(nxt)[0]),
                         float(np.asarray(lp)[0]), rng)
             return st, None, None, rng
 
+        t_slice = self._clock()
         with self.profiler.phase("prefill_chunk", tokens=n):
             async with self.gpu_lock:
                 st, first, flp, rng = await loop.run_in_executor(
@@ -2638,6 +2670,13 @@ class ContinuousBatcher:
                 self._st = st
                 self._rng = rng
         pf["fed"] = fed + n
+        tl = rec.meta.timeline if rec.meta is not None else None
+        if tl is not None and not tl.tokens:
+            # the request's own slices up to its first token (a
+            # preemption's replay is not part of it), against the time
+            # it waited its turn for them (timeline.prefill_wait_s)
+            tl.prefill_s += self._clock() - t_slice
+            tl.prefill_slices += 1
         self.tokens_prefilled += n
         if not finish:
             return
@@ -2785,7 +2824,9 @@ class ContinuousBatcher:
             # The rng chains THROUGH the compiled step (it splits
             # internally and returns the next key) — no host-side
             # jax.random.split dispatch per chunk.
-            return self.cengine.step(st, sp, self._rng, steps)
+            with jax.profiler.TraceAnnotation("dispatch.decode",
+                                              steps=steps):
+                return self.cengine.step(st, sp, self._rng, steps)
 
         if self.tracer is not None:
             # Tracer.wrap propagates the current context into the
@@ -2861,7 +2902,10 @@ class ContinuousBatcher:
             # One profiled iteration: every explicit phase below claims
             # its wall time; end_iteration books the residual as
             # host_gap, so phase sums reconcile against loop wall time
-            self.profiler.begin_iteration()
+            self.profiler.begin_iteration(
+                active=len(self._active), prefilling=len(self._prefill_q),
+                pending=len(self._pending), inflight=len(inflight),
+                pool_in_use=self.cengine.pool.in_use)
             # Preemption runs BEFORE the dirty-slot reset so an evicted
             # slot's table is trash-reset in this same iteration —
             # admission below may hand its freed blocks to the

@@ -208,31 +208,42 @@ def transformer_block(cfg, fam: Family, p, x, rope_positions, inv_freq,
     there (serving/multilora.py) — and defaults to the plain matmul.
     Keeping every matmul/norm/activation in ONE function is what makes
     the serving paths provably the same model — a drifted copy would
-    silently change logits."""
+    silently change logits.
+
+    The parts carry the scope names a device trace is read by
+    (`attn_proj`, `kv_write`, `mlp`; `rms_norm` opens `norm` and the
+    attention call its kernel's own, in ops/), the same as the
+    training forward's (models/llama.py). Scopes are HLO metadata: they change
+    no compiled instruction."""
     if proj is None:
         def proj(name, h, w):
             return qdot(h, w, cfg.dtype)
 
     b, s = x.shape[:2]
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q = proj("wq", h, p["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = proj("wk", h, p["wk"]).reshape(
-        b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = proj("wv", h, p["wv"]).reshape(
-        b, s, cfg.num_kv_heads, cfg.head_dim)
-    q = apply_rope(q, rope_positions, inv_freq)
-    k = apply_rope(k, rope_positions, inv_freq)
-    k_cache, v_cache = write_kv(k, v)
+    with jax.named_scope("attn_proj"):
+        q = proj("wq", h, p["wq"]).reshape(
+            b, s, cfg.num_heads, cfg.head_dim)
+        k = proj("wk", h, p["wk"]).reshape(
+            b, s, cfg.num_kv_heads, cfg.head_dim)
+        v = proj("wv", h, p["wv"]).reshape(
+            b, s, cfg.num_kv_heads, cfg.head_dim)
+        q = apply_rope(q, rope_positions, inv_freq)
+        k = apply_rope(k, rope_positions, inv_freq)
+    with jax.named_scope("kv_write"):
+        k_cache, v_cache = write_kv(k, v)
     out = attn(q, k_cache, v_cache)
-    x = x + proj("wo", out.reshape(b, s, cfg.q_dim), p["wo"])
+    with jax.named_scope("attn_proj"):
+        x = x + proj("wo", out.reshape(b, s, cfg.q_dim), p["wo"])
 
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    if fam.mlp is not None:
-        x = x + fam.mlp(cfg, p, h)
-    else:
-        gate = fam.gate_act(proj("w_gate", h, p["w_gate"]))
-        ff = gate * proj("w_up", h, p["w_up"])
-        x = x + proj("w_down", ff, p["w_down"])
+    with jax.named_scope("mlp"):
+        if fam.mlp is not None:
+            x = x + fam.mlp(cfg, p, h)
+        else:
+            gate = fam.gate_act(proj("w_gate", h, p["w_gate"]))
+            ff = gate * proj("w_up", h, p["w_up"])
+            x = x + proj("w_down", ff, p["w_down"])
     return x, (k_cache, v_cache)
 
 
@@ -266,6 +277,7 @@ class InferenceEngine:
 
     # -- model internals ---------------------------------------------------
 
+    @jax.named_scope("embed")
     def _embed(self, params, tokens):
         cfg = self.cfg
         # Mesh-aware (ops.embedding): a gather is fine single-chip, but a
@@ -276,6 +288,7 @@ class InferenceEngine:
             x = x * jnp.asarray(cfg.hidden_size ** 0.5, cfg.dtype)
         return x
 
+    @jax.named_scope("head")
     def _head(self, params, x):
         tied = "lm_head" not in params
         head = params["embed"].T if tied else params["lm_head"]
@@ -403,6 +416,7 @@ class InferenceEngine:
         return (2 * cfg.num_layers * batch * cells
                 * cfg.num_kv_heads * cfg.head_dim * itemsize)
 
+    @jax.named_scope("sample")
     def _sample(self, logits, rng, sp: SamplingParams):
         """-> (tokens [b], logprobs [b]). The logprob is the chosen
         token's log-softmax under the RAW model distribution

@@ -50,6 +50,7 @@ def _masked_mean(
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+@jax.named_scope("loss")
 def cross_entropy_loss(
     logits: jnp.ndarray,   # [b, s, vocab] fp32
     targets: jnp.ndarray,  # [b, s] int32
@@ -61,6 +62,7 @@ def cross_entropy_loss(
     return _masked_mean(logz - gold, mask)
 
 
+@jax.named_scope("loss")
 def chunked_cross_entropy_from_hidden(
     hidden: jnp.ndarray,    # [b, s, D] final (normed) hidden states
     head: jnp.ndarray,      # [D, vocab] unembedding matrix
@@ -324,7 +326,11 @@ class Trainer:
         # (the jit call) and `host_gap` (wall between consecutive
         # steps: input pipeline, checkpointing, logging). Goodput for
         # a trainer is step-time over (step + host_gap).
-        self.profiler = obs.PhaseProfiler(phases=obs.TRAIN_PHASES)
+        # In the JAX profiler's trace the phase is the span `train.step`
+        # (the dispatch); the host gap is the gap between two of them.
+        self.profiler = obs.PhaseProfiler(
+            phases=obs.TRAIN_PHASES,
+            annotate=jax.profiler.TraceAnnotation)
         self.phase_seconds = obs.get_or_create_histogram(
             reg, "train_step_phase_seconds",
             "Wall time per training phase: step (jit dispatch; the "
@@ -409,10 +415,11 @@ class Trainer:
                 lambda g, p: (g / denom).astype(p.dtype), gsum,
                 state.params)
             loss = lsum / denom
-        updates, opt_state = self.optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = self.optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss
 
     def init(self, rng: jax.Array) -> TrainState:

@@ -72,6 +72,139 @@ def test_exclusive_nesting_and_host_gap_residual():
     assert toks["prefill"] == 16 and toks["decode"] == 8
 
 
+class FakeAnnotations:
+    """Stands in for `jax.profiler.TraceAnnotation`: records what was
+    opened and closed, in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **stats):
+        import contextlib
+
+        @contextlib.contextmanager
+        def span():
+            self.log.append(("open", name, stats))
+            try:
+                yield
+            finally:
+                self.log.append(("close", name))
+        return span()
+
+
+def test_phases_are_also_annotated_with_their_name_and_tokens():
+    """With an `annotate` factory every phase is also a span
+    `sched.<phase>` with its tokens, nested under `sched.iteration`,
+    which carries the counts `begin_iteration` was given."""
+    clk, ann = FakeClock(), FakeAnnotations()
+    p = PhaseProfiler(clock=clk, wall_clock=clk, annotate=ann)
+    p.begin_iteration(active=3, pending=1)
+    with p.phase("admit"):
+        with p.phase("prefill", tokens=16):
+            clk.t = 2.0
+    with p.phase("decode"):
+        clk.t = 3.0
+    p.end_iteration()
+    assert ann.log == [
+        ("open", "sched.iteration", {"active": 3, "pending": 1}),
+        ("open", "sched.admit", {"tokens": 0}),
+        ("open", "sched.prefill", {"tokens": 16}),
+        ("close", "sched.prefill"), ("close", "sched.admit"),
+        ("open", "sched.decode", {"tokens": 0}), ("close", "sched.decode"),
+        ("close", "sched.iteration")]
+    # a pass that never reached end_iteration (the worker's `continue`
+    # on a failure) is closed by the next begin: spans never interleave
+    del ann.log[:]
+    p.begin_iteration()
+    p.begin_iteration()
+    p.end_iteration()
+    p.end_iteration()                       # a second end closes nothing
+    assert [e[:2] for e in ann.log] == [
+        ("open", "sched.iteration"), ("close", "sched.iteration"),
+        ("open", "sched.iteration"), ("close", "sched.iteration")]
+    # record() and add_tokens() are retrospective: no span
+    del ann.log[:]
+    p.record("host_gap", 0.5)
+    p.add_tokens("decode", 4)
+    assert ann.log == []
+
+
+def test_train_phases_are_annotated_under_their_own_prefix():
+    ann = FakeAnnotations()
+    p = PhaseProfiler(phases=obs.TRAIN_PHASES, annotate=ann)
+    with p.phase("step", tokens=4096):
+        pass
+    assert ann.log == [("open", "train.step", {"tokens": 4096}),
+                       ("close", "train.step")]
+
+
+def test_a_profiler_without_annotate_accounts_exactly_as_one_with():
+    def drive(p, clk):
+        p.begin_iteration(active=1)
+        with p.phase("admit"):
+            clk.t += 1.0
+            with p.phase("prefill", tokens=16):
+                clk.t += 2.0
+        with p.phase("decode", tokens=8):
+            clk.t += 2.0
+        clk.t += 0.5
+        p.end_iteration()
+        return p.totals(), p.phase_tokens(), p.goodput()
+
+    ca, cb = FakeClock(), FakeClock()
+    plain = drive(PhaseProfiler(clock=ca, wall_clock=ca), ca)
+    annotated = drive(PhaseProfiler(clock=cb, wall_clock=cb,
+                                    annotate=FakeAnnotations()), cb)
+    assert plain == annotated
+    assert plain[0]["admit"] == 1.0 and plain[0]["host_gap"] == 0.5
+
+
+def test_obs_imports_no_jax():
+    """`annotate` is handed in by the owner: obs itself stays
+    importable in a process without JAX."""
+    import subprocess
+    import sys
+
+    code = ("import sys; import kubeflow_tpu.obs, "
+            "kubeflow_tpu.obs.profiling, kubeflow_tpu.obs.timeline; "
+            "bad = [m for m in sys.modules if m == 'jax' "
+            "or m.startswith('jax.') or m.startswith('jaxlib')]; "
+            "print(bad); sys.exit(1 if bad else 0)")
+    proc = subprocess.run([sys.executable, "-c", code],
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+
+
+async def test_the_batcher_and_the_trainer_hand_over_the_profilers_own_span():
+    """The owners pass `jax.profiler.TraceAnnotation` itself: one
+    tracing path, the profiler's."""
+    from kubeflow_tpu.models import llama
+    from kubeflow_tpu.serving.continuous import ContinuousBatcher
+    from kubeflow_tpu.serving.engine import (LLAMA_FAMILY, EngineConfig,
+                                             InferenceEngine)
+
+    cfg = llama.LLAMA_TINY
+    engine = InferenceEngine(llama.init(jax.random.key(0), cfg), cfg,
+                             LLAMA_FAMILY, EngineConfig(max_len=64))
+    batcher = ContinuousBatcher(engine, asyncio.Lock(), max_slots=2)
+    try:
+        assert batcher.profiler._annotate is jax.profiler.TraceAnnotation
+    finally:
+        await batcher.close()
+
+    from kubeflow_tpu.parallel import MeshSpec, create_mesh
+    from kubeflow_tpu.train.trainer import TrainConfig, Trainer
+
+    tr = Trainer(
+        mesh=create_mesh(MeshSpec(data=2, fsdp=2, tensor=2)),
+        apply_fn=lambda p, t: llama.apply(p, cfg, t),
+        init_fn=lambda k: llama.init(k, cfg),
+        logical_axes=llama.param_logical_axes(cfg),
+        train_config=TrainConfig(), tracer=obs.Tracer())
+    assert tr.profiler._annotate is jax.profiler.TraceAnnotation
+    assert tr.profiler._span_prefix == "train."
+
+
 def test_unknown_phase_collapses_to_overflow_label():
     p = PhaseProfiler(phases=("decode",))
     p.record("decode", 1.0)
