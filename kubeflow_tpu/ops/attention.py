@@ -88,6 +88,29 @@ def _check_impl(impl: str, what: str) -> None:
             f"{what} impl must be 'auto', 'xla' or 'pallas', got {impl!r}")
 
 
+def layered_pool(k_pool, v_pool, layer):
+    """The paged entries' pool as `[L, num_blocks, block_size, n_kv,
+    hd]` plus an int32 scalar layer. What decides is the pool's rank: a
+    rank-5 pool (every layer's, the serving engines' scan carry) comes
+    with the layer to read; a rank-4 pool is one layer's and is lifted
+    to `[1, ...]` at layer 0 — a bitcast, so both reach one kernel
+    body. -> (k_pool, v_pool, layer)."""
+    if k_pool.shape != v_pool.shape:
+        raise ValueError(
+            f"k_pool/v_pool shapes disagree: {k_pool.shape} vs "
+            f"{v_pool.shape}")
+    if k_pool.ndim == 4 and layer is None:
+        return k_pool[None], v_pool[None], jnp.int32(0)
+    if k_pool.ndim == 5 and layer is not None:
+        return k_pool, v_pool, jnp.asarray(layer, jnp.int32)
+    raise ValueError(
+        "a pool [L, num_blocks, block_size, n_kv, hd] comes with a "
+        "layer, a pool [num_blocks, block_size, n_kv, hd] without one; "
+        f"got pools {k_pool.shape} / {v_pool.shape} and layer "
+        + ("None" if layer is None
+           else f"of shape {jnp.shape(layer)}"))
+
+
 def resolve_paged_prefill_impl(impl: str, *, vmem_bytes: int = 0) -> str:
     """Resolve a `paged_prefill_attention` impl request to "xla" or
     "pallas". "auto" is a rule on platform and shape, nothing else: the
@@ -230,8 +253,8 @@ def _attention(q, k, v, q_positions, kv_positions, *, causal, kv_mask,
 @jax.named_scope("paged_attention")
 def paged_attention(
     q: jnp.ndarray,            # [b, 1, n_q, hd] — single decode step
-    k_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
-    v_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
+    k_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
+    v_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
     block_table: jnp.ndarray,  # [b, blocks_per_slot] int32 physical ids
     q_positions: jnp.ndarray,  # [b, 1]
     kv_positions: jnp.ndarray, # [b, blocks_per_slot * block_size]
@@ -239,10 +262,16 @@ def paged_attention(
     causal: bool = True,
     kv_mask: jnp.ndarray | None = None,  # [b, blocks_per_slot * block_size]
     window: int | None = None,
+    layer=None,                # int32 scalar, with a rank-5 pool
     impl: str = "xla",
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Decode attention against a paged KV cache.
+
+    The pool is one layer's (rank 4) or every layer's with `layer`
+    saying which to read (rank 5; see `layered_pool`): a caller whose
+    layers share one array hands it over whole, and neither impl
+    materialises the layer's slice.
 
     impl: "auto" | "xla" | "pallas".
 
@@ -276,12 +305,9 @@ def paged_attention(
         raise ValueError(
             f"block_table must be [b={b}, blocks_per_slot], got "
             f"{block_table.shape}")
-    if k_pool.shape != v_pool.shape:
-        raise ValueError(
-            f"k_pool/v_pool shapes disagree: {k_pool.shape} vs "
-            f"{v_pool.shape}")
+    k_pool, v_pool, layer = layered_pool(k_pool, v_pool, layer)
     blocks_per_slot = block_table.shape[1]
-    block_size, n_kv, hd = k_pool.shape[1:]
+    block_size, n_kv, hd = k_pool.shape[2:]
     width = blocks_per_slot * block_size
     # Geometry mismatches (a pool rebuilt with a different block_size
     # than the tables/masks were laid out for) used to surface as an
@@ -313,9 +339,9 @@ def paged_attention(
 
         return paged_decode_attention(
             q, k_pool, v_pool, block_table, q_positions[:, 0],
-            kv_mask, window=window, interpret=interpret)
-    k = k_pool[block_table].reshape(b, width, n_kv, hd)
-    v = v_pool[block_table].reshape(b, width, n_kv, hd)
+            kv_mask, layer=layer, window=window, interpret=interpret)
+    k = k_pool[layer, block_table].reshape(b, width, n_kv, hd)
+    v = v_pool[layer, block_table].reshape(b, width, n_kv, hd)
     # impl="xla" said explicitly: "auto" would hand this single-token
     # step to the Pallas decode kernel on TPU, and "xla" must mean XLA
     # on every platform (it is the kernels' oracle).
@@ -330,14 +356,15 @@ def paged_prefill_attention(
     q: jnp.ndarray,            # [b, s, n_q, hd] — s new tokens per row
     k_new: jnp.ndarray,        # [b, s, n_kv, hd]
     v_new: jnp.ndarray,        # [b, s, n_kv, hd]
-    k_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
-    v_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
+    k_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
+    v_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
     block_table: jnp.ndarray,  # [b, blocks_per_slot] int32 physical ids
     q_start: jnp.ndarray,      # [b] int32 — append cursor per row
     q_lens: jnp.ndarray | None = None,  # [b] int32 — valid new tokens
     *,
     kv_mask: jnp.ndarray | None = None,  # [b, blocks_per_slot*block_size]
     window: int | None = None,
+    layer=None,                # int32 scalar, with a rank-5 pool
     impl: str = "xla",
     interpret: bool | None = None,
 ):
@@ -345,7 +372,10 @@ def paged_prefill_attention(
     them against everything written so far. Returns
     `(out [b, s, n_q, hd], k_pool, v_pool)` — the serving primitive
     behind chunked prefill (the chunk's tokens) and speculative verify
-    (the γ+1 draft-window tokens).
+    (the γ+1 draft-window tokens). The pools come back in the rank
+    they were given: with a rank-5 pool and `layer` (`layered_pool`)
+    only layer `layer`'s visited blocks are written, and every other
+    byte of the array stays where it is.
 
     Row r's token t lands at logical cell `q_start[r] + t` (physical:
     through the row's block table) and attends causally by absolute
@@ -368,17 +398,14 @@ def paged_prefill_attention(
       kernel's budget (`resolve_paged_prefill_impl`), xla otherwise.
     """
     b, s, n_q, hd = q.shape
-    n_kv = k_pool.shape[2]
-    if k_pool.shape != v_pool.shape:
-        raise ValueError(
-            f"k_pool/v_pool shapes disagree: {k_pool.shape} vs "
-            f"{v_pool.shape}")
+    given = k_pool.shape
+    k_pool, v_pool, layer = layered_pool(k_pool, v_pool, layer)
+    block_size, n_kv = k_pool.shape[2:4]
     if block_table.ndim != 2 or block_table.shape[0] != b:
         raise ValueError(
             f"block_table must be [b={b}, blocks_per_slot], got "
             f"{block_table.shape}")
     blocks_per_slot = block_table.shape[1]
-    block_size = k_pool.shape[1]
     width = blocks_per_slot * block_size
     if q_lens is None:
         q_lens = jnp.full((b,), s, jnp.int32)
@@ -397,10 +424,11 @@ def paged_prefill_attention(
     _impl_counts["paged_prefill"] += 1
     _impl_counts["paged_prefill_" + impl] += 1
     if impl == "pallas":
-        return paged_prefill_append(
+        out, k_pool, v_pool = paged_prefill_append(
             q, k_new, v_new, k_pool, v_pool, block_table,
-            q_start, q_lens, kv_mask, window=window,
+            q_start, q_lens, kv_mask, layer=layer, window=window,
             interpret=interpret)
+        return out, k_pool.reshape(given), v_pool.reshape(given)
     # XLA reference: scatter the new cells through the table (invalid
     # tokens to the trash block — the pool's garbage-write convention),
     # then gather and attend with the shared fp32 path.
@@ -411,13 +439,13 @@ def paged_prefill_attention(
     blk = jnp.take_along_axis(block_table, safe // block_size, axis=1)
     blk = jnp.where(valid, blk, 0)
     off = safe % block_size
-    k_pool = k_pool.at[blk, off].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(v_new.astype(v_pool.dtype))
-    k = k_pool[block_table].reshape(b, width, n_kv, hd)
-    v = v_pool[block_table].reshape(b, width, n_kv, hd)
+    k_pool = k_pool.at[layer, blk, off].set(k_new.astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, blk, off].set(v_new.astype(v_pool.dtype))
+    k = k_pool[layer, block_table].reshape(b, width, n_kv, hd)
+    v = v_pool[layer, block_table].reshape(b, width, n_kv, hd)
     kv_positions = jnp.broadcast_to(
         jnp.arange(width, dtype=jnp.int32)[None, :], (b, width))
     out = _xla_attention(
         q, k, v, pos, kv_positions, causal=True, kv_mask=kv_mask,
         window=window)
-    return out, k_pool, v_pool
+    return out, k_pool.reshape(given), v_pool.reshape(given)

@@ -13,6 +13,15 @@ in-kernel instead:
   are scalar-prefetched, so the K/V BlockSpec index map can resolve
   `table[row, j]` before the body runs and DMA only that physical
   block from the pool;
+- the pool is rank 5, `[L, num_blocks, block_size, n_kv, hd]`: every
+  layer's, as the serving engines' layer scan carries it, with the
+  LAYER a third scalar-prefetched operand. The index map returns
+  `(layer, table[row, j], 0, 0, 0)` over a squeezed leading block
+  dimension, so the body sees one block as before and no caller takes
+  a layer's slice first (for a Pallas operand that slice is a copy of
+  the whole layer: 268 MB a layer a step at Mistral-7B's pool). One
+  layer's pool (rank 4, no `layer`) is lifted to `[1, ...]` at layer
+  0, a bitcast, and runs the same body;
 - iterations past the cursor block (and, with a sliding window, before
   the window's first block) are CLAMPED to the boundary — a repeated
   physical index means no new DMA, so HBM traffic tracks the cache
@@ -54,16 +63,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kubeflow_tpu.ops.attention import NEG_INF
+from kubeflow_tpu.ops.attention import NEG_INF, layered_pool
 from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
 
 
-def _kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
-            acc, m_scr, l_scr, *, scale, window, block_size, nb, n_kv,
-            group):
-    # tab_ref is consumed by the BlockSpec index maps (that's the whole
-    # point); the body only needs the cursor.
-    del tab_ref
+def _kernel(pos_ref, tab_ref, layer_ref, q_ref, k_ref, v_ref, mask_ref,
+            o_ref, acc, m_scr, l_scr, *, scale, window, block_size, nb,
+            n_kv, group):
+    # tab_ref and layer_ref are consumed by the BlockSpec index maps
+    # (that's the whole point); the body only needs the cursor.
+    del tab_ref, layer_ref
     b_i, bj = pl.program_id(0), pl.program_id(1)
     pos = pos_ref[b_i]
 
@@ -130,12 +139,13 @@ def _kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
 
 def paged_decode_attention(
     q: jnp.ndarray,            # [b, 1, n_q, hd]
-    k_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
-    v_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
+    k_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
+    v_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
     block_table: jnp.ndarray,  # [b, blocks_per_slot] int32 physical ids
     q_positions: jnp.ndarray,  # [b] int32 — each row's cursor
     kv_mask: jnp.ndarray | None = None,  # [b, blocks_per_slot*block_size]
     *,
+    layer=None,                # int32 scalar, with a rank-5 pool
     window: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -150,11 +160,8 @@ def paged_decode_attention(
     if sq != 1:
         raise ValueError(
             f"paged_decode_attention is s=1 only, got sq={sq}")
-    if k_pool.shape != v_pool.shape:
-        raise ValueError(
-            f"k_pool/v_pool shapes disagree: {k_pool.shape} vs "
-            f"{v_pool.shape}")
-    num_blocks, block_size, n_kv, hd_kv = k_pool.shape
+    k_pool, v_pool, layer = layered_pool(k_pool, v_pool, layer)
+    block_size, n_kv, hd_kv = k_pool.shape[2:]
     if hd_kv != hd:
         raise ValueError(
             f"head dim mismatch: q has {hd}, pool has {hd_kv}")
@@ -192,27 +199,29 @@ def paged_decode_attention(
         lo = jnp.maximum((pos - window + 1) // block_size, 0)
         return jnp.clip(bj, lo, hi)
 
-    def kv_map(b_i, bj, pos_ref, tab_ref):
-        # The indirection: logical block -> physical pool block.
-        return (tab_ref[b_i, _clamp(bj, pos_ref[b_i])], 0, 0, 0)
+    def kv_map(b_i, bj, pos_ref, tab_ref, layer_ref):
+        # The indirection: logical block -> physical pool block of the
+        # prefetched layer (whose block dimension is squeezed away).
+        return (layer_ref[0], tab_ref[b_i, _clamp(bj, pos_ref[b_i])],
+                0, 0, 0)
 
-    def mask_map(b_i, bj, pos_ref, tab_ref):
+    def mask_map(b_i, bj, pos_ref, tab_ref, layer_ref):
         # The mask is laid out logically, so no table lookup here.
         return (b_i, _clamp(bj, pos_ref[b_i]), 0, 0)
 
+    def row_map(b_i, bj, pos_ref, tab_ref, layer_ref):
+        return (b_i, 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, n_q, hd),
-                         lambda b_i, bj, pos_ref, tab_ref: (b_i, 0, 0, 0)),
-            pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
+            pl.BlockSpec((1, 1, n_q, hd), row_map),
+            pl.BlockSpec((None, 1, block_size, n_kv, hd), kv_map),
+            pl.BlockSpec((None, 1, block_size, n_kv, hd), kv_map),
             pl.BlockSpec((1, 1, 1, block_size), mask_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, n_q, hd),
-            lambda b_i, bj, pos_ref, tab_ref: (b_i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, n_q, hd), row_map),
         scratch_shapes=[
             pltpu.VMEM((n_q, hd), jnp.float32),
             pltpu.VMEM((n_q, 128), jnp.float32),
@@ -229,5 +238,5 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(positions, table, q, k_pool, v_pool,
+    )(positions, table, layer.reshape(1), q, k_pool, v_pool,
       kv_mask.astype(jnp.int32).reshape(b, nb, 1, block_size))

@@ -15,6 +15,16 @@ kernel fuses the two:
   body runs and DMA only live physical blocks (iterations outside
   [window lo, append hi] are clamped — a repeated physical index skips
   the DMA, as in paged_attention.py);
+- the pool is rank 5, `[L, num_blocks, block_size, n_kv, hd]`, and the
+  LAYER a fourth scalar-prefetched operand, as in paged_attention.py:
+  the index maps return `(layer, table[row, j], 0, 0, 0)` over a
+  squeezed leading block dimension. `input_output_aliases` covers the
+  WHOLE array, so a call rewrites the visited blocks of layer `layer`
+  and every other byte (the other layers, the unvisited blocks) stays
+  where it is; a caller whose layers share one array (the engines'
+  scan carry) neither slices a layer out nor writes it back. One
+  layer's pool (rank 4, no `layer`) is lifted to `[1, ...]` at layer
+  0 and comes back in the rank it was given;
 - per visited block the body MERGES the new tokens in-register (a
   one-hot [block_size, s] matmul scatters token t to cell
   `q_start + t`), writes the merged block back to the pool via
@@ -57,15 +67,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kubeflow_tpu.ops.attention import NEG_INF
+from kubeflow_tpu.ops.attention import NEG_INF, layered_pool
 from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
 
 
-def _kernel(qs_ref, ql_ref, tab_ref, q_ref, kn_ref, vn_ref, kp_ref,
-            vp_ref, mask_ref, o_ref, ko_ref, vo_ref, acc, m_scr, l_scr,
-            *, scale, window, block_size, s, nb, n_kv, group, hd):
-    # tab_ref feeds the BlockSpec index maps; the body needs cursors.
-    del tab_ref
+def _kernel(qs_ref, ql_ref, tab_ref, layer_ref, q_ref, kn_ref, vn_ref,
+            kp_ref, vp_ref, mask_ref, o_ref, ko_ref, vo_ref, acc, m_scr,
+            l_scr, *, scale, window, block_size, s, nb, n_kv, group, hd):
+    # tab_ref and layer_ref feed the BlockSpec index maps; the body
+    # needs cursors.
+    del tab_ref, layer_ref
     b_i, bj = pl.program_id(0), pl.program_id(1)
     start = qs_ref[b_i]
     n_new = ql_ref[b_i]
@@ -200,13 +211,14 @@ def paged_prefill_append(
     q: jnp.ndarray,            # [b, s, n_q, hd]
     k_new: jnp.ndarray,        # [b, s, n_kv, hd]
     v_new: jnp.ndarray,        # [b, s, n_kv, hd]
-    k_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
-    v_pool: jnp.ndarray,       # [num_blocks, block_size, n_kv, hd]
+    k_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
+    v_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
     block_table: jnp.ndarray,  # [b, blocks_per_slot] int32 physical ids
     q_start: jnp.ndarray,      # [b] int32 — append cursor per row
     q_lens: jnp.ndarray,       # [b] int32 — valid new tokens per row
     kv_mask: jnp.ndarray | None = None,  # [b, blocks_per_slot*block_size]
     *,
+    layer=None,                # int32 scalar, with a rank-5 pool
     window: int | None = None,
     interpret: bool | None = None,
 ):
@@ -223,11 +235,9 @@ def paged_prefill_append(
         raise ValueError(
             f"k_new/v_new must be [b={b}, s={s}, n_kv, hd], got "
             f"{k_new.shape} / {v_new.shape}")
-    if k_pool.shape != v_pool.shape:
-        raise ValueError(
-            f"k_pool/v_pool shapes disagree: {k_pool.shape} vs "
-            f"{v_pool.shape}")
-    num_blocks, block_size, n_kv, hd_kv = k_pool.shape
+    given = k_pool.shape
+    k_pool, v_pool, layer = layered_pool(k_pool, v_pool, layer)
+    block_size, n_kv, hd_kv = k_pool.shape[2:]
     if hd_kv != hd:
         raise ValueError(
             f"head dim mismatch: q has {hd}, pool has {hd_kv}")
@@ -263,31 +273,31 @@ def paged_prefill_append(
         lo = jnp.maximum((start - window + 1) // block_size, 0)
         return jnp.clip(bj, lo, hi)
 
-    def kv_map(b_i, bj, qs_ref, ql_ref, tab_ref):
-        return (tab_ref[b_i, _clamp(bj, qs_ref[b_i])], 0, 0, 0)
+    def kv_map(b_i, bj, qs_ref, ql_ref, tab_ref, layer_ref):
+        # the prefetched layer's block dimension is squeezed away
+        return (layer_ref[0], tab_ref[b_i, _clamp(bj, qs_ref[b_i])],
+                0, 0, 0)
 
-    def mask_map(b_i, bj, qs_ref, ql_ref, tab_ref):
+    def mask_map(b_i, bj, qs_ref, ql_ref, tab_ref, layer_ref):
         return (b_i, _clamp(bj, qs_ref[b_i]), 0, 0)
 
-    def row_map(b_i, bj, qs_ref, ql_ref, tab_ref):
+    def row_map(b_i, bj, qs_ref, ql_ref, tab_ref, layer_ref):
         return (b_i, 0, 0, 0)
 
+    kv_block = pl.BlockSpec((None, 1, block_size, n_kv, hd), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, nb),
         in_specs=[
             pl.BlockSpec((1, s, n_q, hd), row_map),
             pl.BlockSpec((1, s, n_kv, hd), row_map),
             pl.BlockSpec((1, s, n_kv, hd), row_map),
-            pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
+            kv_block,
+            kv_block,
             pl.BlockSpec((1, 1, 1, block_size), mask_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, s, n_q, hd), row_map),
-            pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, block_size, n_kv, hd), kv_map),
-        ],
+        out_specs=[pl.BlockSpec((1, s, n_q, hd), row_map),
+                   kv_block, kv_block],
         scratch_shapes=[
             pltpu.VMEM((s * n_q, hd), jnp.float32),
             pltpu.VMEM((s * n_q, 128), jnp.float32),
@@ -298,9 +308,10 @@ def paged_prefill_append(
         _kernel, scale=hd**-0.5, window=window, block_size=block_size,
         s=s, nb=nb, n_kv=n_kv, group=group, hd=hd,
     )
-    # operand order: 3 prefetch scalars, then q, k_new, v_new, k_pool,
-    # v_pool, kv_mask — the pools (operands 6/7) alias outputs 1/2.
-    return pl.pallas_call(
+    # operand order: 4 prefetch scalars, then q, k_new, v_new, k_pool,
+    # v_pool, kv_mask — the WHOLE pools (operands 7/8) alias outputs
+    # 1/2, so the layers the index map never names keep their bytes.
+    out, k_pool, v_pool = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -308,11 +319,12 @@ def paged_prefill_append(
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
-        input_output_aliases={6: 1, 7: 2},
+        input_output_aliases={7: 1, 8: 2},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=max(16 * 2**20, vmem_bytes(
                 s, n_q, n_kv, hd, block_size, q.dtype.itemsize))),
         interpret=interpret,
         name="prefill_append",
-    )(starts, lens, table, q, k_new, v_new, k_pool, v_pool,
-      kv_mask.astype(jnp.int32).reshape(b, nb, 1, block_size))
+    )(starts, lens, table, layer.reshape(1), q, k_new, v_new, k_pool,
+      v_pool, kv_mask.astype(jnp.int32).reshape(b, nb, 1, block_size))
+    return out, k_pool.reshape(given), v_pool.reshape(given)

@@ -736,37 +736,35 @@ class ContinuousEngine:
                 p, ab, li = scanned
                 proj = lora_proj(ab, st.aid,
                                  eng.adapter_pack.scaling, cfg)
-            cell = {}
 
             def write_kv(k, v):
                 # one [S]-row scatter into the shared block pool:
-                # slot s's token lands at (table[s, at//bs], at%bs)
-                k2 = k_all.at[li, write_blk, write_off].set(
-                    k[:, 0].astype(k_all.dtype))
-                v2 = v_all.at[li, write_blk, write_off].set(
-                    v[:, 0].astype(v_all.dtype))
-                cell["k"], cell["v"] = k2, v2
-                return (jax.lax.dynamic_index_in_dim(
-                            k2, li, 0, keepdims=False),
-                        jax.lax.dynamic_index_in_dim(
-                            v2, li, 0, keepdims=False))
+                # slot s's token lands at (table[s, at//bs], at%bs).
+                # The WHOLE pool goes on to the attention call: a
+                # layer's slice taken here would be a 268 MB copy a
+                # layer on the chip (PERF.md, PR 26)
+                return (k_all.at[li, write_blk, write_off].set(
+                            k[:, 0].astype(k_all.dtype)),
+                        v_all.at[li, write_blk, write_off].set(
+                            v[:, 0].astype(v_all.dtype)))
 
             def attn(q, kp, vp):
-                # kp/vp are this layer's block POOL; the paged path
-                # gathers each row's K/V through its block table.
-                # Insert-time compaction keeps cell index == logical
-                # token position, so masking semantics (and bits — see
-                # paged_attention's docstring) match the dense path.
+                # kp/vp are every layer's block POOL and `li` says
+                # which to read; the paged path gathers each row's K/V
+                # through its block table. Insert-time compaction keeps
+                # cell index == logical token position, so masking
+                # semantics (and bits — see paged_attention's
+                # docstring) match the dense path.
                 return paged_attention(
                     q, kp, vp, st.block_table, positions, kv_positions,
                     causal=True, kv_mask=kv_valid,
                     window=getattr(cfg, "sliding_window", None),
-                    impl=self.attention_impl)
+                    layer=li, impl=self.attention_impl)
 
-            x, _ = transformer_block(
+            x, (k_all, v_all) = transformer_block(
                 cfg, fam, p, x, rope_positions, inv_freq, write_kv,
                 attn, proj)
-            return (x, cell["k"], cell["v"]), None
+            return (x, k_all, v_all), None
 
         layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         xs = ((params["blocks"], layer_ids) if adapters is None
@@ -860,26 +858,20 @@ class ContinuousEngine:
 
             def write_kv(k, v):
                 # defer the write: the fused op scatters K/V through
-                # the block table and attends in one pass
+                # the block table and attends in one pass, over the
+                # carry itself (every layer's pool, never a slice)
                 cell["new"] = (k, v)
-                return (jax.lax.dynamic_index_in_dim(
-                            k_all, li, 0, keepdims=False),
-                        jax.lax.dynamic_index_in_dim(
-                            v_all, li, 0, keepdims=False))
+                return k_all, v_all
 
             def attn(q, kp, vp):
                 kn, vn = cell["new"]
-                out, kp2, vp2 = paged_prefill_attention(
+                # the op's pools are the carry with layer `li`'s
+                # visited blocks rewritten in place
+                out, cell["k"], cell["v"] = paged_prefill_attention(
                     q, kn, vn, kp, vp, table, start, n_valid,
                     kv_mask=kv_valid,
                     window=getattr(cfg, "sliding_window", None),
-                    impl=self.paged_attention_impl)
-                with jax.named_scope("kv_write"):
-                    # the layer's pool slice back into the scan carry
-                    cell["k"] = jax.lax.dynamic_update_index_in_dim(
-                        k_all, kp2, li, 0)
-                    cell["v"] = jax.lax.dynamic_update_index_in_dim(
-                        v_all, vp2, li, 0)
+                    layer=li, impl=self.paged_attention_impl)
                 return out
 
             x, _ = transformer_block(

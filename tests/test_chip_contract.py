@@ -429,5 +429,7 @@ def test_smoke_kernels_phase_passes_interpreted_at_a_tiny_size():
                                interpret=True)
     assert result["ok"], result["problems"]
     assert result["kernels"]["empty_visible_set_rows_are_zero"]["ok"]
-    assert len(result["kernels"]) == 11
+    assert len(result["kernels"]) == 15
+    assert result["kernels"][
+        "prefill_append[s=16,layer=1 of 2].other_layers_untouched"]["ok"]
     assert set(result["auto"].values()) == {"xla"}      # this backend

@@ -72,14 +72,40 @@ def _oracle(q, kp, vp, table, pos, mask, kv_pos, window=None):
                            impl="xla")
 
 
+def _layers(pool, layer, seed, n=3):
+    """`pool` as layer `layer` of an `n`-layer pool whose other layers
+    hold other numbers: a read of the wrong layer cannot pass."""
+    rng = np.random.default_rng(1000 + seed)
+    out = np.asarray(rng.normal(size=(n, *pool.shape)), np.float32)
+    out[layer] = np.asarray(pool)
+    return jnp.asarray(out)
+
+
+@pytest.mark.parametrize("layer", [None, 0, 1, 2])
 @pytest.mark.parametrize("n_q,n_kv", [(8, 2), (4, 4), (8, 1)])
-def test_kernel_matches_oracle_across_gqa_ratios(n_q, n_kv):
+def test_kernel_matches_oracle_across_gqa_ratios(n_q, n_kv, layer):
+    """`layer=None`: one layer's pool (rank 4). Otherwise the same pool
+    as layer `layer` of three: the layered call must equal the rank-4
+    call bit for bit in either implementation (one kernel body, one
+    gather), and the kernel the oracle to fp32 tolerance."""
     for seed in (0, 1):
         q, kp, vp, table, pos, mask, kv_pos = _mk(
             seed, n_q=n_q, n_kv=n_kv)
         want = _oracle(q, kp, vp, table, pos, mask, kv_pos)
         got = paged_decode_attention(q, kp, vp, table, pos, mask,
                                      interpret=True)
+        if layer is not None:
+            kl, vl = _layers(kp, layer, seed), _layers(vp, layer, seed + 7)
+            li = jnp.int32(layer)
+            np.testing.assert_array_equal(
+                np.asarray(paged_attention(
+                    q, kl, vl, table, pos[:, None], kv_pos, causal=True,
+                    kv_mask=mask, layer=li, impl="xla")),
+                np.asarray(want))
+            layered = paged_decode_attention(
+                q, kl, vl, table, pos, mask, layer=li, interpret=True)
+            np.testing.assert_array_equal(np.asarray(layered),
+                                          np.asarray(got))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    **TOL)
 
@@ -186,6 +212,15 @@ def test_dispatcher_validation_doors():
     with pytest.raises(ValueError, match="block_table"):
         paged_attention(q, kp, vp, table[0], pos[:, None], kv_pos,
                         causal=True)
+    # a pool of every layer comes with a layer, one layer's without:
+    # the message carries the shapes
+    with pytest.raises(ValueError, match=r"\(32, 8, 2, 32\).*layer of"):
+        paged_attention(q, kp, vp, table, pos[:, None], kv_pos,
+                        causal=True, layer=jnp.int32(0))
+    with pytest.raises(ValueError,
+                       match=r"\(1, 32, 8, 2, 32\).*layer None"):
+        paged_attention(q, kp[None], vp[None], table, pos[:, None],
+                        kv_pos, causal=True)
 
 
 def test_kernel_validation_doors():
@@ -201,6 +236,13 @@ def test_kernel_validation_doors():
                                mask[:, :-1], interpret=True)
     with pytest.raises(ValueError, match="grouped"):
         paged_decode_attention(q[:, :, :3], kp, vp, table, pos,
+                               interpret=True)
+    with pytest.raises(ValueError, match=r"\(32, 8, 2, 32\).*layer of"):
+        paged_decode_attention(q, kp, vp, table, pos, layer=1,
+                               interpret=True)
+    with pytest.raises(ValueError,
+                       match=r"\(1, 32, 8, 2, 32\).*layer None"):
+        paged_decode_attention(q, kp[None], vp[None], table, pos,
                                interpret=True)
 
 

@@ -189,6 +189,58 @@ def _solo(engine, prompt, max_new):
         jnp.asarray([prompt], jnp.int32), max_new=max_new))[0].tolist()
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters
+    (scan and cond bodies, calls)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("program", ["_decode_one", "_paged_forward"])
+def test_step_programs_take_no_layer_slice_of_the_pool(program, impl):
+    """The pool `[L, num_blocks, block_size, n_kv, hd]` is the layer
+    scan's carry, and the attention ops take it whole with the layer's
+    index. A layer's slice taken out of it (or put back into it) is a
+    copy of the whole layer on the chip — 47 % of a decode step and
+    59 % of a prefill slice once (PERF.md, PR 26)."""
+    engine, cfg = _llama_engine()
+    ce = ContinuousEngine(engine, max_slots=2, block_size=8,
+                          paged_attention_impl=impl)
+    st = ce.init_slots()
+    rng = jax.random.key(0)
+    if program == "_decode_one":
+        sp = engine._resolve_sampling(
+            np.zeros(2, np.float32), np.zeros(2, np.int64),
+            np.ones(2, np.float32), rng, batch=2)[0]
+        jaxpr = jax.make_jaxpr(lambda st: ce._decode_one(
+            engine.params, None, st, sp, rng))(st)
+    else:
+        jaxpr = jax.make_jaxpr(lambda st: ce._paged_forward(
+            engine.params, None, st, jnp.asarray([1]),
+            jnp.zeros((1, 4), jnp.int32), jnp.asarray([4]),
+            jnp.asarray([0])))(st)
+
+    def a_layer(var):
+        shape = var.aval.shape
+        return (shape[-4:] == st.k.shape[1:]
+                and int(np.prod(shape[:-4])) == 1)
+
+    names = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+    assert "scan" in names and (impl == "xla" or "pallas_call" in names)
+    moved = [str(e) for e in _eqns(jaxpr.jaxpr)
+             if (e.primitive.name == "dynamic_slice"
+                 and a_layer(e.outvars[0]))
+             or (e.primitive.name == "dynamic_update_slice"
+                 and a_layer(e.invars[1]))]
+    assert not moved, moved
+
+
 @pytest.mark.slow
 async def test_paged_parity_and_prefix_reuse_llama():
     """The tentpole contract end-to-end: repeated and prefix-sharing

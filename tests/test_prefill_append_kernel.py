@@ -33,6 +33,7 @@ from kubeflow_tpu.ops.attention import (
     paged_prefill_attention,
     resolve_paged_prefill_impl,
 )
+from kubeflow_tpu.ops.pallas.prefill_append import paged_prefill_append
 from kubeflow_tpu.serving import EngineConfig, InferenceEngine, LLAMA_FAMILY
 from kubeflow_tpu.serving.continuous import ContinuousBatcher, ContinuousEngine
 
@@ -74,11 +75,52 @@ def _mk(seed, b=3, s=5, n_q=8, n_kv=2, hd=32, bs=8, nb=6,
             jnp.asarray(table), jnp.asarray(starts), jnp.asarray(lens))
 
 
-def _run(args, impl, window=None, mask=None):
+def _run(args, impl, window=None, mask=None, layer=None):
     q, kn, vn, kp, vp, table, starts, lens = args
     return paged_prefill_attention(
         q, kn, vn, kp, vp, table, starts, lens, kv_mask=mask,
-        window=window, impl=impl, interpret=True)
+        window=window, layer=layer, impl=impl, interpret=True)
+
+
+def _visited(args):
+    """Physical blocks some row's append visits (and the trash block)."""
+    table, starts = np.asarray(args[5]), np.asarray(args[6])
+    s, bs = args[0].shape[1], args[3].shape[1]
+    visited = {0}
+    for i in range(table.shape[0]):
+        last = (int(starts[i]) + s - 1) // bs
+        visited.update(int(b) for b in table[i, :last + 1])
+    return visited
+
+
+def _check_layered(args, layer, n=3):
+    """`args`' pool as layer `layer` of `n` holding other numbers. In
+    either implementation the layered call equals the rank-4 call on
+    `pool[layer]` bit for bit (one kernel body, one scatter), and
+    leaves every other layer and every unvisited block of `layer`
+    byte-identical: the whole array is aliased, its other bytes stay."""
+    rng = np.random.default_rng(1000 + layer)
+    pools = []
+    for one in args[3:5]:
+        every = np.asarray(rng.normal(size=(n, *one.shape)), np.float32)
+        every[layer] = np.asarray(one)
+        pools.append(every)
+    largs = (*args[:3], jnp.asarray(pools[0]), jnp.asarray(pools[1]),
+             *args[5:])
+    unvisited = sorted(set(range(args[3].shape[0])) - _visited(args))
+    assert unvisited
+    for impl in ("xla", "pallas"):
+        wo, wk, wv = _run(args, impl)
+        go, gk, gv = _run(largs, impl, layer=jnp.int32(layer))
+        np.testing.assert_array_equal(np.asarray(go), np.asarray(wo))
+        for got, want, before in ((gk, wk, pools[0]), (gv, wv, pools[1])):
+            got = np.asarray(got)
+            assert got.shape == before.shape
+            np.testing.assert_array_equal(got[layer], np.asarray(want))
+            np.testing.assert_array_equal(got[layer, unvisited],
+                                          before[layer, unvisited])
+            others = [i for i in range(n) if i != layer]
+            np.testing.assert_array_equal(got[others], before[others])
 
 
 def _check(args, window=None, mask=None):
@@ -99,10 +141,14 @@ def _check(args, window=None, mask=None):
                                   np.asarray(wv)[1:])
 
 
+@pytest.mark.parametrize("layer", [None, 0, 1, 2])
 @pytest.mark.parametrize("n_q,n_kv", [(8, 2), (4, 4), (8, 1)])
-def test_kernel_matches_oracle_across_gqa_ratios(n_q, n_kv):
+def test_kernel_matches_oracle_across_gqa_ratios(n_q, n_kv, layer):
     for seed in (0, 1):
-        _check(_mk(seed, n_q=n_q, n_kv=n_kv))
+        args = _mk(seed, n_q=n_q, n_kv=n_kv)
+        _check(args)
+        if layer is not None:
+            _check_layered(args, layer)
 
 
 def test_kernel_matches_oracle_ragged_cursors():
@@ -161,15 +207,9 @@ def test_kernel_preserves_unvisited_blocks():
     nobody) must come back byte-identical — the pool is shared state;
     a stray DMA would corrupt OTHER requests' KV."""
     args = _mk(6, b=2, s=4, starts=[0, 5])
-    _, kp0, vp0 = args[3], args[3], args[4]
     kp_before = np.asarray(args[3]).copy()
     _, gk, gv = _run(args, "pallas")
-    table = np.asarray(args[5])
-    starts, s = np.asarray(args[6]), 4
-    visited = {0}
-    for i in range(2):
-        last = (int(starts[i]) + s - 1) // 8
-        visited.update(int(b) for b in table[i, :last + 1])
+    visited = _visited(args)
     for blk in range(kp_before.shape[0]):
         if blk not in visited:
             np.testing.assert_array_equal(np.asarray(gk)[blk],
@@ -209,6 +249,16 @@ def test_dispatcher_validation_doors():
         paged_prefill_attention(
             q, kn, vn, kp, vp, table, starts,
             kv_mask=jnp.ones((3, 40), bool))
+    # a pool of every layer comes with a layer, one layer's without:
+    # the message carries the shapes (dispatcher and kernel alike)
+    for fn in (paged_prefill_attention, paged_prefill_append):
+        with pytest.raises(ValueError,
+                           match=r"\(64, 8, 2, 32\).*layer of"):
+            fn(q, kn, vn, kp, vp, table, starts, lens,
+               layer=jnp.int32(0))
+        with pytest.raises(ValueError,
+                           match=r"\(1, 64, 8, 2, 32\).*layer None"):
+            fn(q, kn, vn, kp[None], vp[None], table, starts, lens)
 
 
 # -- continuous engine end-to-end token parity ------------------------------

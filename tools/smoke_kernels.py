@@ -6,6 +6,10 @@ compiled (`interpret=False`) at the `llama3-1b` head shapes, against
 
   paged_attention   s = 1 over a 2048-cell block table
   prefill_append    s = 5 and s = 256 (one serving prefill chunk)
+                    both also as the serving engines call them: the
+                    pool as layer 1 of a two-layer array and the layer
+                    as an operand (the squeezed rank-5 BlockSpec, the
+                    alias over the whole array)
   decode_attention  s = 1 over a 2048-cell dense cache
   flash_attention   forward and backward at seq 2048
 
@@ -95,9 +99,9 @@ def run(*, n_q: int, n_kv: int, hd: int, block_size: int, cells: int,
               f"tolerance={tol:.6f} {'ok' if ok else 'FAIL'} "
               f"(first call {seconds:.1f}s)", flush=True)
 
-    def timed(fn, *args):
+    def timed(fn, *args, **kwargs):
         t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
+        out = jax.block_until_ready(fn(*args, **kwargs))
         return out, time.perf_counter() - t0
 
     # --- single-token cases: 8 rows, cursors from the first cell to the
@@ -131,6 +135,17 @@ def run(*, n_q: int, n_kv: int, hd: int, block_size: int, cells: int,
     check("paged_attention[s=1]", out, ref, secs, keep)
     empty_row_zero = not np.asarray(out, np.float32)[2].any()
 
+    def layered(pool, layer):
+        # `pool` as layer `layer` of layer + 1, the others other numbers
+        return jnp.stack([normal(*pool.shape)] * layer + [pool])
+
+    out, secs = timed(jax.jit(
+        lambda q, kp, vp, t, p, m, layer: paged_decode_attention(
+            q, kp, vp, t, p, m, layer=layer, interpret=interpret)),
+        q1, layered(k_pool, 1), layered(v_pool, 1), table, pos, jmask,
+        jnp.int32(1))
+    check("paged_attention[s=1,layer=1 of 2]", out, ref, secs, keep)
+
     # dense decode cache: the same rows, gathered
     out, secs = timed(jax.jit(
         lambda q, k, v, p, m: decode_attention(
@@ -146,23 +161,40 @@ def run(*, n_q: int, n_kv: int, hd: int, block_size: int, cells: int,
     # attention output of the valid tokens and the pool the call leaves
     # (block 0 aside: the XLA path parks padding tokens there, the
     # kernel writes nothing for them)
-    def prefill_case(s, starts, lens):
+    def prefill_case(s, starts, lens, layer=None):
         g = len(starts)
         q, kn, vn = normal(g, s, n_q, hd), normal(g, s, n_kv, hd), \
             normal(g, s, n_kv, hd)
         tab = table[:g]
         qs, ql = jnp.asarray(starts, jnp.int32), jnp.asarray(lens, jnp.int32)
         m = jnp.ones((g, cells), bool)
+        name = f"prefill_append[s={s}]"
+        kp, vp = k_pool, v_pool
+        if layer is not None:
+            name = f"prefill_append[s={s},layer={layer} of {layer + 1}]"
+            kp, vp = layered(k_pool, layer), layered(v_pool, layer)
         (out, kp2, vp2), secs = timed(jax.jit(
-            lambda *a: paged_prefill_append(*a, interpret=interpret)),
-            q, kn, vn, k_pool, v_pool, tab, qs, ql, m)
+            lambda *a, layer: paged_prefill_append(
+                *a, layer=layer, interpret=interpret)),
+            q, kn, vn, kp, vp, tab, qs, ql, m,
+            layer=None if layer is None else jnp.int32(layer))
         with jax.default_matmul_precision("highest"):
             ref, kp_ref, vp_ref = attention.paged_prefill_attention(
                 q, kn, vn, k_pool, v_pool, tab, qs, ql, kv_mask=m,
                 impl="xla")
         valid = np.arange(s)[None, :] < np.asarray(lens)[:, None]
-        check(f"prefill_append[s={s}]", out, ref, secs, valid)
-        check(f"prefill_append[s={s}].pool",
+        check(name, out, ref, secs, valid)
+        if layer is not None:
+            # the alias covers the whole array: the other layers come
+            # back as they went in, to the bit
+            same = all(np.array_equal(np.asarray(a[:layer]),
+                                      np.asarray(b[:layer]))
+                       for a, b in ((kp2, kp), (vp2, vp)))
+            results[name + ".other_layers_untouched"] = {"ok": same}
+            print(f"kernels: {name}: other layers untouched: {same}",
+                  flush=True)
+            kp2, vp2 = kp2[layer], vp2[layer]
+        check(name + ".pool",
               jnp.stack([kp2[1:], vp2[1:]]),
               jnp.stack([kp_ref[1:], vp_ref[1:]]), 0.0)
 
@@ -173,6 +205,8 @@ def run(*, n_q: int, n_kv: int, hd: int, block_size: int, cells: int,
                  [small, small, small, 3, small, small, 1, 0])
     prefill_case(chunk, [0, cells - chunk - block_size // 2],
                  [chunk, chunk - 3])
+    prefill_case(chunk, [block_size + 3, cells // 2], [chunk - 1, chunk],
+                 layer=1)
 
     # --- flash: forward, and backward through a weighted sum
     fb = 2
