@@ -98,7 +98,7 @@ class Registry:
 
     def __init__(self):
         self._metrics: list[Counter] = []
-        self._collectors: list[Callable[[], None]] = []
+        self._collectors: list[Callable[[], bool | None]] = []
         self._lock = threading.Lock()
 
     def register(self, metric: Counter) -> None:
@@ -117,9 +117,11 @@ class Registry:
                     return m
         return None
 
-    def register_collector(self, fn: Callable[[], None]) -> None:
+    def register_collector(self, fn: Callable[[], bool | None]) -> None:
         """`fn` refreshes gauges from live state; runs on every render
-        (the reference's custom Collect→scrape pattern)."""
+        (the reference's custom Collect→scrape pattern). One that
+        returns False has read its last (what it reads is gone) and is
+        dropped."""
         with self._lock:
             self._collectors.append(fn)
 
@@ -127,8 +129,11 @@ class Registry:
         with self._lock:
             collectors = list(self._collectors)
             metrics = list(self._metrics)
-        for fn in collectors:
-            fn()
+        done = [fn for fn in collectors if fn() is False]
+        if done:
+            with self._lock:
+                self._collectors = [
+                    fn for fn in self._collectors if fn not in done]
         lines: list[str] = []
         for m in metrics:
             lines.append(f"# HELP {m.name} {m.help}")

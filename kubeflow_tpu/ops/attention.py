@@ -46,7 +46,7 @@ def impl_counts() -> dict[str, int]:
 def _xla_attention(
     q: jnp.ndarray,            # [b, sq, n_q, hd]
     k: jnp.ndarray,            # [b, skv, n_kv, hd]
-    v: jnp.ndarray,            # [b, skv, n_kv, hd]
+    v: jnp.ndarray,            # [b, skv, n_kv, hd_v]
     q_positions: jnp.ndarray,  # [b, sq]
     kv_positions: jnp.ndarray, # [b, skv]
     *,
@@ -79,7 +79,7 @@ def _xla_attention(
 
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bngst,btnh->bsngh", probs, v.astype(jnp.float32))
-    return out.reshape(b, sq, n_q, hd).astype(q.dtype)
+    return out.reshape(b, sq, n_q, v.shape[-1]).astype(q.dtype)
 
 
 def _check_impl(impl: str, what: str) -> None:

@@ -162,9 +162,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
 
 
 def _fwd(q4, k4, v4, *, causal, window, block_q, block_k, interpret):
-    """q4: [b, nq, s, hd]; k4/v4: [b, nkv, s, hd] → (o4, lse[b, nq, s])."""
+    """q4: [b, nq, s, hd]; k4: [b, nkv, s, hd]; v4: [b, nkv, s, hdv]
+    (each block takes its array's own last dimension, so `v` may have
+    another head size than `q`/`k`) → (o4 [b, nq, s, hdv], lse)."""
     b, nq, s, hd = q4.shape
-    nkv = k4.shape[1]
+    nkv, hdv = k4.shape[1], v4.shape[3]
     g = nq // nkv
     scale = hd**-0.5
     block_q = _pick_block(s, block_q)
@@ -176,12 +178,14 @@ def _fwd(q4, k4, v4, *, causal, window, block_q, block_k, interpret):
         (1, 1, block_q, hd),
         lambda bh, qi, ki: (bh // nq, bh % nq, qi, 0),
     )
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, hd),
-        lambda bh, qi, ki: (bh // nq, (bh % nq) // g, ki, 0),
-    )
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda bh, qi, ki: (bh // nq, (bh % nq) // g, ki, 0),
+        )
+
     o_spec = pl.BlockSpec(
-        (1, 1, block_q, hd),
+        (1, 1, block_q, hdv),
         lambda bh, qi, ki: (bh // nq, bh % nq, qi, 0),
     )
     lse_spec = pl.BlockSpec(
@@ -196,14 +200,14 @@ def _fwd(q4, k4, v4, *, causal, window, block_q, block_k, interpret):
     o4, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec(hd), kv_spec(hdv)],
         out_specs=[o_spec, lse_spec],
         out_shape=[
-            jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+            jax.ShapeDtypeStruct((b, nq, s, hdv), q4.dtype),
             jax.ShapeDtypeStruct((b, nq, s, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, hdv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -315,7 +319,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd(causal, window, block_q, block_k, interpret, res, do4):
     q4, k4, v4, o4, lse = res
     b, nq, s, hd = q4.shape
-    nkv = k4.shape[1]
+    nkv, hdv = k4.shape[1], v4.shape[3]
     g = nq // nkv
     scale = hd**-0.5
     block_q = _pick_block(s, block_q)
@@ -325,11 +329,16 @@ def _bwd(causal, window, block_q, block_k, interpret, res, do4):
     delta = jnp.sum(do4.astype(jnp.float32) * o4.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, 128))
 
-    q_spec = pl.BlockSpec(
-        (1, 1, block_q, hd), lambda bh, qi, ki: (bh // nq, bh % nq, qi, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, hd),
-        lambda bh, qi, ki: (bh // nq, (bh % nq) // g, ki, 0))
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, 1, block_q, width),
+            lambda bh, qi, ki: (bh // nq, bh % nq, qi, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda bh, qi, ki: (bh // nq, (bh % nq) // g, ki, 0))
+
     row_spec = pl.BlockSpec(
         (1, 1, block_q, 128),
         lambda bh, qi, ki: (bh // nq, bh % nq, qi, 0))
@@ -339,8 +348,9 @@ def _bwd(causal, window, block_q, block_k, interpret, res, do4):
                           window=window, block_q=block_q,
                           block_k=block_k, nk=nkb),
         grid=(b * nq, nqb, nkb),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[q_spec(hd), kv_spec(hd), kv_spec(hdv), q_spec(hdv),
+                  row_spec, row_spec],
+        out_specs=q_spec(hd),
         out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=interpret,
@@ -349,31 +359,40 @@ def _bwd(causal, window, block_q, block_k, interpret, res, do4):
 
     # dk/dv at query-head resolution; kv-head index maps stream the same
     # K/V block to every query head in the group.
-    q_spec2 = pl.BlockSpec(
-        (1, 1, block_q, hd), lambda bh, ki, qi: (bh // nq, bh % nq, qi, 0))
-    kv_spec2 = pl.BlockSpec(
-        (1, 1, block_k, hd),
-        lambda bh, ki, qi: (bh // nq, (bh % nq) // g, ki, 0))
+    def q_spec2(width):
+        return pl.BlockSpec(
+            (1, 1, block_q, width),
+            lambda bh, ki, qi: (bh // nq, bh % nq, qi, 0))
+
+    def kv_spec2(width):
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda bh, ki, qi: (bh // nq, (bh % nq) // g, ki, 0))
+
     row_spec2 = pl.BlockSpec(
         (1, 1, block_q, 128),
         lambda bh, ki, qi: (bh // nq, bh % nq, qi, 0))
-    dkv_out_spec = pl.BlockSpec(
-        (1, 1, block_k, hd), lambda bh, ki, qi: (bh // nq, bh % nq, ki, 0))
+
+    def dkv_out_spec(width):
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda bh, ki, qi: (bh // nq, bh % nq, ki, 0))
 
     dk_full, dv_full = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           window=window, block_q=block_q,
                           block_k=block_k, nq_blocks=nqb),
         grid=(b * nq, nkb, nqb),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=[dkv_out_spec, dkv_out_spec],
+        in_specs=[q_spec2(hd), kv_spec2(hd), kv_spec2(hdv), q_spec2(hdv),
+                  row_spec2, row_spec2],
+        out_specs=[dkv_out_spec(hd), dkv_out_spec(hdv)],
         out_shape=[
             jax.ShapeDtypeStruct((b, nq, s, hd), k4.dtype),
-            jax.ShapeDtypeStruct((b, nq, s, hd), v4.dtype),
+            jax.ShapeDtypeStruct((b, nq, s, hdv), v4.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, hd), jnp.float32),
-            pltpu.VMEM((block_k, hd), jnp.float32),
+            pltpu.VMEM((block_k, hdv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_dkv",
@@ -381,7 +400,7 @@ def _bwd(causal, window, block_q, block_k, interpret, res, do4):
 
     # Group-sum query-head gradients onto their KV head.
     dk = dk_full.reshape(b, nkv, g, s, hd).sum(axis=2).astype(k4.dtype)
-    dv = dv_full.reshape(b, nkv, g, s, hd).sum(axis=2).astype(v4.dtype)
+    dv = dv_full.reshape(b, nkv, g, s, hdv).sum(axis=2).astype(v4.dtype)
     return dq, dk, dv
 
 
@@ -431,7 +450,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(
     q: jnp.ndarray,  # [b, s, n_q, hd]
     k: jnp.ndarray,  # [b, s, n_kv, hd]
-    v: jnp.ndarray,
+    v: jnp.ndarray,  # [b, s, n_kv, hd_v]: its own head size
     *,
     causal: bool = True,
     window: int | None = None,
@@ -442,7 +461,8 @@ def flash_attention(
     """Flash attention with GQA, differentiable (custom VJP).
 
     Layout contract matches ops.attention.dot_product_attention:
-    [batch, seq, heads, head_dim] in/out. `interpret`: see
+    [batch, seq, heads, head_dim] in/out; the output has `v`'s head
+    size, the softmax scale is `q`'s. `interpret`: see
     `resolve_interpret` (compiled unless a test says otherwise).
     """
     interpret = resolve_interpret(interpret)

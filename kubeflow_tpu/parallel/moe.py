@@ -21,6 +21,15 @@ Routing is standard top-k softmax gating with per-expert capacity
 (drop-overflow) and the Switch-style load-balancing auxiliary loss.
 Everything is static-shaped: capacity is a compile-time constant, drops
 are masked writes — no dynamic shapes under jit (XLA requirement).
+
+- `routed_experts` — one chip's share of an expert layer that drops
+  nothing: the layer is told which experts it holds (`held`), routes
+  over all of them (sigmoid scores, top-k, renormalised, scaled), sorts
+  the (token, expert) pairs whose expert it holds by expert and runs
+  grouped products (`jax.lax.ragged_dot`) over the held experts only.
+  No capacity, no padding to one, no exchange: what the absent experts
+  would add is left out, and nothing stands in for the chips that hold
+  them.
 """
 
 from __future__ import annotations
@@ -32,6 +41,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubeflow_tpu.parallel import mesh as mesh_lib
@@ -265,3 +275,106 @@ def moe_mlp_sharded(
         check_vma=False,
     )
     return fn(params, x)
+
+
+# -- the share of a drop-free expert layer that this chip holds ------------
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedConfig:
+    num_experts: int           # the router's outputs, held here or not
+    top_k: int
+    scale: float = 1.0         # on the renormalised weights
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_rows(x, idx, back, width):
+    """x[idx], whose gradient is a gather too: `back` [rows of x,
+    width] lists where each row of x went (every row goes to exactly
+    `width` places), so the cotangent is `ct[back].sum(1)` and no
+    scatter-add with repeated indices is ever run."""
+    return x[idx]
+
+
+def _gather_rows_fwd(x, idx, back, width):
+    return x[idx], back
+
+
+def _gather_rows_bwd(width, back, ct):
+    dx = ct[back.reshape(-1)].reshape(back.shape[0], width, ct.shape[-1])
+    return dx.sum(axis=1).astype(ct.dtype), None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+def routed_experts(params, h: jnp.ndarray, cfg: RoutedConfig,
+                   held: tuple[int, int]):
+    """What the experts `held = (first, count)` add to the tokens
+    h [T, d]. `params`: `router` [d, cfg.num_experts], `w_gate`, `w_up`
+    [count, d, m], `w_down` [count, m, d]. -> (y [T, d], load [count]
+    int32: the (token, expert) pairs each held expert took this call).
+
+    Every token scores all `cfg.num_experts` experts and picks its
+    `top_k`; a pair whose expert is not held adds nothing here. The
+    held pairs are sorted by expert and each expert multiplies its own
+    rows (SwiGLU). Shapes are static at `T * top_k` rows, the most that
+    could be held; the grouped products do work for the held rows
+    only. Scopes: `moe_route`, `moe_experts`."""
+    first, count = held
+    n_tok, k = h.shape[0], cfg.top_k
+    with jax.named_scope("moe_route"):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "td,de->te", h, params["router"].astype(h.dtype),
+            preferred_element_type=jnp.float32))
+        top, expert = jax.lax.top_k(scores, k)                  # [T, k]
+        weight = cfg.scale * top / jnp.sum(top, axis=-1, keepdims=True)
+        local = expert.reshape(-1) - first
+        is_held = (local >= 0) & (local < count)
+        # held pairs first, by expert; the rest behind them
+        key = jnp.where(is_held, local, count)
+        order = jnp.argsort(key, stable=True)                   # [T * k]
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype),
+            unique_indices=True)
+        load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                       dtype=jnp.int32)
+        weight = jnp.where(is_held.reshape(n_tok, k), weight, 0.0)
+    with jax.named_scope("moe_experts"):
+        # rows past the held ones belong to no expert: the grouped
+        # products neither read nor write them, so they are zeroed on
+        # the way in and on the way out (and so are their cotangents)
+        is_row = (jnp.arange(order.shape[0]) < jnp.sum(load))[:, None]
+        rows = _gather_rows(h, order // k, back.reshape(n_tok, k), k)
+        rows = jnp.where(is_row, rows, 0)
+        gate = jax.lax.ragged_dot(rows, params["w_gate"].astype(h.dtype), load)
+        up = jax.lax.ragged_dot(rows, params["w_up"].astype(h.dtype), load)
+        out = jax.lax.ragged_dot(
+            jax.nn.silu(gate) * up, params["w_down"].astype(h.dtype), load)
+        out = jnp.where(is_row, out, 0)
+        # back in (token, choice) order
+        out = _gather_rows(out, back, order[:, None], 1)
+        y = jnp.sum(out.reshape(n_tok, k, -1).astype(jnp.float32)
+                    * weight[..., None], axis=1)
+    return y.astype(h.dtype), load
+
+
+# the gauges a step's expert loads feed (`load_stats`), with their help
+LOAD_GAUGES = {
+    "moe_held_assignments":
+        "(token, expert) pairs the experts held here took in the last "
+        "train step, over its expert layers",
+    "moe_max_over_mean_load":
+        "busiest held expert's load over the mean held load in the last "
+        "train step, the largest over its expert layers",
+}
+
+
+def load_stats(load) -> dict[str, float]:
+    """The two numbers a step's expert loads [layers, held] reduce to:
+    the (token, expert) pairs the held experts took, and the largest
+    over the layers of the busiest held expert's load over the mean."""
+    load = np.asarray(load, dtype=np.float64).reshape(-1, np.shape(load)[-1])
+    mean = np.maximum(load.mean(axis=1), 1e-9)
+    return {"moe_held_assignments": float(load.sum()),
+            "moe_max_over_mean_load": float((load.max(axis=1) / mean).max())}

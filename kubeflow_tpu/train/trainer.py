@@ -16,6 +16,7 @@ import dataclasses
 import functools
 import logging
 import time
+import weakref
 from typing import Any, Callable
 
 import jax
@@ -24,6 +25,8 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu import obs
+from kubeflow_tpu.controlplane.metrics import Counter, Gauge
+from kubeflow_tpu.parallel import moe as moe_lib
 from kubeflow_tpu.parallel import sharding as sharding_lib
 from kubeflow_tpu.parallel.sharding import ShardingRules
 
@@ -246,7 +249,16 @@ class Trainer:
         """`loss_fn(params, tokens, targets, mask) -> scalar` overrides
         the default apply_fn→cross-entropy pipeline — e.g.
         `chunked_cross_entropy_from_hidden` over `llama.hidden`, which
-        skips materializing the [b, s, vocab] logits entirely.
+        skips materializing the [b, s, vocab] logits entirely. It may
+        return `(scalar, aux)` instead: `aux` is a dict of arrays of
+        counts (summed over the microbatches of `grad_accum`) that
+        leaves the jitted step beside the loss and stays on the device
+        as `last_aux`; `step()` still returns `(state, loss)`. The
+        first step whose `aux` carries `moe_load` ([layers, held
+        experts], `parallel.moe.routed_experts`' loads) puts the gauges
+        `moe_held_assignments` and `moe_max_over_mean_load` into the
+        registry, set when it is rendered and not before: no step
+        waits for them.
         `freeze_labels` (params-shaped "train"/"freeze" tree) freezes a
         subtree with no optimizer state (see make_optimizer)."""
         self.mesh = mesh
@@ -300,7 +312,8 @@ class Trainer:
             self._step,
             in_shardings=(self.state_shardings, self.batch_sharding,
                           self.batch_sharding, self.batch_sharding),
-            out_shardings=(self.state_shardings, NamedSharding(mesh, P())),
+            out_shardings=(self.state_shardings, NamedSharding(mesh, P()),
+                           NamedSharding(mesh, P())),
             donate_argnums=(0,),
         )
         # Obs bridge (spans + /metrics histograms). The Trainer has no
@@ -350,8 +363,6 @@ class Trainer:
         # `recompile` span naming the offending signature.
         self.recompiles = reg.get("train_recompiles_total")
         if self.recompiles is None:
-            from kubeflow_tpu.controlplane.metrics import Counter
-
             self.recompiles = Counter(
                 "train_recompiles_total",
                 "Retraces of the jitted train step (novel abstract "
@@ -363,6 +374,32 @@ class Trainer:
             self._jit_step, "train_step")
         self.recompiles.inc(0, fn="train_step")
         self._last_step_end: float | None = None
+        # what the last step's loss_fn counted, still on the device
+        self.last_aux: dict[str, jax.Array] = {}
+        self._registry = reg
+        self._moe_load: list | None = None     # see _export_moe_load
+
+    def _export_moe_load(self, load: jax.Array) -> None:
+        """A step's expert loads -> the gauges of `moe_lib.LOAD_GAUGES`,
+        created by the first step that brings any and set from the
+        newest whenever the registry is rendered. A registry outlives
+        its trainers (the process default does), so the collector holds
+        the loads alone, not the trainer: after the trainer has gone it
+        reads its last step's once more and leaves the registry."""
+        if self._moe_load is None:
+            reg = self._registry
+            gauges = {name: reg.get(name) or Gauge(name, help_, reg)
+                      for name, help_ in moe_lib.LOAD_GAUGES.items()}
+            newest = self._moe_load = [load]
+            alive = weakref.ref(self)
+
+            def collect():
+                for name, value in moe_lib.load_stats(newest[0]).items():
+                    gauges[name].set(value)
+                return alive() is not None
+
+            reg.register_collector(collect)
+        self._moe_load[0] = load
 
     def _build_state(self, params: Params) -> TrainState:
         return TrainState(params, self.optimizer.init(params),
@@ -373,14 +410,17 @@ class Trainer:
 
     def _step(self, state: TrainState, tokens, targets, mask):
         def loss_fn(params, toks, tgts, m):
+            """-> (loss, aux); `aux` is empty unless `self.loss_fn`
+            returns one."""
             if self.loss_fn is not None:
-                return self.loss_fn(params, toks, tgts, m)
+                out = self.loss_fn(params, toks, tgts, m)
+                return out if isinstance(out, tuple) else (out, {})
             logits = self.apply_fn(params, toks)
-            return cross_entropy_loss(logits, tgts, m)
+            return cross_entropy_loss(logits, tgts, m), {}
 
         acc = self.tc.grad_accum
         if acc <= 1:
-            loss, grads = jax.value_and_grad(loss_fn)(
+            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 state.params, tokens, targets, mask)
         else:
             # lax.scan over microbatches: ONE compiled micro-step,
@@ -399,17 +439,18 @@ class Trainer:
             def micro(carry, x):
                 gsum, lsum, wsum = carry
                 toks, tgts, m = x
-                l_, g_ = jax.value_and_grad(loss_fn)(
+                (l_, aux_), g_ = jax.value_and_grad(loss_fn, has_aux=True)(
                     state.params, toks, tgts, m)
                 w = jnp.sum(m.astype(jnp.float32))
                 gsum = jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32) * w, gsum, g_)
                 return (gsum, lsum + l_.astype(jnp.float32) * w,
-                        wsum + w), None
+                        wsum + w), aux_
 
-            (gsum, lsum, wsum), _ = jax.lax.scan(
+            (gsum, lsum, wsum), aux = jax.lax.scan(
                 micro, (g0, jnp.zeros((), jnp.float32),
                         jnp.zeros((), jnp.float32)), xs)
+            aux = jax.tree.map(lambda a: jnp.sum(a, axis=0), aux)
             denom = jnp.maximum(wsum, 1.0)
             grads = jax.tree.map(
                 lambda g, p: (g / denom).astype(p.dtype), gsum,
@@ -420,7 +461,7 @@ class Trainer:
                 grads, state.opt_state, state.params
             )
             params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.step + 1), loss
+        return TrainState(params, opt_state, state.step + 1), loss, aux
 
     def init(self, rng: jax.Array) -> TrainState:
         with jax.set_mesh(self.mesh):
@@ -505,14 +546,17 @@ class Trainer:
                 with self.profiler.phase(
                         "step", tokens=int(tokens.shape[0])
                         * int(tokens.shape[1])):
-                    out = self._jit_step(state, tokens, targets, mask)
+                    state, loss, self.last_aux = self._jit_step(
+                        state, tokens, targets, mask)
+        if "moe_load" in self.last_aux:
+            self._export_moe_load(self.last_aux["moe_load"])
         dt = time.perf_counter() - t0
         self._last_step_end = time.perf_counter()
         self.step_seconds.observe(dt)
         if compiling:
             self._stepped = True
             self.compile_seconds.observe(dt)
-        return out
+        return state, loss
 
 
 def _opt_state_shardings(opt_shapes, params_shapes, param_shardings, mesh):

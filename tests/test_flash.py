@@ -206,3 +206,34 @@ def test_dispatcher_routes_flash_on_request():
     want = _reference(q, k, v, True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_flash_v_head_size_differs_from_qk():
+    """Latent attention after up-projection: q/k heads of 192 beside v
+    heads of 128 through the same kernels, forward and gradients."""
+    rng = np.random.default_rng(5)
+    b, s, n = 1, 128, 2
+    q = jnp.asarray(rng.standard_normal((b, s, n, 192)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, s, n, 192)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, s, n, 128)), jnp.float32)
+
+    def flash_loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, block_q=64, block_k=32)
+        return jnp.sum(o * jnp.cos(o)), o
+
+    def dense_loss(q, k, v):
+        o = _reference(q, k, v, True)
+        return jnp.sum(o * jnp.cos(o)), o
+
+    (_, got), g_flash = jax.value_and_grad(
+        flash_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), g_dense = jax.value_and_grad(
+        dense_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert got.shape == (b, s, n, 128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    for name, gf, gd in zip("qkv", g_flash, g_dense):
+        assert gf.shape == gd.shape
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gd), rtol=5e-4, atol=5e-4,
+            err_msg=f"grad w.r.t. {name}")

@@ -114,6 +114,23 @@ def test_render_under_concurrent_inc():
     assert c.value(worker="w") == n_workers * per_worker
 
 
+def test_a_collector_that_returns_false_is_dropped_after_that_render():
+    reg = Registry()
+    g = Counter("seen_total", "renders each collector saw", reg)
+    left = [2]
+
+    def twice():
+        g.inc(who="twice")
+        left[0] -= 1
+        return left[0] > 0
+
+    reg.register_collector(twice)
+    reg.register_collector(lambda: g.inc(who="always"))
+    for _ in range(4):
+        reg.render()
+    assert g.value(who="twice") == 2 and g.value(who="always") == 4
+
+
 def test_strict_parser_catches_render_bugs():
     with pytest.raises(ExpositionError):
         parse_exposition("no_type_decl 1\n")

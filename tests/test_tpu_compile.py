@@ -1,0 +1,62 @@
+"""The main path's kernels compiled at real widths for a described (not
+attached) TPU v5e: what Mosaic refuses, it refuses here, at no chip
+time. Nothing runs, so nothing is said about results or times.
+
+The topology is described inside a fixture and only there (libtpu loads
+in the worker that runs this file, once); where it cannot be described
+the tests skip. Keep such tests in this one file.
+"""
+
+from __future__ import annotations
+
+import os
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from kubeflow_tpu.ops.pallas.flash_attention import flash_attention
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu from it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compile_flash(sharding, *, b, s, n_q, n_kv, hd, hd_v):
+    shapes = [jax.ShapeDtypeStruct((b, s, n, d), jnp.bfloat16,
+                                   sharding=sharding)
+              for n, d in ((n_q, hd), (n_kv, hd), (n_kv, hd_v))]
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, interpret=False).astype(jnp.float32)), argnums=(0, 1, 2))(
+                q, k, v)
+
+    # the kernels refuse any backend but the TPU; the compile is for one
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = jax.jit(grads).lower(*shapes).compile()
+    return compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", [
+    # mistral-7b.train: GQA 32/8 heads of 128 at 2048
+    dict(b=2, s=2048, n_q=32, n_kv=8, hd=128, hd_v=128),
+    # kimi-linear-48b.train-8k: latent attention after up-projection,
+    # q/k heads of 192 beside v heads of 128 at 8192
+    dict(b=2, s=8192, n_q=32, n_kv=32, hd=192, hd_v=128),
+], ids=["gqa-128-128-2048", "mla-192-128-8192"])
+def test_flash_forward_and_backward_compile_for_v5e(one_chip, shape):
+    text = compile_flash(one_chip, **shape)
+    for kernel in ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv"):
+        assert kernel in text
