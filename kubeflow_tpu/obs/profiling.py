@@ -208,8 +208,10 @@ class PhaseProfiler:
         return self._annotate(self._span_prefix + name, **stats)
 
     @contextlib.contextmanager
-    def phase(self, name: str, tokens: int = 0):
-        with self._span(name, tokens=int(tokens)):
+    def phase(self, name: str, tokens: int = 0, **stats: int):
+        """`stats` go to the trace's span beside `tokens`, and nowhere
+        else."""
+        with self._span(name, tokens=int(tokens), **stats):
             start = self._clock()
             if self._t_first is None:
                 # the observed-wall window opens at the first phase

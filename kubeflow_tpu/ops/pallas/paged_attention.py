@@ -7,35 +7,72 @@ FULL `[blocks_per_slot * block_size]` window through the table before
 attending — correct, but it streams the dead tail (and the trash-block
 padding) through HBM on every decode step, and decode MBU is the
 roofline that matters (bench.py). This kernel walks the table
-in-kernel instead:
+in-kernel instead, and its grid steps follow the LIVE blocks:
 
-- grid = (rows, blocks_per_slot); each row's CURSOR and BLOCK TABLE
-  are scalar-prefetched, so the K/V BlockSpec index map can resolve
-  `table[row, j]` before the body runs and DMA only that physical
-  block from the pool;
-- the pool is rank 5, `[L, num_blocks, block_size, n_kv, hd]`: every
-  layer's, as the serving engines' layer scan carries it, with the
-  LAYER a third scalar-prefetched operand. The index map returns
-  `(layer, table[row, j], 0, 0, 0)` over a squeezed leading block
-  dimension, so the body sees one block as before and no caller takes
-  a layer's slice first (for a Pallas operand that slice is a copy of
-  the whole layer: 268 MB a layer a step at Mistral-7B's pool). One
-  layer's pool (rank 4, no `layer`) is lifted to `[1, ...]` at layer
-  0, a bitcast, and runs the same body;
-- iterations past the cursor block (and, with a sliding window, before
-  the window's first block) are CLAMPED to the boundary — a repeated
-  physical index means no new DMA, so HBM traffic tracks the cache
-  FILL, not `blocks_per_slot * block_size` — and `pl.when` gates the
-  compute;
-- GQA stays at KV resolution (queries reshape to [n_kv, group] inside
-  the kernel; the pool never repeats heads);
-- per-block partials merge with the same online softmax as
-  flash_attention.py / decode_attention.py; per-cell validity (left-pad
-  holes) rides in as an int32 mask laid out `[b, blocks, 1, block_size]`
-  and indexed by LOGICAL block (Mosaic takes neither an i1 VMEM operand
-  nor a `(1, block_size)` block over `[b, width]`: the last two block
-  dims must be (8, 128)-divisible or the array's own), causality masks
-  by absolute cell index against the prefetched cursor.
+- the pools stay in HBM (`memory_space=ANY`), whole and rank 5,
+  `[L, num_blocks, block_size, n_kv, hd]`: every layer's, as the
+  serving engines' layer scan carries it. Cursors, block table and the
+  LAYER are scalar-prefetched. One layer's pool (rank 4, no `layer`) is
+  lifted to `[1, ...]` at layer 0, a bitcast, and runs the same body.
+  The call sees a block as `[block_size * n_kv, hd]` rows (row = cell
+  * n_kv + head, the pool's own order): a reshape that is a bitcast
+  where the heads fill the pool's tiles (8 heads of 128 in bf16; the
+  compiled serving step has no copy of the pool), and that lets one
+  KV head (Gemma) through Mosaic's copies, which a `[.., 1, hd]` slice
+  is not. The head dim has to be a multiple of 128 on the chip;
+- grid = (rows, ceil(blocks_per_slot / G)): a step owns a GROUP of `G`
+  consecutive logical blocks of one row. A row's live range runs from
+  the first block its sliding window can see (block 0 without one) to
+  its cursor's block. A group that touches the range is a FETCHING
+  step; any other starts no copy and runs no body, so a slot's empty
+  tail costs `1 / G` of the steps it used to (16 x 64 = 1024 steps a
+  call at Mistral-7B's serving shapes were 16 x 8 = 128 now, of which
+  the ~35 that hold live blocks do anything);
+- a fetching step copies ONLY its live blocks, each with its own
+  `pltpu.make_async_copy` through the table (`pool[layer, table[row,
+  j]]` -> block `j % G` of a VMEM buffer of `G` blocks) and its own DMA
+  semaphore. Two such buffers alternate: before a fetching step waits
+  for its own copies it starts those of the NEXT fetching step (the
+  row's next group, or the first live group of the next row that has
+  one), so they fly while this one is computed. The very first step of
+  the grid starts the first fetching step's copies;
+- `G` (`group_blocks`) is the largest power of two whose two K and two
+  V buffers fit `VMEM_BUFFER_BYTES`, and no more than the table needs:
+  8 at Mistral-7B's shapes (64 blocks of 64 x 8 x 128 bf16: 512 tokens,
+  2 MB of K and V a buffer), 4 for a 4-block table. It is read off the
+  call's shapes and nothing else;
+- the body works on the group at once. The buffer is `[G * block_size
+  * n_kv, hd]` rows, in the pool's own order (no transpose), and all
+  query heads meet all rows in one `q k^T`: a query head's logits
+  against another KV head's rows are masked like any invisible cell
+  (GQA without repeating or regrouping the pool: the MXU's time is the
+  K and V tiles it loads, which are the same either way). `q k^T`
+  takes its operands in the promoted dtype of `q` and the pool with
+  float32 accumulation (bf16 x bf16 is exact in float32); logits, the
+  running maximum, the sum, `p` and the accumulator are float32, and
+  `p` is not rounded before `p v`: against a bfloat16 pool it is split
+  into three bfloat16 parts that sum to it exactly, which meet V in
+  one pass (Mosaic's float32 dot rounds its operands to bfloat16
+  unless told HIGHEST, which loads V's tiles six times); a float32
+  pool takes HIGHEST in both products;
+- masking: a column is visible if it is its query head's own KV head,
+  its token is at or before the cursor, inside the window, and no pad
+  hole. Everything else, the cells of blocks that were NOT fetched
+  among them (a buffer holds whatever an earlier group left there, or
+  nothing yet: possibly NaN bits), is masked with `where` on the
+  logits AND on `p`, and the V rows outside `[window start, cursor]`
+  are zeroed before `p v`, so nothing unfetched is read as a number;
+- partials merge with the same online softmax as flash_attention.py /
+  decode_attention.py; per-cell validity (left-pad holes) rides in as
+  an int32 mask laid out `[b, groups, 1, G * block_size]` (Mosaic
+  takes neither an i1 VMEM operand nor a block whose last two dims are
+  not (8, 128)-divisible or the array's own), a bit a cell. A cell has
+  `n_kv` columns, and repeating lanes in place is what neither XLA (a
+  2 MB relayout a call, 30 us of a 110 us call when it was tried) nor
+  Mosaic does well: the body multiplies the bits, 128 cells to a row,
+  by a constant `[128, 128 * n_kv]` matrix of ones where column //
+  n_kv is the cell (5 us a call). The mask's index map is clamped to
+  the live groups, so a dead step refetches nothing.
 
 Cell index == logical token position is a precondition (the pool's
 insert-time compaction guarantees it — see serving/paged.py); callers
@@ -43,12 +80,13 @@ with rotated/packed layouts must use the XLA gather path, which masks
 by the actual position tensors.
 
 A row whose visible set is empty (pad holes over its whole causal
-prefix) comes out as exact zeros; the XLA path gives such a row the mean
-of V. Only host-masked filler rows are ever in that state, and
-tools/smoke_kernels.py pins the convention on the chip.
+prefix, or a negative cursor) comes out as exact zeros; the XLA path
+gives such a row the mean of V. Only host-masked filler rows are ever
+in that state, and tools/smoke_kernels.py pins the convention on the
+chip.
 
-The trash-block-0 convention costs nothing here: clamping confines j
-to live blocks, so the table's trash tail is never even read.
+The trash-block-0 convention costs nothing here: only live blocks are
+copied, so the table's trash tail is never even read.
 
 Pinned against the XLA gather oracle (`ops.paged_attention`
 impl="xla") by tests/test_paged_attention_kernel.py.
@@ -57,80 +95,216 @@ impl="xla") by tests/test_paged_attention_kernel.py.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kubeflow_tpu.ops.attention import NEG_INF, layered_pool
 from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
 
+# What the K and V buffers of one call may take of VMEM, both slots of
+# both: `group_blocks` sizes a grid step's group of blocks against it.
+VMEM_BUFFER_BYTES = 4 * 2**20
 
-def _kernel(pos_ref, tab_ref, layer_ref, q_ref, k_ref, v_ref, mask_ref,
-            o_ref, acc, m_scr, l_scr, *, scale, window, block_size, nb,
-            n_kv, group):
-    # tab_ref and layer_ref are consumed by the BlockSpec index maps
-    # (that's the whole point); the body only needs the cursor.
-    del tab_ref, layer_ref
-    b_i, bj = pl.program_id(0), pl.program_id(1)
-    pos = pos_ref[b_i]
 
-    @pl.when(bj == 0)
+def group_blocks(blocks_per_slot: int, block_size: int, n_kv: int,
+                 hd: int, itemsize: int) -> int:
+    """`G`, the pool blocks one grid step owns: the largest power of
+    two whose double-buffered K and V fit `VMEM_BUFFER_BYTES`, stopped
+    at the first that covers the whole table."""
+    block_bytes = block_size * n_kv * hd * itemsize
+    g = 1
+    while g < blocks_per_slot and 8 * g * block_bytes <= VMEM_BUFFER_BYTES:
+        g *= 2
+    return g
+
+
+def _div(x, n: int):
+    """x // n for non-negative int32 `x`: a shift where `n` is a power
+    of two (the VPU has no integer divide)."""
+    if n & (n - 1) == 0:
+        return jax.lax.shift_right_logical(x, n.bit_length() - 1)
+    return jax.lax.div(x, n)
+
+
+def _rem(x, n: int):
+    """x % n for non-negative int32 `x`, as `_div`."""
+    return x & (n - 1) if n & (n - 1) == 0 else jax.lax.rem(x, n)
+
+
+def _kernel(pos_ref, tab_ref, layer_ref, q_ref, k_hbm, v_hbm, mask_ref,
+            spread_ref, o_ref, k_buf, v_buf, sems, slot_ref, acc, m_scr,
+            l_scr, *,
+            scale, window, block_size, nb, g_blocks, n_kv, group, rows):
+    r, g = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
+    tokens = g_blocks * block_size        # a group's cells
+    cols = tokens * n_kv                  # ... and its K/V rows
+
+    def live_blocks(row):
+        """[lo, hi], the logical blocks `row` can see; hi < lo: none."""
+        pos = pos_ref[row]
+        hi = jnp.where(pos < 0, -1,
+                       jnp.minimum(jax.lax.div(pos, block_size), nb - 1))
+        if window is None:
+            return 0, hi
+        first = jnp.maximum(pos - window + 1, 0)
+        return jax.lax.div(first, block_size), hi
+
+    def each_live_copy(row, grp, slot, do):
+        """`do(copy)` for the K and V copy of every live block of group
+        `grp` of `row`, into (or out of the semaphores of) `slot`."""
+        lo, hi = live_blocks(row)
+
+        def block(i, _):
+            blk = grp * g_blocks + i
+
+            @pl.when((blk >= lo) & (blk <= hi))
+            def _():
+                phys = tab_ref[row, blk]
+                for kv, (pool, buf) in enumerate(
+                        ((k_hbm, k_buf), (v_hbm, v_buf))):
+                    do(pltpu.make_async_copy(
+                        pool.at[layer, phys], buf.at[slot, i],
+                        sems.at[kv, slot, i]))
+
+        # a loop, not `for i in range(g_blocks)`: unrolled, the three
+        # uses of this traced 48 copies a kernel and set-up took 6 s
+        # longer, for 1.5 us a call (PERF.md, PR 29)
+        jax.lax.fori_loop(0, g_blocks, block, None)
+
+    def first_live_row(start):
+        """The first row >= start with a live block, or `rows`."""
+        def dead(row):
+            lo, hi = live_blocks(jnp.minimum(row, rows - 1))
+            return (row < rows) & (hi < lo)
+        return jax.lax.while_loop(dead, lambda row: row + 1, start)
+
+    def first_group(row):
+        return jax.lax.div(live_blocks(jnp.minimum(row, rows - 1))[0],
+                           g_blocks)
+
+    @pl.when((r == 0) & (g == 0))
+    def _first_copies():
+        first = first_live_row(0)
+        slot_ref[0] = 0
+
+        @pl.when(first < rows)
+        def _():
+            each_live_copy(first, first_group(first), 0,
+                           lambda copy: copy.start())
+
+    @pl.when(g == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    # Relevance mirrors decode_attention: skip logical blocks past the
-    # cursor AND (with a sliding window) blocks wholly older than the
-    # attention band.
-    relevant = bj * block_size <= pos
-    if window is not None:
-        relevant &= (bj * block_size + block_size - 1) >= pos - window + 1
+    pos = jnp.minimum(pos_ref[r], nb * block_size - 1)
+    lo, hi = live_blocks(r)
+    fetching = (g * g_blocks <= hi) & ((g + 1) * g_blocks - 1 >= lo)
 
-    @pl.when(relevant)
+    @pl.when(fetching)
     def _compute():
-        n_q = n_kv * group
-        q = q_ref[0, 0].astype(jnp.float32)           # [n_q, hd]
-        k = k_ref[0].astype(jnp.float32)              # [bs, n_kv, hd]
-        qg = q.reshape(n_kv, group, -1)
-        kt = jnp.swapaxes(k, 0, 1)                    # [n_kv, bs, hd]
-        # [n_kv, group, bs]: batch over kv heads — GQA without repeat
-        logits = jax.lax.dot_general(
-            qg, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        logits = logits.reshape(n_q, block_size)
+        slot = slot_ref[0]
+        # the next fetching step's copies fly under this one's body
+        more = (g + 1) * g_blocks <= hi
+        nxt = first_live_row(jnp.where(more, r, r + 1))
 
-        # Logical cell index == token position (pool compaction).
-        idx = bj * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (n_q, block_size), 1)
-        visible = (idx <= pos) & (mask_ref[0, 0] != 0)  # causal & pad holes
-        if window is not None:
-            visible &= (pos - idx) < window
+        @pl.when(nxt < rows)
+        def _():
+            each_live_copy(nxt, jnp.where(more, g + 1, first_group(nxt)),
+                           1 - slot, lambda copy: copy.start())
+
+        each_live_copy(r, g, slot, lambda copy: copy.wait())
+        slot_ref[0] = 1 - slot
+
+        # Logical cell index == token position (pool compaction): the
+        # group's K/V rows [first, last) are the cells the cursor and
+        # the window let through. Rows outside hold cells past the
+        # cursor, before the window, or nothing that was ever copied.
+        last = (pos - g * tokens + 1) * n_kv
+        first = (0 if window is None
+                 else (pos - window + 1 - g * tokens) * n_kv)
+
+        def seen(row):
+            return (row < last) & (row >= first)
+
+        n_q = n_kv * group
+        q = q_ref[0, 0]                                # [n_q, hd]
+        k = k_buf[slot].reshape(cols, -1)              # [cols, hd]
+        mm = jnp.promote_types(q.dtype, k.dtype)
+        exact = (jax.lax.Precision.HIGHEST if mm == jnp.float32
+                 else None)     # bf16 x bf16 is exact in float32
+        logits = jax.lax.dot_general(
+            q.astype(mm), k.astype(mm), (((1,), (1,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32,
+        ) * scale                                      # [n_q, cols]
+
+        # The mask holds a bit a cell and a cell has n_kv columns: the
+        # cells, `span` to a row, times `spread` ([span, span * n_kv],
+        # a one where column // n_kv is the cell) give each column its
+        # cell's bit: lanes are not repeated in place on this chip
+        span = spread_ref.shape[0]
+        cells = jnp.concatenate(
+            [mask_ref[0, 0, :, i:i + span]
+             for i in range(0, tokens, span)])      # [tokens / span, span]
+        bits = jax.lax.dot_general(
+            cells.astype(jnp.float32).astype(spread_ref.dtype),
+            spread_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        holes = jnp.concatenate(
+            [bits[i:i + 1] for i in range(tokens // span)],
+            axis=1) == 0.0                             # [1, cols]
+
+        col = jax.lax.broadcasted_iota(jnp.int32, (n_q, cols), 1)
+        q_head = jax.lax.broadcasted_iota(jnp.int32, (n_q, cols), 0)
+        visible = (seen(col)
+                   & (_rem(col, n_kv) == _div(q_head, group))  # GQA
+                   & ~holes)                                   # pads
         logits = jnp.where(visible, logits, NEG_INF)
 
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
         p = jnp.exp(logits - m_new[:, None])
-        # a fully-masked block contributes nothing, not exp(NEG_INF-m)
+        # a fully-masked group contributes nothing, not exp(NEG_INF-m)
         p = jnp.where(visible, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = jnp.broadcast_to(
             (l_scr[:, 0] * alpha + jnp.sum(p, axis=1))[:, None],
             l_scr.shape)
-        v = v_ref[0].astype(jnp.float32)              # [bs, n_kv, hd]
-        vg = jnp.swapaxes(v, 0, 1)                    # [n_kv, bs, hd]
-        pv = jax.lax.dot_general(
-            p.reshape(n_kv, group, block_size), vg,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ).reshape(n_q, -1)                            # [n_q, hd]
+        # 0 * NaN is NaN: an unseen row leaves the product as a row of
+        # zeros, not as the zeros of p
+        v = v_buf[slot].reshape(cols, -1).astype(jnp.float32)
+        v = jnp.where(
+            seen(jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)), v, 0.0)
+        if v_buf.dtype == jnp.bfloat16:
+            # p = p1 + p2 + p3 to the last bit, each a bfloat16: three
+            # exact products in one pass over V's tiles, where a
+            # float32 dot would round p (one pass) or load V's tiles
+            # six times (HIGHEST)
+            p1 = p.astype(jnp.bfloat16)
+            rest = p - p1.astype(jnp.float32)
+            p2 = rest.astype(jnp.bfloat16)
+            p3 = (rest - p2.astype(jnp.float32)).astype(jnp.bfloat16)
+            pv = jax.lax.dot_general(
+                jnp.concatenate([p1, p2, p3]), v.astype(jnp.bfloat16),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)    # [3 n_q, hd]
+            pv = pv[:n_q] + pv[n_q:2 * n_q] + pv[2 * n_q:]
+        else:
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)    # [n_q, hd]
         acc[:] = acc[:] * alpha[:, None] + pv
         m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
 
-    @pl.when(bj == nb - 1)
+    @pl.when(g == pl.num_programs(1) - 1)
     def _finish():
         l = l_scr[:, 0]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -167,6 +341,11 @@ def paged_decode_attention(
             f"head dim mismatch: q has {hd}, pool has {hd_kv}")
     if n_q % n_kv:
         raise ValueError(f"{n_q} query heads not grouped by {n_kv} kv")
+    if hd % 128 and not interpret:
+        # a block's copy moves whole 128-lane tiles of the pool
+        raise ValueError(
+            f"the compiled kernel copies pool blocks whose head dim is "
+            f"a multiple of 128, got {hd}: use impl='xla'")
     group = n_q // n_kv
     if block_table.ndim != 2 or block_table.shape[0] != b:
         raise ValueError(
@@ -186,43 +365,55 @@ def paged_decode_attention(
     positions = q_positions.astype(jnp.int32)
     table = block_table.astype(jnp.int32)
 
-    # Clamped LOGICAL block index: iterations outside a row's live
-    # range re-reference a boundary block, whose PHYSICAL id then
-    # repeats — consecutive equal indices skip the DMA, which is where
-    # the fill-proportional saving comes from. The live range is
-    # [first block the window can see, cursor block]; the table's
-    # trash-block tail is never read.
-    def _clamp(bj, pos):
-        hi = pos // block_size
+    # A block as the body takes it: [block_size * n_kv, hd] rows, row =
+    # cell * n_kv + head, the pool's own order (a bitcast where the
+    # heads fill the pool's tiles, as 8 heads of 128 do)
+    n_layers, num_blocks = k_pool.shape[:2]
+    block_rows = block_size * n_kv
+    k_pool = k_pool.reshape(n_layers, num_blocks, block_rows, hd)
+    v_pool = v_pool.reshape(n_layers, num_blocks, block_rows, hd)
+    g_blocks = group_blocks(nb, block_size, n_kv, hd,
+                            k_pool.dtype.itemsize)
+    groups = pl.cdiv(nb, g_blocks)
+    tokens = g_blocks * block_size
+    # the mask by group; the last group's cells past the table are
+    # holes. `spread` takes a cell's bit to its n_kv columns (_kernel)
+    mask = jnp.pad(kv_mask.astype(jnp.int32),
+                   ((0, 0), (0, groups * tokens - width))).reshape(
+        b, groups, 1, tokens)
+    span = math.gcd(tokens, 128)
+    spread = jnp.asarray(np.repeat(np.eye(span), n_kv, axis=1),
+                         jnp.bfloat16)
+
+    def mask_map(b_i, g, pos_ref, tab_ref, layer_ref):
+        # Clamped to the row's live groups: a step outside them asks
+        # for the block it already has, which is no new DMA.
+        pos = pos_ref[b_i]
+        hi = jnp.clip(pos, 0, width - 1) // tokens
         if window is None:
-            return jnp.minimum(bj, hi)
-        lo = jnp.maximum((pos - window + 1) // block_size, 0)
-        return jnp.clip(bj, lo, hi)
+            return (b_i, jnp.minimum(g, hi), 0, 0)
+        lo = jnp.clip(pos - window + 1, 0, width - 1) // tokens
+        return (b_i, jnp.clip(g, lo, hi), 0, 0)
 
-    def kv_map(b_i, bj, pos_ref, tab_ref, layer_ref):
-        # The indirection: logical block -> physical pool block of the
-        # prefetched layer (whose block dimension is squeezed away).
-        return (layer_ref[0], tab_ref[b_i, _clamp(bj, pos_ref[b_i])],
-                0, 0, 0)
-
-    def mask_map(b_i, bj, pos_ref, tab_ref, layer_ref):
-        # The mask is laid out logically, so no table lookup here.
-        return (b_i, _clamp(bj, pos_ref[b_i]), 0, 0)
-
-    def row_map(b_i, bj, pos_ref, tab_ref, layer_ref):
+    def row_map(b_i, g, pos_ref, tab_ref, layer_ref):
         return (b_i, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, nb),
+        grid=(b, groups),
         in_specs=[
             pl.BlockSpec((1, 1, n_q, hd), row_map),
-            pl.BlockSpec((None, 1, block_size, n_kv, hd), kv_map),
-            pl.BlockSpec((None, 1, block_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, 1, 1, block_size), mask_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, 1, 1, tokens), mask_map),
+            pl.BlockSpec(spread.shape, lambda *_: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, n_q, hd), row_map),
         scratch_shapes=[
+            pltpu.VMEM((2, g_blocks, block_rows, hd), k_pool.dtype),
+            pltpu.VMEM((2, g_blocks, block_rows, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, g_blocks)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((n_q, hd), jnp.float32),
             pltpu.VMEM((n_q, 128), jnp.float32),
             pltpu.VMEM((n_q, 128), jnp.float32),
@@ -230,13 +421,14 @@ def paged_decode_attention(
     )
     kernel = functools.partial(
         _kernel, scale=hd**-0.5, window=window, block_size=block_size,
-        nb=nb, n_kv=n_kv, group=group,
+        nb=nb, g_blocks=g_blocks, n_kv=n_kv, group=group, rows=b,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(positions, table, layer.reshape(1), q, k_pool, v_pool,
-      kv_mask.astype(jnp.int32).reshape(b, nb, 1, block_size))
+    )(positions, table, layer.reshape(1), q, k_pool, v_pool, mask, spread)

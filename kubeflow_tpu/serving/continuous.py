@@ -65,6 +65,7 @@ from kubeflow_tpu.ops.attention import (
 )
 from kubeflow_tpu.ops.norms import rms_norm
 from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
+from kubeflow_tpu.ops.pallas.paged_attention import group_blocks
 from kubeflow_tpu.ops.rotary import rope_frequencies
 from kubeflow_tpu.serving.engine import (
     DecodeState,
@@ -337,6 +338,27 @@ class ContinuousEngine:
             None,
             jnp.zeros((self.S, self.blocks_per_slot), jnp.int32),
         )
+
+    def decode_kv_steps(self, cursors) -> dict[str, int]:
+        """What a decode step at these cursors (one per decoding slot)
+        asks of the KV pool, in the paged decode kernel's units
+        (ops/pallas/paged_attention.py): the pool blocks the rows can
+        see, the grid steps those fall in (a step owns `group_blocks`
+        consecutive blocks of a row and fetches only when one is
+        live), and the grid's size, slots x groups, which is static."""
+        cfg = self.engine.cfg
+        bs, nb = self.block_size, self.blocks_per_slot
+        g = group_blocks(nb, bs, cfg.num_kv_heads, cfg.head_dim,
+                         jnp.dtype(cfg.dtype).itemsize)
+        window = getattr(cfg, "sliding_window", None)
+        live = fetching = 0
+        for cur in cursors:
+            lo = max(cur - window + 1, 0) // bs if window else 0
+            hi = min(cur // bs, nb - 1)
+            live += hi - lo + 1
+            fetching += hi // g - lo // g + 1
+        return {"kv_blocks_live": live, "kv_steps_fetching": fetching,
+                "kv_steps": self.S * -(-nb // g)}
 
     def kv_block_bytes(self) -> int:
         """HBM bytes one pool block holds (K+V, all layers) — the unit
@@ -2829,8 +2851,12 @@ class ContinuousBatcher:
                 impl=self.cengine.attention_impl, steps=steps)
         # `decode` phase = dispatch + any blocking inside run_step.
         # Tokens are attributed where they're OBSERVED (_process_chunk)
-        # so over-decoded garbage rows never inflate the count.
-        with self.profiler.phase("decode"):
+        # so over-decoded garbage rows never inflate the count. The
+        # span says how far the KV walk of this dispatch's first step
+        # follows the live blocks: a row's cursor is its last token's
+        # cell, which that step writes.
+        with self.profiler.phase("decode", **self.cengine.decode_kv_steps(
+                len(r.kv_toks) - 1 for r in snap.values())):
             async with self.gpu_lock:
                 st, toks, lps, rng = await loop.run_in_executor(
                     None, run_step)

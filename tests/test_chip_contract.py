@@ -426,10 +426,16 @@ def test_smoke_kernels_phase_passes_interpreted_at_a_tiny_size():
     compiled at the llama3-1b shapes."""
     result = smoke_kernels.run(n_q=4, n_kv=2, hd=32, block_size=8,
                                cells=64, chunk=16, flash_seq=128,
-                               interpret=True)
+                               interpret=True,
+                               steady=dict(n_q=8, blocks_per_slot=16,
+                                           num_blocks=65, calls=2))
     assert result["ok"], result["problems"]
     assert result["kernels"]["empty_visible_set_rows_are_zero"]["ok"]
-    assert len(result["kernels"]) == 15
+    assert len(result["kernels"]) == 16
+    steady = result["kernels"][
+        "paged_attention[s=1,16x16 blocks of 65,layer=2 of 3]"]
+    assert steady["ok"] and steady["live_blocks"] == 48
+    assert steady["call_us"] > 0
     assert result["kernels"][
         "prefill_append[s=16,layer=1 of 2].other_layers_untouched"]["ok"]
     assert set(result["auto"].values()) == {"xla"}      # this backend

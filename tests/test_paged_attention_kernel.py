@@ -24,7 +24,10 @@ from kubeflow_tpu.ops.attention import (
     paged_attention,
     resolve_paged_attention_impl,
 )
-from kubeflow_tpu.ops.pallas.paged_attention import paged_decode_attention
+from kubeflow_tpu.ops.pallas.paged_attention import (
+    group_blocks,
+    paged_decode_attention,
+)
 from kubeflow_tpu.serving import (
     GEMMA_FAMILY,
     LLAMA_FAMILY,
@@ -152,7 +155,7 @@ def test_kernel_matches_oracle_cow_shared_tables():
 
 def test_kernel_never_reads_the_trash_tail():
     """Trash-block-0 convention: table tails point at block 0. The
-    kernel's clamp must confine DMA to live blocks — poison the trash
+    kernel must confine its copies to live blocks — poison the trash
     block with NaN and the output must stay finite and match the
     oracle run on a clean pool. (The oracle itself is NOT given the
     poison: its gather multiplies trash V cells by probability 0.0,
@@ -166,6 +169,146 @@ def test_kernel_never_reads_the_trash_tail():
                                  interpret=True)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
+# -- grid steps of several blocks --------------------------------------------
+#
+# The cases above fit one group (`group_blocks` stops at the table's
+# size). These run at block sizes a pool is served with, where a table
+# is several groups: float32 blocks of 64 cells x 4 heads x 128 are
+# 128 KB, as the bf16 blocks of Mistral-7B's 8 heads, and make G = 8
+# (512 cells a step); 8 heads make 256 KB and G = 4.
+
+
+def _mk_groups(seed, cursors, nb, *, n_q=8, n_kv=4, hd=128, bs=64,
+               spare=3, dtype=np.float32):
+    """Rows at the given cursors over `nb`-block tables: each live
+    block its own pool block, in scrambled order, the table's tails at
+    the trash block, `spare` blocks no row owns, one pad hole a row
+    (cell 1). Cursor -1: a row with no cell at all."""
+    rng = np.random.default_rng(seed)
+    cursors = np.asarray(cursors, np.int32)
+    b, width = len(cursors), nb * bs
+    live = np.where(cursors < 0, 0, cursors // bs + 1)
+    num_blocks = 1 + int(live.sum()) + spare
+    ids = 1 + rng.permutation(num_blocks - 1)
+    table = np.zeros((b, nb), np.int32)
+    for r, start in enumerate(np.cumsum(live) - live):
+        table[r, :live[r]] = ids[start:start + live[r]]
+    q = rng.normal(size=(b, 1, n_q, hd)).astype(dtype)
+    kp, vp = (rng.normal(size=(num_blocks, bs, n_kv, hd)).astype(dtype)
+              for _ in range(2))
+    mask = np.ones((b, width), bool)
+    mask[:, 1] = False
+    kv_pos = np.broadcast_to(np.arange(width, dtype=np.int32), (b, width))
+    return tuple(jnp.asarray(a) for a in
+                 (q, kp, vp, table, cursors, mask, kv_pos))
+
+
+def _poisoned(pool, table, cursors, bs):
+    """`pool` with NaN in every block that is live for no row."""
+    live = np.zeros(pool.shape[0], bool)
+    for row, cur in zip(np.asarray(table), np.asarray(cursors)):
+        live[row[:max(cur // bs + 1, 0)]] = True
+    return jnp.where(live[:, None, None, None], pool, np.nan)
+
+
+GROUP_CASES = {
+    # cursors on block and group edges, G = 8: first cell, a block's
+    # last and the next one's first, a group's last and the next one's
+    # first, the table's last
+    "edges": dict(cursors=[0, 63, 64, 511, 512, 64 * 64 - 1], nb=64),
+    # G = 4 (eight heads): tables of a group and a block, of three
+    # groups and a block, and shorter than one group
+    "5 blocks": dict(cursors=[300, 255, 256, 319], nb=5, n_kv=8),
+    "13 blocks": dict(cursors=[831, 767, 768, 10], nb=13, n_kv=8),
+    "3 blocks": dict(cursors=[191, 64, 5], nb=3, n_kv=8),
+    "all rows full": dict(cursors=[16 * 64 - 1] * 3, nb=16),
+    # rows with no cell (cursor -1) around the one that has: the
+    # first copies, and the next row's, have to skip them
+    "one row live": dict(cursors=[-1, -1, 700, -1], nb=16),
+    "last row live": dict(cursors=[-1, 40, -1, 1023], nb=16),
+}
+
+
+@pytest.mark.parametrize("case", GROUP_CASES)
+def test_kernel_matches_oracle_over_groups_of_blocks(case):
+    """Every pool block that is live for no row is NaN (the trash
+    block, the spare ones): the kernel copies live blocks only, zeroes
+    what it did not copy out of the product, and equals the oracle on
+    the clean pool; a row with no cell is exact zeros."""
+    kw = dict(GROUP_CASES[case])
+    cursors = kw["cursors"]
+    q, kp, vp, table, pos, mask, kv_pos = _mk_groups(11, **kw)
+    bs, n_kv, hd = kp.shape[1:]
+    g = group_blocks(kw["nb"], bs, n_kv, hd, 4)
+    assert g == (8 if n_kv == 4 else 4)
+    want = np.asarray(_oracle(q, kp, vp, table, jnp.maximum(pos, 0),
+                              mask, kv_pos))
+    got = np.asarray(paged_decode_attention(
+        q, _poisoned(kp, table, cursors, bs),
+        _poisoned(vp, table, cursors, bs), table, pos, mask,
+        interpret=True))
+    assert np.isfinite(got).all()
+    has_cell = np.asarray(cursors) >= 0
+    np.testing.assert_allclose(got[has_cell], want[has_cell], **TOL)
+    assert not got[~has_cell].any()
+
+
+@pytest.mark.parametrize("window,cursors", [
+    # the window's first block in the group before the cursor's
+    (700, [1500, 520, 4095]),
+    # ... three groups before it, and inside the cursor's own group
+    (1600, [2047, 1700, 100]),
+    (100, [1500, 511, 512]),
+    # one cell
+    (1, [512, 0, 63]),
+])
+def test_kernel_matches_oracle_window_across_groups(window, cursors):
+    q, kp, vp, table, pos, mask, kv_pos = _mk_groups(12, cursors, 64)
+    # blocks the window has left behind are dead: give them NaN
+    first = np.maximum(np.asarray(cursors) - window + 1, 0) // 64
+    dead = np.concatenate([np.asarray(table)[r, :f]
+                           for r, f in enumerate(first)])
+    poison = jnp.zeros(kp.shape[0], bool).at[dead].set(True).at[0].set(
+        True)[:, None, None, None]
+    want = _oracle(q, kp, vp, table, pos, mask, kv_pos, window=window)
+    got = np.asarray(paged_decode_attention(
+        q, jnp.where(poison, np.nan, kp), jnp.where(poison, np.nan, vp),
+        table, pos, mask, window=window, interpret=True))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), **TOL)
+
+
+def test_kernel_matches_oracle_at_the_served_dtype():
+    """bfloat16, Mistral-7B's heads: `q k^T` on bfloat16 operands and
+    `p` in three bfloat16 parts against V. Both are exact products
+    summed in float32, so the float32 oracle on the same numbers is
+    met to the output's own rounding."""
+    q, kp, vp, table, pos, mask, kv_pos = _mk_groups(
+        13, [0, 700, 1535, 2047], 32, n_q=32, n_kv=8, dtype=np.float32)
+    q, kp, vp = (a.astype(jnp.bfloat16) for a in (q, kp, vp))
+    assert group_blocks(32, 64, 8, 128, 2) == 8
+    want = _oracle(*(a.astype(jnp.float32) for a in (q, kp, vp)),
+                   table, pos, mask, kv_pos)
+    got = paged_decode_attention(q, kp, vp, table, pos, mask,
+                                 interpret=True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=2.0 ** -8,
+                               rtol=2.0 ** -8)
+
+
+def test_group_of_blocks_is_read_off_the_shapes():
+    # mistral-7b.steady's call: 64-block tables of 64 x 8 x 128 bf16
+    assert group_blocks(64, 64, 8, 128, 2) == 8
+    # a table of four blocks pays for four; one block, for one
+    assert group_blocks(4, 64, 8, 128, 2) == 4
+    assert group_blocks(1, 64, 8, 128, 2) == 1
+    # twice the bytes a block, half the blocks; small blocks stop at
+    # the table
+    assert group_blocks(64, 64, 8, 128, 4) == 4
+    assert group_blocks(6, 8, 2, 32, 4) == 8
 
 
 # -- dispatcher doors -------------------------------------------------------
@@ -272,6 +415,64 @@ def test_engine_rejects_mismatched_pool_geometry():
     with pytest.raises(ValueError, match="num_blocks=32"):
         ContinuousEngine(engine, max_slots=2, block_size=8,
                          num_blocks=9, pool=BlockPool(32, 8))
+
+
+@pytest.mark.parametrize("window,cursors,live,fetching", [
+    # block and group edges of a 64-block table in groups of 8
+    (None, [0, 63, 64, 511, 512, 4095], 1 + 1 + 2 + 8 + 9 + 64,
+     1 + 1 + 1 + 1 + 2 + 8),
+    (None, [], 0, 0),
+    # cells 801..1500: blocks 12..23, groups 1 and 2
+    (700, [1500, 10], 12 + 1, 2 + 1),
+])
+def test_decode_kv_steps_at_the_served_shapes(window, cursors, live,
+                                              fetching):
+    """`sched.decode`'s stats, at mistral-7b.steady's geometry (the
+    arithmetic needs no weights: the engine is a stand-in)."""
+    import types
+
+    ce = object.__new__(ContinuousEngine)
+    ce.engine = types.SimpleNamespace(cfg=types.SimpleNamespace(
+        num_kv_heads=8, head_dim=128, dtype=jnp.bfloat16,
+        sliding_window=window))
+    ce.S, ce.block_size, ce.blocks_per_slot = 16, 64, 64
+    assert ce.decode_kv_steps(cursors) == {
+        "kv_blocks_live": live, "kv_steps_fetching": fetching,
+        "kv_steps": 16 * 8}
+
+
+def test_decode_dispatch_span_carries_the_kv_steps():
+    """Each `sched.decode` span of a dispatch says how many pool blocks
+    its rows can see and how many of the kernel's grid steps fetch."""
+    import contextlib
+
+    spans = []
+
+    @contextlib.contextmanager
+    def annotate(name, **stats):
+        spans.append((name, stats))
+        yield
+
+    async def run():
+        engine, _ = _llama_engine()
+        b = ContinuousBatcher(engine, asyncio.Lock(), max_slots=2,
+                              kv_block_size=8,
+                              paged_attention_impl="xla")
+        b.profiler._annotate = annotate
+        await b.submit(list(range(3, 13)), 4, ())
+        await b.close()
+        return b.cengine
+
+    ce = asyncio.get_event_loop().run_until_complete(run())
+    dispatches = [st for name, st in spans
+                  if name == "sched.decode" and "kv_steps" in st]
+    assert dispatches
+    for st in dispatches:
+        # one row decoding, its cursor in the second block of 8 cells;
+        # the tiny model's whole table is one group
+        assert st["kv_blocks_live"] == 2
+        assert st["kv_steps_fetching"] == 1
+        assert st["kv_steps"] == ce.S == 2
 
 
 def test_engine_rejects_bad_impl_name():

@@ -102,7 +102,7 @@ def test_phases_are_also_annotated_with_their_name_and_tokens():
     with p.phase("admit"):
         with p.phase("prefill", tokens=16):
             clk.t = 2.0
-    with p.phase("decode"):
+    with p.phase("decode", kv_blocks_live=9):   # a span's own stats
         clk.t = 3.0
     p.end_iteration()
     assert ann.log == [
@@ -110,7 +110,8 @@ def test_phases_are_also_annotated_with_their_name_and_tokens():
         ("open", "sched.admit", {"tokens": 0}),
         ("open", "sched.prefill", {"tokens": 16}),
         ("close", "sched.prefill"), ("close", "sched.admit"),
-        ("open", "sched.decode", {"tokens": 0}), ("close", "sched.decode"),
+        ("open", "sched.decode", {"tokens": 0, "kv_blocks_live": 9}),
+        ("close", "sched.decode"),
         ("close", "sched.iteration")]
     # a pass that never reached end_iteration (the worker's `continue`
     # on a failure) is closed by the next begin: spans never interleave

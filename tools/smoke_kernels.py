@@ -4,7 +4,13 @@ compiled (`interpret=False`) at the `llama3-1b` head shapes, against
 `ops.attention._xla_attention` reached directly — never through
 `impl="auto"`, which on TPU would compare a kernel with itself.
 
-  paged_attention   s = 1 over a 2048-cell block table
+  paged_attention   s = 1 over a 2048-cell block table; and the call
+                    `mistral-7b.steady` makes (PERF.md): 16 rows of 32
+                    query heads, 64-block tables over a five-rank pool
+                    of 1025 blocks, contexts spread from 30 to 2400
+                    tokens and two idle rows, a layer other than 0;
+                    that one is also timed, many calls in one program,
+                    and the line gives a call's microseconds
   prefill_append    s = 5 and s = 256 (one serving prefill chunk)
                     both also as the serving engines call them: the
                     pool as layer 1 of a two-layer array and the layer
@@ -46,8 +52,16 @@ from kubeflow_tpu import compile_cache  # noqa: E402
 BF16_EPS = 2.0 ** -8
 
 
+# Contexts of the rows of `mistral-7b.steady`'s decode step, in tokens
+# of a 4096-cell table: 14 rows decoding at a mean of 711 (the traced
+# window's: 13.6 rows at ~660) and two idle rows at cursor 0.
+STEADY_CONTEXTS = (30, 90, 150, 250, 330, 420, 500, 580, 660, 800, 950,
+                   1200, 1600, 2400, 1, 1)
+
+
 def run(*, n_q: int, n_kv: int, hd: int, block_size: int, cells: int,
-        chunk: int, flash_seq: int, interpret: bool) -> dict:
+        chunk: int, flash_seq: int, interpret: bool,
+        steady: dict) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -145,6 +159,51 @@ def run(*, n_q: int, n_kv: int, hd: int, block_size: int, cells: int,
         q1, layered(k_pool, 1), layered(v_pool, 1), table, pos, jmask,
         jnp.int32(1))
     check("paged_attention[s=1,layer=1 of 2]", out, ref, secs, keep)
+
+    # the serving cell's call: its own rows, table and pool, layer 2 of
+    # 3; then `calls` of them in one program, each on the next layer
+    # and the last one's output, for a call's time on the device
+    def steady_case(n_q, blocks_per_slot, num_blocks, calls, layers=3):
+        width = blocks_per_slot * block_size
+        ctx = np.maximum(
+            np.asarray(STEADY_CONTEXTS) * width // 4096, 1)
+        rows = len(ctx)
+        live = (ctx - 1) // block_size + 1
+        tab = np.zeros((rows, blocks_per_slot), np.int32)
+        ids = 1 + rng.permutation(num_blocks - 1)[:live.sum()]
+        for r, start in enumerate(np.cumsum(live) - live):
+            tab[r, :live[r]] = ids[start:start + live[r]]
+        # (drawn on the device: 400 MB of numpy normals take a minute)
+        kp, vp = (jax.random.normal(
+            key, (layers, num_blocks, block_size, n_kv, hd), dt)
+            for key in jax.random.split(jax.random.key(0)))
+        q, tab = normal(rows, 1, n_q, hd), jnp.asarray(tab)
+        cur = jnp.asarray(ctx - 1, jnp.int32)
+        valid = jnp.ones((rows, width), bool)    # an operand, as served
+        call = jax.jit(lambda q, layer, valid: paged_decode_attention(
+            q, kp, vp, tab, cur, valid, layer=layer, interpret=interpret))
+        out, secs = timed(call, q, jnp.int32(layers - 1), valid)
+        ref = reference(
+            q, kp[layers - 1][tab].reshape(rows, width, n_kv, hd),
+            vp[layers - 1][tab].reshape(rows, width, n_kv, hd),
+            cur[:, None], None)
+        name = (f"paged_attention[s=1,{rows}x{blocks_per_slot} blocks "
+                f"of {num_blocks},layer={layers - 1} of {layers}]")
+        check(name, out, ref, secs)
+
+        def chained(q, valid):
+            def one(q, i):
+                return q + call(q, i % layers, valid) / 8, None
+            return jax.lax.scan(one, q, jnp.arange(calls))[0]
+        chained = jax.jit(chained)
+        jax.block_until_ready(chained(q, valid))
+        _, secs = timed(chained, q, valid)
+        results[name]["call_us"] = round(secs / calls * 1e6, 1)
+        results[name]["live_blocks"] = int(live.sum())
+        print(f"kernels: {name}: {secs / calls * 1e6:.1f} us a call over "
+              f"{calls} calls, {live.sum()} live blocks", flush=True)
+
+    steady_case(**steady)
 
     # dense decode cache: the same rows, gathered
     out, secs = timed(jax.jit(
@@ -263,8 +322,11 @@ def main() -> int:
     compile_cache.enable()
     # llama3-1b: 16 query heads over 8 KV heads of 128, 64-cell KV blocks,
     # --max-len 2048, --prefill-chunk-tokens 256
+    # and mistral-7b.steady's decode call: 32 query heads, --max-len 4096
     result = run(n_q=16, n_kv=8, hd=128, block_size=64, cells=2048,
-                 chunk=256, flash_seq=2048, interpret=False)
+                 chunk=256, flash_seq=2048, interpret=False,
+                 steady=dict(n_q=32, blocks_per_slot=64, num_blocks=1025,
+                             calls=256))
     print(json.dumps(result), flush=True)
     return 0 if result["ok"] else 1
 
