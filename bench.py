@@ -656,13 +656,17 @@ def bench_decode_continuous(model: str, *, slots: int, prompt_len: int,
     # must hold them all so cursors never clamp mid-measurement
     budget = (3 * rounds + 1) * chunk
     assert prompt_len + budget <= max_len, (prompt_len, budget, max_len)
-    for i in range(slots):
-        p = rng.integers(0, cfg.vocab_size, prompt_len).tolist()
-        pstate, first, _, _ = ce.prefill(p, budget, {}, key)
-        st = ce.insert(st, i, pstate, first)
     sp = eng._resolve_sampling(
         np.zeros(slots, np.float32), np.zeros(slots, np.int64),
         np.ones(slots, np.float32), key, batch=slots)[0]
+    mb = ce.blocks_per_slot
+    for i in range(slots):
+        # the batcher's admission: a slot's own run of blocks, the
+        # whole prompt as one slice
+        p = rng.integers(0, cfg.vocab_size, prompt_len).tolist()
+        st = ce.adopt_slot(st, i, 1 + i * mb + np.arange(mb), 0, p[0])
+        st, _, _, key = ce.append_rows(st, [i], [p], [prompt_len],
+                                       [True], sp, key)
     st, toks, _, key = ce.step(st, sp, key, steps=chunk)  # compile + warm
     jax.block_until_ready(toks)
     decoded = rounds * chunk
@@ -921,8 +925,7 @@ def bench_decode_spill(model: str, *, slots: int, prompt_len: int,
     restores them with a host->device copy. Both arms run the same
     prompts on the same pool geometry; the re-request pass's
     per-request wall (full generation — the one-shot TTFT upper
-    bound, same proxy as decode-cont-ttft's monolithic arm) is the
-    compared number.
+    bound) is the compared number.
 
     Headline: re-request decoded tokens/s/chip with the tier ON
     (gated). The speedup ratio off/on is informational ("x"), like
@@ -1034,12 +1037,9 @@ def bench_decode_cont_ttft(model: str, *, slots: int, short_len: int,
                            verbose: bool = True) -> dict:
     """TTFT of a SHORT interactive request that arrives just after a
     LONG prompt was submitted — the collision chunked prefill exists
-    for. Monolithic admission prefills the long prompt in one gpu
-    call, so the short request's first token waits out the whole
-    thing; with `prefill_chunk_tokens=budget` the long prompt trickles
+    for: with `prefill_chunk_tokens=budget` the long prompt trickles
     in budget-size slices and the shortest-remaining-first scheduler
-    finishes the short prompt ahead of it. Headline = chunked TTFT;
-    vs_baseline = monolithic/chunked (> 1 == chunking cut TTFT)."""
+    finishes the short prompt ahead of it."""
     import asyncio
 
     from kubeflow_tpu.models import llama
@@ -1055,62 +1055,49 @@ def bench_decode_cont_ttft(model: str, *, slots: int, short_len: int,
     )
     rng = np.random.default_rng(0)
 
-    def measure(chunk_budget):
-        async def go():
-            b = ContinuousBatcher(
-                eng, asyncio.Lock(), max_slots=slots, chunk=4,
-                kv_block_size=block_size,
-                prefill_chunk_tokens=chunk_budget)
-            try:
-                # compile both prefill shapes + decode before timing
-                await asyncio.gather(
-                    b.submit(rng.integers(
-                        0, cfg.vocab_size, long_len).tolist(), 2, ()),
-                    b.submit(rng.integers(
-                        0, cfg.vocab_size, short_len).tolist(), 2, ()))
-                ttfts = []
-                for _ in range(3):  # fresh prompts: no radix shortcut
-                    long_p = rng.integers(
-                        0, cfg.vocab_size, long_len).tolist()
-                    short_p = rng.integers(
-                        0, cfg.vocab_size, short_len).tolist()
-                    fut_l = asyncio.ensure_future(
-                        b.submit(long_p, 2, ()))
-                    await asyncio.sleep(0)  # long enqueues FIRST
-                    t0 = time.perf_counter()
-                    fut_s, q = b.open_stream(short_p, 2, ())
+    async def go():
+        b = ContinuousBatcher(
+            eng, asyncio.Lock(), max_slots=slots, chunk=4,
+            kv_block_size=block_size, prefill_chunk_tokens=budget)
+        try:
+            # compile the prefill slice + decode before timing
+            await asyncio.gather(
+                b.submit(rng.integers(
+                    0, cfg.vocab_size, long_len).tolist(), 2, ()),
+                b.submit(rng.integers(
+                    0, cfg.vocab_size, short_len).tolist(), 2, ()))
+            ttfts = []
+            for _ in range(3):  # fresh prompts: no radix shortcut
+                long_p = rng.integers(
+                    0, cfg.vocab_size, long_len).tolist()
+                short_p = rng.integers(
+                    0, cfg.vocab_size, short_len).tolist()
+                fut_l = asyncio.ensure_future(
+                    b.submit(long_p, 2, ()))
+                await asyncio.sleep(0)  # long enqueues FIRST
+                t0 = time.perf_counter()
+                fut_s, q = b.open_stream(short_p, 2, ())
+                tok = await q.get()
+                ttfts.append(time.perf_counter() - t0)
+                while tok is not None:  # drain the stream
                     tok = await q.get()
-                    ttfts.append(time.perf_counter() - t0)
-                    while tok is not None:  # drain the stream
-                        tok = await q.get()
-                    await fut_s
-                    await fut_l
-                return min(ttfts)
-            finally:
-                await b.close()
+                await fut_s
+                await fut_l
+            return min(ttfts)
+        finally:
+            await b.close()
 
-        return asyncio.run(go())
-
-    mono_s = measure(None)
-    chunk_s = measure(budget)
+    chunk_s = asyncio.run(go())
     gen = detect_generation()
     if verbose:
         print(f"# decode-cont-ttft model={model} long={long_len} "
               f"short={short_len} budget={budget} "
-              f"ttft chunked={chunk_s * 1e3:.1f}ms "
-              f"monolithic={mono_s * 1e3:.1f}ms "
-              f"(x{mono_s / chunk_s:.2f})", file=sys.stderr)
+              f"ttft={chunk_s * 1e3:.1f}ms", file=sys.stderr)
     return {
         "metric": f"serving_interactive_ttft_ms[{model}-cont,{gen}]",
         "value": round(chunk_s * 1e3, 2),
         "unit": "ms",
-        "vs_baseline": round(mono_s / max(chunk_s, 1e-9), 4),
-        "extra_metrics": [
-            {"metric": ("serving_interactive_ttft_ms"
-                        f"[{model}-cont-monolithic,{gen}]"),
-             "value": round(mono_s * 1e3, 2), "unit": "ms",
-             "vs_baseline": 1.0},
-        ],
+        "vs_baseline": 1.0,
     }
 
 
@@ -1822,24 +1809,18 @@ def _run_sweep(sweep: list[str], backend: str, *, json_only: bool) -> int:
                 "tiny", slots=2, prompt_len=8, rounds=2, chunk=4,
                 max_len=64, verbose=verbose))
 
-        # TTFT under a long-prompt collision: monolithic admission vs
-        # chunked prefill, same continuous engine — the latency side
-        # of the decode-cont story.
-        def _cont_ttft() -> dict:
-            if on_tpu:
-                m = bench_decode_cont_ttft(
-                    "bench-500m-serve", slots=8, short_len=16,
-                    long_len=384, budget=64, max_len=512,
-                    block_size=64, verbose=verbose)
-            else:
-                m = bench_decode_cont_ttft(
-                    "tiny", slots=4, short_len=6, long_len=48,
-                    budget=8, max_len=64, block_size=8,
-                    verbose=verbose)
-            extras.extend(m.pop("extra_metrics", []))
-            return m
-
-        guarded("decode-cont-ttft", _cont_ttft)
+        # TTFT under a long-prompt collision — the latency side of
+        # the decode-cont story.
+        if on_tpu:
+            guarded("decode-cont-ttft", lambda: bench_decode_cont_ttft(
+                "bench-500m-serve", slots=8, short_len=16,
+                long_len=384, budget=64, max_len=512,
+                block_size=64, verbose=verbose))
+        else:
+            guarded("decode-cont-ttft", lambda: bench_decode_cont_ttft(
+                "tiny", slots=4, short_len=6, long_len=48,
+                budget=8, max_len=64, block_size=8,
+                verbose=verbose))
     if "decode-paged" in sweep:
         # Paged KV + radix prefix cache under a repeated-prompt
         # workload. The bench returns its cache-evidence metrics

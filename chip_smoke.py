@@ -14,7 +14,8 @@ calls, and checks what comes out:
            runs; must become ready, answer a repeated prompt
            identically (the second a radix hit), eight concurrent
            requests from 16 to ~1500 prompt tokens, one SSE stream,
-           and drain to exit code 0 on SIGTERM
+           all without a program compiled after it was ready, and
+           drain to exit code 0 on SIGTERM
   train    tools/smoke_train.py — the user guide's training script,
            five steps at seq 2048, loss finite and falling, attention
            through the flash kernel; over every chip of the host when
@@ -61,8 +62,7 @@ MODEL = "llama3-1b"
 VOCAB = 128256
 MAX_LEN = 2048
 SERVE_ARGS = ["--model", MODEL, "--random", "--continuous", "--warmup",
-              "--max-len", str(MAX_LEN), "--max-batch", "8",
-              "--prefill-chunk-tokens", "256"]
+              "--max-len", str(MAX_LEN), "--max-batch", "8"]
 # ops/attention.py's rule: on TPU `auto` is the Pallas paged kernel
 ATTENTION_IMPL = "pallas"
 # The whole run has 1200 s, compilation included; no single wait may
@@ -207,8 +207,17 @@ def metric(text: str, name: str, **labels) -> float:
     return total
 
 
+def compiled_so_far(port: int) -> tuple[dict, int]:
+    """The signatures past its first that each watched jit has met,
+    and the compile cache's entries."""
+    profile = json.loads(http(port, "/debug/profile"))
+    return profile["models"][MODEL]["recompiles"], cache_entries()
+
+
 def serve_requests(port: int) -> dict:
     rnd = random.Random(0)
+    # ready = compiled (--warmup): no request below may compile
+    at_ready = compiled_so_far(port)
 
     def prompt(n: int) -> list[int]:
         return [rnd.randrange(VOCAB) for _ in range(n)]
@@ -264,13 +273,20 @@ def serve_requests(port: int) -> dict:
               model=MODEL, impl=ATTENTION_IMPL) != 1:
         raise PhaseFailed(
             f"serving_attention_impl is not {ATTENTION_IMPL}")
+    at_end = compiled_so_far(port)
+    if at_end != at_ready:
+        raise PhaseFailed(
+            f"a request compiled a program the warm-up had not: "
+            f"(recompiles, cache entries) {at_ready} when ready, "
+            f"{at_end} after the last request")
     return {
         "radix_hits": hits,
         "prefill_chunk": {k: phases["prefill_chunk"][k]
                           for k in ("count", "tokens", "total_s")},
         "decode": {k: phases["decode"][k]
                    for k in ("count", "total_s", "p50_s")},
-        "recompiles": profile["models"][MODEL]["recompiles"],
+        # what the warm-up walked; nothing was added after it
+        "recompiles": at_ready[0],
         "attention_impl": ATTENTION_IMPL,
     }
 

@@ -210,7 +210,7 @@ class ModelServerSpec:
     continuous: bool = True
     warmup: bool = True
     max_batch: int = 8
-    prefill_chunk: int = 0       # 0 = off
+    prefill_chunk: int = 0       # prompt tokens a prefill slice; 0 = 256
     quant: str = ""              # "" | int8
     # "auto" = tokenizer.json beside the checkpoint when present (the
     # tools/prepare_data.py output), "none" = byte fallback forced,

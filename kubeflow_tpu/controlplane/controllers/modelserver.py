@@ -394,7 +394,7 @@ class ModelServerController(Controller):
         if spec.warmup:
             args += ["--warmup"]
         if spec.prefill_chunk:
-            args += ["--prefill-chunk", str(spec.prefill_chunk)]
+            args += ["--prefill-chunk-tokens", str(spec.prefill_chunk)]
         if spec.quant:
             args += ["--quant", spec.quant]
         # "none"/"" force byte mode; "auto" lets the server pick up

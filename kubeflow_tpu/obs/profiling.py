@@ -78,7 +78,7 @@ from kubeflow_tpu.obs.metrics import sample_quantile
 # The serving step anatomy (ContinuousBatcher worker loop).
 # prefill_chunk = chunked-prefill slices interleaved with decode
 # (ISSUE 9); draft/verify = the speculative round's two device legs.
-SERVING_PHASES = ("admit", "prefill", "prefill_chunk", "decode",
+SERVING_PHASES = ("admit", "prefill_chunk", "decode",
                   "draft", "verify", "sample", "detokenize",
                   "preempt", "resume", "host_gap", "idle")
 # The training step anatomy (Trainer.step): one device phase plus the
@@ -93,8 +93,7 @@ GOODPUT_PHASES = ("decode", "draft", "verify", "step")
 IDLE_PHASES = ("idle",)
 
 # Jitted callables the serving compile-watch wraps (closed fn set).
-WATCHED_SERVING_FNS = ("decode_step", "prefill", "insert_many",
-                       "gather_seed", "reset_slots", "prefill_append",
+WATCHED_SERVING_FNS = ("decode_step", "reset_slots", "prefill_append",
                        "spec_draft", "spec_verify")
 WATCHED_TRAIN_FNS = ("train_step",)
 

@@ -147,14 +147,13 @@ def main(argv=None) -> int:
     p.add_argument("--warmup", action="store_true")
     p.add_argument("--max-batch", type=int, default=8)
     p.add_argument("--batch-window-ms", type=float, default=0.0)
-    p.add_argument("--prefill-chunk", type=int, default=0)
     p.add_argument("--prefill-chunk-tokens", type=int, default=0,
                    help="chunked prefill token budget (continuous "
                         "only): admission prefill feeds at most this "
                         "many prompt tokens per worker iteration, "
                         "interleaved with decode chunks — bounds the "
                         "decode stall a long prompt imposes. 0 = "
-                        "monolithic admission prefill")
+                        "the default (256)")
     p.add_argument("--kv-spill-bytes", type=int, default=0,
                    help="host-RAM KV spill tier byte budget "
                         "(continuous only): radix eviction demotes "
@@ -271,6 +270,7 @@ def main(argv=None) -> int:
 
     from aiohttp import web
 
+    from kubeflow_tpu.serving.continuous import PREFILL_CHUNK_TOKENS
     from kubeflow_tpu.serving.engine import EngineConfig, InferenceEngine
     from kubeflow_tpu.serving.server import (
         create_serving_app,
@@ -338,8 +338,8 @@ def main(argv=None) -> int:
         max_batch=args.max_batch,
         continuous=args.continuous,
         warmup=args.warmup,
-        prefill_chunk=args.prefill_chunk or None,
-        prefill_chunk_tokens=args.prefill_chunk_tokens or None,
+        prefill_chunk_tokens=(args.prefill_chunk_tokens
+                              or PREFILL_CHUNK_TOKENS),
         kv_spill_bytes=args.kv_spill_bytes or None,
         pipeline_depth=args.pipeline_depth or None,
         paged_attention_impl=args.paged_attention_impl,

@@ -12,10 +12,11 @@ This module is the TPU-idiomatic fix (the JetStream pattern): keep ONE
 compiled decode step over a fixed `[slots]` batch alive and make
 admission DATA, not shape —
 
-- A new request prefills alone through the engine's existing
-  `_prefill_sample` jit (one compile per power-of-two prompt bucket),
-  then its KV rows are scattered into a free slot
-  (`ContinuousEngine._insert`, slot index traced ⇒ one compile total).
+- A new request takes a free slot FROZEN (`ContinuousEngine._adopt`,
+  slot index traced ⇒ one compile total) and its prompt is fed in
+  fixed-size slices straight through the slot's block table
+  (`_append_rows`, one `[1, s]` program for every prompt length),
+  interleaved with the other slots' decode steps.
 - Every decode step advances ALL slots at once at per-slot cursors
   (`SlotState.length` is a vector where `DecodeState.length` is a
   scalar); a request exits the moment IT hits EOS or its own max_new,
@@ -33,8 +34,8 @@ scatter write + per-row masks), so the two serving paths cannot drift.
 KV memory is PAGED (the vLLM/SGLang move): instead of a dense
 [L, S, max_len] buffer, slots address a shared pool of fixed-size
 blocks through per-slot block tables, decode gathers K/V through the
-table (`ops.paged_attention`), and prefilled rows are compacted
-(bucket left-pads stripped) as they're scattered into blocks — so a
+table (`ops.paged_attention`), and prompt slices are written at the
+row's cursor with no padding — cell index == token position, so a
 block's content is a pure function of its token prefix. That canonical
 form feeds the automatic RADIX PREFIX CACHE (serving/paged.py): prompt
 blocks are indexed by token prefix at admission and donated back to
@@ -68,7 +69,6 @@ from kubeflow_tpu.ops.pallas.flash_attention import resolve_interpret
 from kubeflow_tpu.ops.pallas.paged_attention import group_blocks
 from kubeflow_tpu.ops.rotary import rope_frequencies
 from kubeflow_tpu.serving.engine import (
-    DecodeState,
     InferenceEngine,
     SamplingParams,
     transformer_block,
@@ -84,10 +84,15 @@ from kubeflow_tpu.tenancy.ledger import TenantLedger
 from kubeflow_tpu.tenancy.scheduler import FairShareQueue, ReqMeta
 
 
+# Prompt tokens a prefill slice feeds, where the caller names no other
+# budget: the one value every chip run has used (PERF.md).
+PREFILL_CHUNK_TOKENS = 256
+
+
 def pow2_ceil(n: int) -> int:
-    """Smallest power of two >= n (n >= 1) — the group-size law shared
-    by prefill padding and admission-scatter padding (one compiled
-    program per pow2 size, not per novel count)."""
+    """Smallest power of two >= n (n >= 1) — the law `reset_slots`
+    pads its slot list by (one compiled program per pow2 size, not per
+    novel count)."""
     p = 1
     while p < n:
         p *= 2
@@ -97,7 +102,7 @@ def pow2_ceil(n: int) -> int:
 def bucket_pow2(n: int, cap: int) -> int:
     """Round up to a power of two (>= 16), capped — bounded compile
     shapes instead of one compile per novel length. Shared by the
-    window Batcher and the continuous engine's prefill."""
+    window Batcher and the continuous engine's draft prefill."""
     return min(max(pow2_ceil(n), 16), cap)
 
 
@@ -109,13 +114,11 @@ class SlotState:
     positions, which is the whole point of continuous batching.
     """
 
-    def __init__(self, k, v, length, offset, pad, tok, aid=None,
+    def __init__(self, k, v, length, tok, aid=None,
                  block_table=None, frozen=None):
         self.k = k            # [L, num_blocks, block_size, n_kv, hd]
         self.v = v            # (paged pool; block 0 is the trash block)
         self.length = length  # [S] int32 — filled cache cells per row
-        self.offset = offset  # [S] int32 — left-pad count (rope shift)
-        self.pad = pad        # [S, W] bool — padded cache cells
         self.tok = tok        # [S] int32 — last sampled token per row
         if aid is None:       # multi-LoRA adapter id (0 = plain base)
             aid = jnp.zeros(length.shape, jnp.int32)
@@ -134,8 +137,8 @@ class SlotState:
         self.frozen = frozen
 
     def tree_flatten(self):
-        return (self.k, self.v, self.length, self.offset, self.pad,
-                self.tok, self.aid, self.block_table, self.frozen), None
+        return (self.k, self.v, self.length, self.tok, self.aid,
+                self.block_table, self.frozen), None
 
     @classmethod
     def tree_unflatten(cls, _, children):
@@ -181,16 +184,15 @@ jax.tree_util.register_pytree_node(
 class ContinuousEngine:
     """Device half of continuous batching for one `InferenceEngine`.
 
-    Three compiled programs, all shape-stable for the server's life:
-    prefill (per prompt bucket — the engine's own `_prefill_jit`),
-    `_insert` (slot index is traced data), and `_step` (one token for
-    all S slots). The host half (`ContinuousBatcher`) owns admission,
+    A few compiled programs, all shape-stable for the server's life:
+    `_adopt` (slot index is traced data), `_append_rows` (one prompt
+    slice through the block table) and `_step` (one token for all S
+    slots). The host half (`ContinuousBatcher`) owns admission,
     budgets, and EOS retirement — policies live in Python, tensors on
     device.
     """
 
     def __init__(self, engine: InferenceEngine, max_slots: int = 8,
-                 prefill_chunk: int | None = None,
                  block_size: int = 64, num_blocks: int | None = None,
                  paged_attention_impl: str = "auto",
                  pool: BlockPool | None = None,
@@ -216,9 +218,6 @@ class ContinuousEngine:
                     "multi-LoRA adapter pack (the verify pass would "
                     "score base-model logits against adapter rows)")
         self.draft = draft
-        if prefill_chunk is not None and prefill_chunk < 1:
-            raise ValueError(f"prefill_chunk must be >= 1, got "
-                             f"{prefill_chunk}")
         if block_size < 2 or block_size & (block_size - 1):
             raise ValueError(
                 f"block_size must be a power of two >= 2, got {block_size}")
@@ -280,34 +279,25 @@ class ContinuousEngine:
             self.pool = pool
         else:
             self.pool = BlockPool(num_blocks, block_size)
-        # Long-prompt admissions prefill in fixed slices (engine.
-        # prefill_chunked): buckets become chunk MULTIPLES, so every
-        # long prompt reuses the one [g, chunk] program instead of
-        # minting a power-of-two bucket compile per length class.
-        self.prefill_chunk = prefill_chunk
         # KV buffers dominate serving HBM: donate the old state so step
-        # and insert update in place instead of holding two copies
+        # and append update in place instead of holding two copies
         # (same policy as the Trainer's donated TrainState). The
         # adapter pack rides as an ARGUMENT, not a closure — closed-over
         # arrays bake into the lowered module as constants (see the
         # params note in engine.InferenceEngine.__init__).
         self._step_jit = jax.jit(self._step, donate_argnums=(2,),
                                  static_argnames=("steps",))
-        self._insert_jit = jax.jit(self._insert, donate_argnums=(0,))
-        self._insert_many_jit = jax.jit(self._insert_many,
-                                        donate_argnums=(0,))
-        self._gather_seed_jit = jax.jit(self._gather_seed)
         self._reset_jit = jax.jit(self._reset_slots, donate_argnums=(0,))
         # migration (serving/migration.py): export gathers block
         # payloads without touching the state; import scatters them in
-        # place (donated, like insert/step — KV dominates serving HBM)
+        # place (donated, like append/step — KV dominates serving HBM)
         self._export_jit = jax.jit(self._export_blocks)
         self._import_jit = jax.jit(self._import_blocks,
                                    donate_argnums=(0,))
-        # chunked prefill (ISSUE 9): adopt points a frozen slot at its
-        # planned blocks, copy_cells seeds a partial CoW block, and
-        # append_rows feeds budget-size prompt slices through the fused
-        # prefill/append path between decode chunks
+        # admission: adopt points a frozen slot at its planned blocks,
+        # copy_cells seeds a partial CoW block, and append_rows feeds
+        # budget-size prompt slices through the fused prefill/append
+        # path between decode chunks
         self._append_jit = jax.jit(self._append_rows,
                                    donate_argnums=(2,))
         self._adopt_jit = jax.jit(self._adopt, donate_argnums=(0,))
@@ -332,8 +322,6 @@ class ContinuousEngine:
         return SlotState(
             jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype),
             jnp.zeros((self.S,), jnp.int32),
-            jnp.zeros((self.S,), jnp.int32),
-            jnp.zeros((self.S, self.kv_width), bool),
             jnp.zeros((self.S,), jnp.int32),
             None,
             jnp.zeros((self.S, self.blocks_per_slot), jnp.int32),
@@ -368,236 +356,6 @@ class ContinuousEngine:
         return (2 * cfg.num_layers * self.block_size
                 * cfg.num_kv_heads * cfg.head_dim * itemsize)
 
-    # -- admission --------------------------------------------------------
-
-    def bucket_for(self, n_tokens: int, max_new: int,
-                   reserve: int = 0) -> int:
-        """Prefill bucket for one request: power-of-two (or, past one
-        chunk with chunked prefill enabled, the ceil chunk multiple),
-        falling back to the EXACT length when the bucket plus this
-        request's max_new would overrun the cache (bucket pads occupy
-        cache cells, so a bucket the admission check never saw could
-        silently clamp the last decode writes otherwise). `reserve` is
-        cache already spoken for (a shared prefix's length)."""
-        cap = self.engine.ec.max_len - reserve
-        c = self.prefill_chunk
-        if c and n_tokens > c:
-            bc = -(-n_tokens // c) * c
-            if bc + max_new <= cap:
-                return bc
-            return n_tokens  # exact single-shot; capacity-checked upstream
-        b = bucket_pow2(n_tokens, max(cap - max_new, 0))
-        return b if b >= n_tokens else n_tokens
-
-    def prefill_batch(self, token_lists: list[list[int]], bucket: int,
-                      samplings: list[dict[str, Any]], rng: jax.Array,
-                      adapter_ids: list[int] | None = None,
-                      prefix_state=None):
-        """Prefill g prompts sharing one bucket in a single dispatch
-        and sample each prompt's first token. Returns (batch-g
-        DecodeState, first tokens [g], done [g]) ready for `insert`.
-        Batching admissions matters under load: per-request prefill
-        dispatch is the continuous design's other overhead tax next to
-        per-token stepping. `adapter_ids` (multi-LoRA) selects each
-        row's resident fine-tune; when the engine carries an
-        adapter_pack the adapter arguments are ALWAYS passed (zeros by
-        default) so warmup and traffic share one jit signature.
-        `prefix_state` (a batch-1 `engine.precompute_prefix` result)
-        seeds every row with shared-prefix KV: only the suffix
-        prefills, and since `state.length` is traced data the SAME
-        compiled prefill program serves prefixed and plain
-        admissions."""
-        eng = self.engine
-        g = len(token_lists)
-        arr = np.zeros((g, bucket), np.int32)
-        mask = np.zeros((g, bucket), bool)
-        for i, toks in enumerate(token_lists):
-            arr[i, bucket - len(toks):] = toks
-            mask[i, bucket - len(toks):] = True
-        ec = eng.ec
-        sp, rng = eng._resolve_sampling(
-            np.asarray([s.get("temperature", ec.temperature)
-                        for s in samplings], np.float32),
-            np.asarray([s.get("top_k", ec.top_k)
-                        for s in samplings], np.int64),
-            np.asarray([s.get("top_p", ec.top_p)
-                        for s in samplings], np.float32),
-            rng, batch=g)
-        adapters = ids = None
-        if eng.adapter_pack is not None:
-            adapters = eng.adapter_pack.blocks
-            ids = jnp.asarray(adapter_ids if adapter_ids is not None
-                              else [0] * g, jnp.int32)
-        if prefix_state is None:
-            state0 = eng.init_state(g)
-        elif prefix_state.k.shape[1] == g:
-            # already batch-g (a gather_seed radix-cache seed)
-            state0 = prefix_state
-        else:
-            ps = prefix_state
-            state0 = DecodeState(
-                jnp.repeat(ps.k, g, axis=1), jnp.repeat(ps.v, g, axis=1),
-                ps.length, jnp.repeat(ps.pad, g, axis=0),
-                jnp.repeat(ps.offset, g, axis=0))
-        c = self.prefill_chunk
-        if c and bucket > c and bucket % c == 0:
-            state, first, _, done, lps = eng.prefill_chunked(
-                eng.params, jnp.asarray(arr), state0, rng,
-                sp, jnp.asarray(mask), chunk=c,
-                adapters=adapters, adapter_ids=ids)
-        else:
-            state, first, _, done, lps = eng._prefill_jit(
-                eng.params, jnp.asarray(arr), state0, rng, sp,
-                jnp.asarray(mask), adapters=adapters, adapter_ids=ids)
-        return state, first, done, lps
-
-    def prefill(self, tokens: list[int], max_new: int,
-                sampling: dict[str, Any], rng: jax.Array):
-        """Single-request admission (the g=1 case of prefill_batch)."""
-        return self.prefill_batch(
-            [tokens], self.bucket_for(len(tokens), max_new),
-            [sampling], rng)
-
-    def _insert(self, st: SlotState, slot, pstate, row, first, aid,
-                table, seed_len):
-        """Scatter row `row` of a prefilled batch-g DecodeState into
-        the pool blocks listed in `table`, and point slot `slot` at
-        them. All indices are traced — one compile per prefill batch
-        size g serves every (slot, row, adapter, table) combination.
-
-        The row is COMPACTED on the way in: prefill left-pads prompts
-        to their bucket, so cells [seed_len, seed_len + npad) of the
-        dense row are padding. The gather below drops them, making
-        pool blocks a pure function of the token prefix — cell index
-        == logical position, offset 0, no pads. That canonical form is
-        what lets the radix tree share blocks across requests whose
-        prompts merely share tokens (their bucket pads differ).
-
-        The write covers EVERY cell of every block in `table` — unused
-        tail entries must be the trash block (0). Fully overwriting the
-        table is a safety invariant: a freed block may still receive
-        in-flight garbage writes from its previous slot's last decode
-        chunk, and this insert is ordered after that chunk by the state
-        donation chain, so it always lands last.
-        """
-        eng = self.engine
-        ec = eng.ec
-        L = eng.cfg.num_layers
-        bs, mb, w = self.block_size, self.blocks_per_slot, self.kv_width
-        npad = pstate.offset[row].astype(jnp.int32)
-        j = jnp.arange(w, dtype=jnp.int32)
-        src = jnp.minimum(jnp.where(j < seed_len, j, j + npad),
-                          ec.max_len - 1)
-        prow_k = jax.lax.dynamic_slice_in_dim(pstate.k, row, 1, axis=1)
-        prow_v = jax.lax.dynamic_slice_in_dim(pstate.v, row, 1, axis=1)
-        ck = jnp.take(prow_k[:, 0], src, axis=1)  # [L, w, n_kv, hd]
-        cv = jnp.take(prow_v[:, 0], src, axis=1)
-        ck = ck.reshape(L, mb, bs, *ck.shape[2:])
-        cv = cv.reshape(L, mb, bs, *cv.shape[2:])
-        k = st.k.at[:, table].set(ck.astype(st.k.dtype))
-        v = st.v.at[:, table].set(cv.astype(st.v.dtype))
-        length = st.length.at[slot].set(
-            (pstate.length - npad).astype(jnp.int32))
-        offset = st.offset.at[slot].set(0)
-        pad = st.pad.at[slot].set(False)
-        tok = st.tok.at[slot].set(first[row])
-        aid_v = st.aid.at[slot].set(aid)
-        bt = st.block_table.at[slot].set(table)
-        frozen = st.frozen.at[slot].set(False)
-        return SlotState(k, v, length, offset, pad, tok, aid_v, bt,
-                         frozen)
-
-    def _auto_table(self, slot: int) -> np.ndarray:
-        """Canonical block table for engine-managed allocation (direct
-        `insert` callers: benches, tests, warmup): slot s owns blocks
-        [1 + s*MB, 1 + (s+1)*MB), the dense-equivalent layout. With a
-        pool smaller than the default the mapping wraps (aliases) —
-        fine for warmup (content is throwaway) but direct callers who
-        need correctness should keep the default pool size or pass
-        explicit tables. The batcher always passes explicit tables."""
-        usable = self.num_blocks - 1
-        base = slot * self.blocks_per_slot
-        return np.asarray(
-            [1 + (base + j) % usable
-             for j in range(self.blocks_per_slot)], np.int32)
-
-    def insert(self, st: SlotState, slot: int, pstate, first,
-               row: int = 0, aid: int = 0, *, table=None,
-               seed_len: int = 0) -> SlotState:
-        if table is None:
-            table = self._auto_table(slot)
-        return self._insert_jit(st, jnp.asarray(slot, jnp.int32), pstate,
-                                jnp.asarray(row, jnp.int32), first,
-                                jnp.asarray(aid, jnp.int32),
-                                jnp.asarray(table, jnp.int32),
-                                jnp.asarray(seed_len, jnp.int32))
-
-    def _insert_many(self, st: SlotState, slots, pstate, rows, first,
-                     aids, tables, seed_lens):
-        """A whole admission group's scatters in one program (a scan
-        over `_insert`) — one device dispatch per group instead of one
-        per request, the admission-side sibling of the group prefill."""
-
-        def body(st, xs):
-            slot, row, aid, table, seed_len = xs
-            return self._insert(st, slot, pstate, row, first, aid,
-                                table, seed_len), None
-
-        st, _ = jax.lax.scan(body, st,
-                             (slots, rows, aids, tables, seed_lens))
-        return st
-
-    def insert_many(self, st: SlotState, slots: list[int], pstate,
-                    rows: list[int], first,
-                    aids: list[int] | None = None, *, tables=None,
-                    seed_lens: list[int] | None = None) -> SlotState:
-        """Insert prefilled rows `rows` into `slots` in ONE dispatch.
-        Compiles one cheap program per group SIZE (bounded by
-        max_slots); the batcher's admission path uses this, the g=1
-        `insert` stays for benches and direct callers. `tables` ([n,
-        blocks_per_slot] physical block ids, trash-padded) and
-        `seed_lens` (cells [0, seed_len) of each row are an already-
-        compact shared-prefix seed) default to the engine-managed
-        dense-equivalent layout with no seed."""
-        n = len(slots)
-        if len(rows) != n or (aids is not None and len(aids) != n):
-            raise ValueError(
-                f"insert_many: {n} slots vs {len(rows)} rows"
-                + (f" vs {len(aids)} aids" if aids is not None else ""))
-        if tables is None:
-            tables = np.stack([self._auto_table(s) for s in slots])
-        if seed_lens is None:
-            seed_lens = [0] * n
-        return self._insert_many_jit(
-            st, jnp.asarray(slots, jnp.int32), pstate,
-            jnp.asarray(rows, jnp.int32), first,
-            jnp.asarray(aids if aids is not None else [0] * n,
-                        jnp.int32),
-            jnp.asarray(tables, jnp.int32),
-            jnp.asarray(seed_lens, jnp.int32))
-
-    def _gather_seed(self, k_pool, v_pool, chains, m):
-        """Assemble a batch-g prefill seed (`DecodeState`) from cached
-        pool blocks: row i's cells [0, m) are read through block chain
-        `chains[i]` (trash-padded past ceil(m / block_size)). Offset 0
-        and no pads by the blocks' canonical-form invariant."""
-        g = chains.shape[0]
-        max_len = self.engine.ec.max_len
-        k = k_pool[:, chains]  # [L, g, MB, bs, n_kv, hd]
-        v = v_pool[:, chains]
-        k = k.reshape(*k.shape[:2], self.kv_width, *k.shape[4:])
-        v = v.reshape(*v.shape[:2], self.kv_width, *v.shape[4:])
-        return DecodeState(
-            k[:, :, :max_len], v[:, :, :max_len],
-            m.astype(jnp.int32),
-            jnp.zeros((g, max_len), bool),
-            jnp.zeros((g,), jnp.int32))
-
-    def gather_seed(self, st: SlotState, chains, m: int) -> DecodeState:
-        return self._gather_seed_jit(st.k, st.v,
-                                     jnp.asarray(chains, jnp.int32),
-                                     jnp.asarray(m, jnp.int32))
-
     def _reset_slots(self, st: SlotState, slots):
         """Point retired slots back at the trash block and zero their
         cursors. Ordered after the slots' last in-flight decode chunk
@@ -606,11 +364,8 @@ class ContinuousEngine:
         tree) — the paged design's one cross-slot hazard."""
         bt = st.block_table.at[slots].set(0)
         length = st.length.at[slots].set(0)
-        offset = st.offset.at[slots].set(0)
-        pad = st.pad.at[slots].set(False)
         frozen = st.frozen.at[slots].set(False)
-        return SlotState(st.k, st.v, length, offset, pad, st.tok,
-                         st.aid, bt, frozen)
+        return SlotState(st.k, st.v, length, st.tok, st.aid, bt, frozen)
 
     def reset_slots(self, st: SlotState, slots: list[int]) -> SlotState:
         """Host entry: pads the slot list to a power of two by
@@ -638,13 +393,13 @@ class ContinuousEngine:
     def _import_blocks(self, st: SlotState, ids, k, v):
         kp = st.k.at[:, ids].set(k.astype(st.k.dtype))
         vp = st.v.at[:, ids].set(v.astype(st.v.dtype))
-        return SlotState(kp, vp, st.length, st.offset, st.pad, st.tok,
-                         st.aid, st.block_table, st.frozen)
+        return SlotState(kp, vp, st.length, st.tok, st.aid,
+                         st.block_table, st.frozen)
 
     def import_blocks(self, st: SlotState, block_ids, k, v) -> SlotState:
         """Scatter migrated block payloads into locally-allocated
         blocks `block_ids` (donates `st` — in-place pool update, same
-        policy as insert/step). Payloads keep the exporter's canonical
+        policy as append/step). Payloads keep the exporter's canonical
         form (cell index == logical token position), so imported
         blocks are immediately radix-shareable. Raises ValueError when
         the payload shape disagrees with this pool's block geometry —
@@ -664,47 +419,6 @@ class ContinuousEngine:
                                 jnp.asarray(list(block_ids), jnp.int32),
                                 jnp.asarray(k), jnp.asarray(v))
 
-    def warmup(self, buckets=(16,), step_sizes=(1,)) -> int:
-        """Compile a serving shape set ahead of traffic: prefill and
-        insert for every power-of-two group size x REGISTERED prompt
-        bucket, and the decode step for every chunk size. Warming
-        turns first-arrival compile stalls into startup cost for the
-        covered buckets; prompts that land in an UNREGISTERED bucket
-        (longer than the warmed set, or an exact-length fallback)
-        still compile on first arrival — cover the deployment's real
-        prompt-length distribution via `buckets` rather than warming
-        every bucket up to max_len (each [g, bucket] prefill compile
-        costs real startup time on TPU). Returns the number of
-        programs warmed."""
-        eng = self.engine
-        rng = jax.random.key(0)
-        st = self.init_slots()
-        sp = eng._resolve_sampling(
-            np.zeros(self.S, np.float32), np.zeros(self.S, np.int64),
-            np.ones(self.S, np.float32), rng, batch=self.S)[0]
-        n = 0
-        g = 1
-        greedy = {"temperature": 0.0, "top_k": 0, "top_p": 1.0}
-        while g <= self.S:
-            for b in buckets:
-                pstate, first, _, _ = self.prefill_batch(
-                    [[0]] * g, b, [greedy] * g, rng)
-                # admissions insert as a GROUP (insert_many), padded
-                # to a power of two by the batcher — warming each pow2
-                # size covers EVERY arrival count
-                st = self.insert_many(
-                    st, list(range(g)), pstate, list(range(g)), first)
-                n += 2
-            g *= 2
-        for steps in step_sizes:
-            st, _, _, rng = self.step(st, sp, rng, steps)
-            n += 1
-        # the batcher resets retired slots' block tables between
-        # chunks — warm that program too (pow2-padded, so size 1
-        # covers every retirement count)
-        st = self.reset_slots(st, [0])
-        return n + 1
-
     # -- decode -----------------------------------------------------------
 
     def _decode_one(self, params, adapters, st: SlotState,
@@ -723,14 +437,12 @@ class ContinuousEngine:
         rng, sub = jax.random.split(rng)
 
         positions = st.length[:, None]                      # [S, 1]
-        rope_positions = jnp.maximum(positions - st.offset[:, None], 0)
         inv_freq = rope_frequencies(cfg.head_dim, theta=cfg.rope_theta)
         kv_positions = jnp.broadcast_to(
             jnp.arange(self.kv_width, dtype=jnp.int32)[None, :],
             (S, self.kv_width))
         # causal q>=kv masking hides stale cells beyond each row's
-        # cursor (a reused slot's old tail); pads are never attended.
-        kv_valid = ~st.pad
+        # cursor (a reused slot's old tail)
         write_at = jnp.minimum(st.length, ec.max_len - 1)
         # paged write coordinates: logical cell -> (physical block,
         # offset) through each row's block table. Frozen rows (mid
@@ -773,19 +485,18 @@ class ContinuousEngine:
             def attn(q, kp, vp):
                 # kp/vp are every layer's block POOL and `li` says
                 # which to read; the paged path gathers each row's K/V
-                # through its block table. Insert-time compaction keeps
-                # cell index == logical token position, so masking
-                # semantics (and bits — see paged_attention's
-                # docstring) match the dense path.
+                # through its block table. Cell index == logical
+                # token position, so masking semantics (and bits — see
+                # paged_attention's docstring) match the dense path.
                 return paged_attention(
                     q, kp, vp, st.block_table, positions, kv_positions,
-                    causal=True, kv_mask=kv_valid,
+                    causal=True, kv_mask=None,
                     window=getattr(cfg, "sliding_window", None),
                     layer=li, impl=self.attention_impl)
 
             x, (k_all, v_all) = transformer_block(
-                cfg, fam, p, x, rope_positions, inv_freq, write_kv,
-                attn, proj)
+                cfg, fam, p, x, positions, inv_freq, write_kv, attn,
+                proj)
             return (x, k_all, v_all), None
 
         layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
@@ -803,7 +514,6 @@ class ContinuousEngine:
             k_new, v_new,
             jnp.where(st.frozen, st.length,
                       jnp.minimum(st.length + 1, ec.max_len)),
-            st.offset, st.pad,
             jnp.where(st.frozen, st.tok, nxt.astype(jnp.int32)),
             st.aid, st.block_table, st.frozen)
         return st, nxt, lp, rng
@@ -861,10 +571,7 @@ class ContinuousEngine:
         s = tokens.shape[1]
         positions = (start[:, None]
                      + jnp.arange(s, dtype=jnp.int32)[None, :])
-        rope_positions = jnp.maximum(
-            positions - st.offset[slots][:, None], 0)
         inv_freq = rope_frequencies(cfg.head_dim, theta=cfg.rope_theta)
-        kv_valid = ~st.pad[slots]
         x = eng._embed(params, tokens)
 
         def layer(carry, scanned):
@@ -891,14 +598,14 @@ class ContinuousEngine:
                 # visited blocks rewritten in place
                 out, cell["k"], cell["v"] = paged_prefill_attention(
                     q, kn, vn, kp, vp, table, start, n_valid,
-                    kv_mask=kv_valid,
+                    kv_mask=None,
                     window=getattr(cfg, "sliding_window", None),
                     layer=li, impl=self.paged_attention_impl)
                 return out
 
             x, _ = transformer_block(
-                cfg, fam, p, x, rope_positions, inv_freq, write_kv,
-                attn, proj)
+                cfg, fam, p, x, positions, inv_freq, write_kv, attn,
+                proj)
             return (x, cell["k"], cell["v"]), None
 
         layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
@@ -938,8 +645,8 @@ class ContinuousEngine:
         tok = st.tok.at[slots].set(newtok)
         frozen = st.frozen.at[slots].set(
             jnp.where(finish, False, st.frozen[slots]))
-        st = SlotState(k_new, v_new, length, st.offset, st.pad, tok,
-                       st.aid, st.block_table, frozen)
+        st = SlotState(k_new, v_new, length, tok, st.aid,
+                       st.block_table, frozen)
         return st, nxt, lp, rng
 
     def append_rows(self, st: SlotState, slots, tokens, n_valid,
@@ -965,8 +672,6 @@ class ContinuousEngine:
         return SlotState(
             st.k, st.v,
             st.length.at[slot].set(seed_len),
-            st.offset.at[slot].set(0),
-            st.pad.at[slot].set(False),
             st.tok.at[slot].set(tok),
             st.aid.at[slot].set(aid),
             st.block_table.at[slot].set(table),
@@ -991,8 +696,7 @@ class ContinuousEngine:
         vd = jnp.where(sel, st.v[:, src], st.v[:, dst])
         return SlotState(
             st.k.at[:, dst].set(kd), st.v.at[:, dst].set(vd),
-            st.length, st.offset, st.pad, st.tok, st.aid,
-            st.block_table, st.frozen)
+            st.length, st.tok, st.aid, st.block_table, st.frozen)
 
     def copy_cells(self, st: SlotState, src: int, dst: int,
                    n: int) -> SlotState:
@@ -1065,8 +769,8 @@ class ContinuousEngine:
 
     def _draft_insert(self, dst: DraftSlots, slot, pstate, npad):
         """Compact row 0 of a batch-1 draft prefill `DecodeState` into
-        draft-cache row `slot` (bucket left-pads stripped, mirroring
-        `_insert`'s canonical form)."""
+        draft-cache row `slot` (bucket left-pads stripped: cell index
+        == token position, as in the target's pool)."""
         W = self.draft.ec.max_len
         j = jnp.arange(W, dtype=jnp.int32)
         src = jnp.minimum(j + npad, W - 1)
@@ -1182,8 +886,8 @@ class ContinuousEngine:
             st.frozen, st.length,
             jnp.minimum(st.length + k + 1, ec.max_len))
         tok = jnp.where(st.frozen, st.tok, extra.astype(jnp.int32))
-        st = SlotState(k_pool, v_pool, length, st.offset, st.pad, tok,
-                       st.aid, st.block_table, st.frozen)
+        st = SlotState(k_pool, v_pool, length, tok, st.aid,
+                       st.block_table, st.frozen)
         # draft rollback: the scan advanced every row by gamma; keep
         # the k+1 cells the accepted tokens fed (capped at gamma),
         # then feed the last drafted token unconditionally — its write
@@ -1277,8 +981,7 @@ class ContinuousBatcher:
 
     def __init__(self, engine: InferenceEngine, gpu_lock: asyncio.Lock,
                  *, max_slots: int = 8, chunk: int = 4,
-                 prefill_chunk: int | None = None,
-                 prefill_chunk_tokens: int | None = None,
+                 prefill_chunk_tokens: int = PREFILL_CHUNK_TOKENS,
                  prefixes: dict[str, list[int]] | None = None,
                  max_pending: int = 256,
                  pipeline_depth: int | None = None,
@@ -1300,14 +1003,13 @@ class ContinuousBatcher:
         # worker feeds at most `prefill_chunk_tokens` prompt tokens per
         # loop iteration through the fused paged append path,
         # interleaved with decode chunks — the per-step token budget
-        # that keeps the decode batch dense. None keeps the monolithic
-        # admission prefill. (Distinct from `prefill_chunk`, which only
-        # slices the MONOLITHIC prefill's compile shapes.)
-        if prefill_chunk_tokens is not None and prefill_chunk_tokens < 1:
+        # that keeps the decode batch dense. One `[1, budget]` program
+        # serves every prompt length (clamped below to the cache
+        # width: no prompt is longer).
+        if prefill_chunk_tokens < 1:
             raise ValueError(
                 f"prefill_chunk_tokens must be >= 1, got "
                 f"{prefill_chunk_tokens}")
-        self.prefill_chunk_tokens = prefill_chunk_tokens
         # Speculative decoding on paged KV (ISSUE 9): with a draft
         # engine, every decode iteration becomes a draft(gamma) +
         # verify(gamma+1) round batched across slots; accepted tokens
@@ -1345,18 +1047,20 @@ class ContinuousBatcher:
         # bounded: one program per steps value in [1, chunk].
         self.chunk = chunk
         self.cengine = ContinuousEngine(
-            engine, max_slots, prefill_chunk=prefill_chunk,
+            engine, max_slots,
             block_size=kv_block_size, num_blocks=kv_pool_blocks,
             paged_attention_impl=paged_attention_impl, draft=draft)
+        self.prefill_chunk_tokens = min(prefill_chunk_tokens,
+                                        self.cengine.kv_width)
         # Automatic radix prefix cache over the block pool: every
         # admitted prompt's full blocks are indexed by token prefix
         # (at admission, so even in-flight prefills are sharable), and
         # retirement donates a request's blocks back to the tree. A new
         # prompt sharing a cached prefix seeds its prefill from those
         # blocks and only computes the suffix. Refcount-0 blocks are
-        # LRU-evicted when admission needs the space — the automatic
-        # generalization of the manual `prefixes` registration (which
-        # stays as a pre-warm hint).
+        # LRU-evicted when admission needs the space. Registered
+        # `prefixes` are names for token lists and ride the same
+        # cache: the first use computes the prefix, later uses hit.
         self._radix = RadixPrefixCache(self.cengine.pool)
         # Block lifecycle ledger (ISSUE 13): attached to the pool
         # before any alloc, so every block birth/death is booked to a
@@ -1444,14 +1148,8 @@ class ContinuousBatcher:
         ce = self.cengine
         ce._step_jit = self.compile_watch.watch(
             ce._step_jit, "decode_step")
-        ce._insert_many_jit = self.compile_watch.watch(
-            ce._insert_many_jit, "insert_many")
-        ce._gather_seed_jit = self.compile_watch.watch(
-            ce._gather_seed_jit, "gather_seed")
         ce._reset_jit = self.compile_watch.watch(
             ce._reset_jit, "reset_slots")
-        engine._prefill_jit = self.compile_watch.watch(
-            engine._prefill_jit, "prefill")
         ce._append_jit = self.compile_watch.watch(
             ce._append_jit, "prefill_append")
         if ce.draft is not None:
@@ -1460,15 +1158,13 @@ class ContinuousBatcher:
             ce._spec_verify_jit = self.compile_watch.watch(
                 ce._spec_verify_jit, "spec_verify")
         # Shared prefixes (system prompts): token lists registered at
-        # construction; each computes its KV ONCE, lazily, on first use
-        # (device work belongs under the gpu lock, not in __init__).
+        # construction, prepended to a request that names one.
         self._prefixes = dict(prefixes or {})
         for pname, ptoks in self._prefixes.items():
             if not ptoks or len(ptoks) >= engine.ec.max_len:
                 raise ValueError(
                     f"prefix {pname!r}: length {len(ptoks)} invalid "
                     f"for max_len {engine.ec.max_len}")
-        self._prefix_states: dict[str, Any] = {}
         self.engine = engine
         self.gpu_lock = gpu_lock
         self.calls = 0            # decode steps (device invocations)
@@ -1573,19 +1269,36 @@ class ContinuousBatcher:
             "heat": self._radix.heat_digest(16),
         }
 
-    def warmup(self, buckets=None) -> int:
-        """Blocking ahead-of-traffic compile of the full shape set
-        (call before serving traffic; the app's on_startup hook does
-        when create_serving_app(warmup=True)). With chunked prefill
-        enabled the default bucket set includes a two-chunk prompt so
-        the chunk-loop and tail programs warm too."""
-        if buckets is None:
-            buckets = [16]
-            c = self.cengine.prefill_chunk
-            if c and 2 * c <= self.engine.ec.max_len and 2 * c != 16:
-                buckets.append(2 * c)
-        return self.cengine.warmup(
-            buckets=tuple(buckets), step_sizes=range(1, self.chunk + 1))
+    def warmup(self) -> int:
+        """Blocking ahead-of-traffic compile of every program admission
+        and decode run (call before serving traffic; the app's
+        on_startup hook does when create_serving_app(warmup=True)):
+        `adopt_slot`, `copy_cells`, `append_rows` at the one slice
+        shape, `step` for 1 .. `chunk` steps and `reset_slots` at each
+        power-of-two list size — on a scratch state, through the same
+        host entries and watches the worker uses, so that traffic
+        meets no new signature. Returns the number of programs
+        warmed."""
+        ce = self.cengine
+        st = ce.init_slots()
+        sp, rng = self._sp(), self._rng
+        toks = np.zeros((1, self.prefill_chunk_tokens), np.int32)
+        table = np.zeros(ce.blocks_per_slot, np.int32)
+        # twice: the first admission meets a state and a key fresh
+        # from the host, every later one the previous program's
+        # outputs — two signatures to a jit's cache, one program
+        for _ in range(2):
+            st = ce.adopt_slot(st, 0, table, 0, 0)
+            st = ce.copy_cells(st, 0, 0, 1)
+            st, _, _, rng = ce.append_rows(st, [0], toks, [1], [True],
+                                           sp, rng)
+        for steps in range(1, self.chunk + 1):
+            st, _, _, rng = ce.step(st, sp, rng, steps)
+        sizes = [1 << i for i in range(pow2_ceil(ce.S).bit_length())]
+        for n in sizes:
+            st = ce.reset_slots(st, [0] * n)
+        jax.block_until_ready(st)
+        return 3 + self.chunk + len(sizes)
 
     # -- public API -------------------------------------------------------
 
@@ -1678,7 +1391,9 @@ class ContinuousBatcher:
                 f"adapter {adapter!r} requested but no adapter pack "
                 "is loaded on this engine")
         aid = pack.resolve(adapter) if pack else 0
-        prefix = sampling.get("prefix", "")
+        # a registered prefix is a name for tokens: expanded HERE, so
+        # the request plans, replays and migrates as its whole prompt
+        prefix = sampling.pop("prefix", "")
         if prefix:
             if prefix not in self._prefixes:
                 raise ValueError(
@@ -1695,6 +1410,7 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"prefix {plen} + prompt {len(tokens)} + max_new "
                     f"{max_new} exceeds model max_len {cap}")
+            tokens = list(self._prefixes[prefix]) + list(tokens)
         if self._worker is None or self._worker.done():
             self._worker = asyncio.get_event_loop().create_task(
                 self._run())
@@ -1721,7 +1437,7 @@ class ContinuousBatcher:
                  priority=meta.priority)
         self.timelines.add(tl)
         self._pending.append(
-            (tokens, max_new, sampling, fut, queue, aid, prefix, meta))
+            (tokens, max_new, sampling, fut, queue, aid, meta))
         self._wake.set()
         return fut
 
@@ -1811,10 +1527,10 @@ class ContinuousBatcher:
             self.cengine.pool.free(dup, cause="divergence")
 
     def _index_inflight(self, rec: _Slot) -> None:
-        """At admission, index the prompt's full blocks in the radix
+        """Once the prompt is fed, index its full blocks in the radix
         tree immediately — a concurrent request sharing the prefix can
         seed from them while this one is still decoding (device order
-        is safe: its gather is dispatched after our insert). Created
+        is safe: its reads are dispatched after our writes). Created
         nodes start with a ref held by this request (`hold=True`): the
         tree must not evict a block our own table points at."""
         bs = self.cengine.block_size
@@ -1997,7 +1713,7 @@ class ContinuousBatcher:
         meta.cost = float(remaining)
         self._pending.appendleft(
             (list(rec.kv_toks), remaining, rec.sampling, rec.fut,
-             rec.queue, rec.aid, "", meta))
+             rec.queue, rec.aid, meta))
         self._wake.set()
 
     def tenant_stats(self) -> dict:
@@ -2009,33 +1725,6 @@ class ContinuousBatcher:
         for tenant, depth in self._pending.depths().items():
             stats.setdefault(tenant, {})["queued"] = depth
         return stats
-
-    async def _get_prefix_state(self, name: str):
-        """Lazily compute (once) a registered prefix's KV, memoized as
-        a single-flight task per name: concurrent first users await the
-        SAME device computation instead of each re-running
-        `precompute_prefix` through the executor (the old check-then-
-        compute raced across its awaits and could prefill the prefix
-        once per concurrent miss). A failed compute is evicted so the
-        next use retries."""
-        task = self._prefix_states.get(name)
-        if task is None:
-            loop = asyncio.get_event_loop()
-
-            async def compute():
-                async with self.gpu_lock:
-                    return await loop.run_in_executor(
-                        None, self.engine.precompute_prefix,
-                        self._prefixes[name])
-
-            task = loop.create_task(compute())
-            self._prefix_states[name] = task
-        try:
-            return await task
-        except Exception:
-            if self._prefix_states.get(name) is task:
-                self._prefix_states.pop(name)
-            raise
 
     def _spill_reader(self, block: int):
         """Device->host snapshot of one pool block's K/V payload —
@@ -2061,9 +1750,9 @@ class ContinuousBatcher:
         `note_restore` for adopted blocks and stamps `meta.restored`
         so the admission's `on_prefix` can split the metric source."""
         tier = self._spill_tier
-        if tier is None or tier.spilled_blocks == 0 or item[6]:
+        if tier is None or tier.spilled_blocks == 0:
             return
-        tokens, meta = item[0], item[7]
+        tokens, meta = item[0], item[6]
         full = [int(t) for t in tokens]
         ns = meta.ns
         bs = self.cengine.block_size
@@ -2155,8 +1844,8 @@ class ContinuousBatcher:
         can't cover it even after evicting idle cached blocks — the
         caller defers the request until retirements free space.
 
-        Plan fields: `full` (prompt incl. registered prefix — the
-        token stream the slot's KV will hold), `suffix` (what prefill
+        Plan fields: `full` (the prompt — the token stream the
+        slot's KV will hold), `suffix` (what prefill
         must actually compute), `m` (cached cells seeding the prefill:
         cell index == token index by the blocks' canonical form),
         `chain` (ref'd radix nodes backing cells [0, m - m % bs)),
@@ -2165,35 +1854,28 @@ class ContinuousBatcher:
         its own fresh block, which is the copy-on-write), `fresh`
         (newly allocated blocks), `table` (the slot's physical block
         table, trash-padded)."""
-        tokens, max_new, _sampling, _fut, _queue, _aid, prefix, meta = item
+        tokens, max_new, _sampling, _fut, _queue, _aid, meta = item
         ceng = self.cengine
         bs, mb = ceng.block_size, ceng.blocks_per_slot
         chain: list = []
         extra = None
         m = 0
-        if prefix:
-            # registered-prefix path: seeded from the precomputed
-            # batch-1 state (base-model KV), not the radix tree
-            full = list(self._prefixes[prefix]) + list(tokens)
-            suffix = list(tokens)
-            m = len(self._prefixes[prefix])
-        else:
-            full = list(tokens)
-            if self._st is not None:
-                nodes, pnode, plen = self._radix.match(full, ns=meta.ns)
-                # always leave >= 1 token to prefill: sampling the
-                # first output needs a forward pass over something
-                m = min(len(nodes) * bs + plen, len(full) - 1)
-                cut = m // bs
-                if cut < len(nodes):
-                    # cap bit inside the full-block chain: the node at
-                    # the cut becomes the partial (copy-on-write) seed
-                    extra = nodes[cut] if m % bs else None
-                    nodes = nodes[:cut]
-                elif m % bs:
-                    extra = pnode
-                chain = nodes
-            suffix = full[m:]
+        full = list(tokens)
+        if self._st is not None:
+            nodes, pnode, plen = self._radix.match(full, ns=meta.ns)
+            # always leave >= 1 token to prefill: sampling the
+            # first output needs a forward pass over something
+            m = min(len(nodes) * bs + plen, len(full) - 1)
+            cut = m // bs
+            if cut < len(nodes):
+                # cap bit inside the full-block chain: the node at
+                # the cut becomes the partial (copy-on-write) seed
+                extra = nodes[cut] if m % bs else None
+                nodes = nodes[:cut]
+            elif m % bs:
+                extra = pnode
+            chain = nodes
+        suffix = full[m:]
         n_total = -(-min(len(full) + max_new,
                          self.engine.ec.max_len) // bs)
         n_fresh = n_total - len(chain)
@@ -2236,7 +1918,7 @@ class ContinuousBatcher:
 
     def _drop_plan(self, plan) -> None:
         """Roll back `_plan_blocks` reservations (admission failed or
-        the request was cancelled before insert)."""
+        the request was cancelled before its slot was adopted)."""
         self._radix.unref(plan["chain"])
         if plan["extra"] is not None:
             self._radix.unref([plan["extra"]])
@@ -2244,310 +1926,64 @@ class ContinuousBatcher:
             self.cengine.pool.free(plan["fresh"], cause="refdrop")
 
     async def _admit_group(self, items: list) -> None:
-        # `admit` phase wraps the whole admission pass; the grouped
-        # prefill/gather device call inside is its own nested `prefill`
-        # phase (nesting subtracts: admit records planning + insert
-        # only, never double-counts prefill time)
-        with self.profiler.phase("admit"):
-            await self._admit_group_inner(items)
-
-    async def _admit_group_inner(self, items: list) -> None:
-        """Admit up to len(self._free) requests; items sharing a
-        prefill bucket, prefix AND cached-seed length share ONE prefill
-        dispatch, and the group's slot scatters share one insert_many
-        dispatch. A prefill failure fails its bucket group only; an
-        insert failure fails its whole admit group (and every active
-        request too when the donated buffers were consumed — see the
-        except block). Admission is now accounted in BLOCKS, not just
-        slots: a request whose worst-case block need outruns the pool
-        (even after evicting idle cached blocks) is deferred back to
-        the queue head until retirements free blocks — later, smaller
-        requests may admit past it (the slot-only admission had no
-        such case: every slot held max_len by construction)."""
+        """Admit up to len(self._free) requests: reserve each one's
+        blocks (`_plan_blocks`: radix seeding, copy-on-write, tenancy
+        quotas), point a FROZEN slot at them, and queue the suffix
+        for budget-slice feeding by the worker loop. Admission is
+        accounted in BLOCKS, not just slots: a request whose
+        worst-case block need outruns the pool (even after evicting
+        idle cached blocks) is deferred back to the queue head until
+        retirements free blocks — later, smaller requests may admit
+        past it. An adopt failure fails its own request (and every
+        active request too when the donated buffers were consumed —
+        see the except block)."""
         loop = asyncio.get_event_loop()
-        if self.prefill_chunk_tokens:
-            # chunked-prefill mode: non-prefix requests adopt a frozen
-            # slot now and feed their prompt in budget slices between
-            # decode chunks. Registered-prefix requests keep the
-            # monolithic path (their KV seed lives in a dense prefix
-            # state, not pool blocks) and fall through below.
-            items = await self._admit_chunked(loop, items)
-            if not items:
-                return
-        plans = []
         deferred = []
-        for item in items:
-            try:
-                await self._restore_spilled(item)
-            except Exception:  # noqa: BLE001 — restore is best-effort
-                pass           # plain prefill covers whatever's missing
-            plan = self._plan_blocks(item)
-            if plan is None:
-                deferred.append(item)
-                if item[7].priority == "interactive":
-                    # an interactive request couldn't get blocks: let
-                    # the worker consider preempting a batch decode
-                    # even though free SLOTS exist
-                    self._interactive_blocked = True
-            else:
-                plans.append((item, plan))
-        for item in reversed(deferred):
-            self._pending.appendleft(item)
-        groups: dict[tuple, list] = {}
-        for item, plan in plans:
-            prefix = item[6]
-            reserve = plan["m"]
-            b = self.cengine.bucket_for(len(plan["suffix"]), item[1],
-                                        reserve)
-            groups.setdefault((b, prefix, plan["m"]), []).append(
-                (item, plan))
-        for (b, prefix, m), group in groups.items():
-            self._rng, sub = jax.random.split(self._rng)
-            # pad the group to a power of two with greedy dummy rows:
-            # prefill/insert shapes come from a SET of log2(max_slots)
-            # sizes instead of one compile per novel group size (the
-            # same row bucketing the window Batcher does)
-            gp = pow2_ceil(len(group))
-            npad_rows = gp - len(group)
-            lists = [pl["suffix"] for _, pl in group] + [[0]] * npad_rows
-            samps = ([it[2] for it, _ in group]
-                     + [{"temperature": 0.0, "top_k": 0, "top_p": 1.0}]
-                     * npad_rows)
-            ids = [it[5] for it, _ in group] + [0] * npad_rows
-
-            def run_prefill(pstate0=None, lists=lists, b=b, samps=samps,
-                            sub=sub, ids=ids):
-                # host sync (np.asarray) INSIDE the executor: jax
-                # dispatch is async, so syncing on the loop thread
-                # would block the whole HTTP server for the device time
-                pstate, first, _, lps = self.cengine.prefill_batch(
-                    lists, b, samps, sub, ids, pstate0)
-                return pstate, np.asarray(first), np.asarray(lps)
-
-            ptoks = sum(len(pl["suffix"]) for _, pl in group)
-            try:
-                with self.profiler.phase("prefill", tokens=ptoks):
-                    if prefix:
-                        pstate0 = await self._get_prefix_state(prefix)
-                    elif m > 0:
-                        # seed rows from cached pool blocks: gather
-                        # each row's chain (+ partial CoW block) into a
-                        # batch-g DecodeState. self._st exists — a
-                        # non-empty radix tree implies blocks were
-                        # inserted into it.
-                        mb = self.cengine.blocks_per_slot
-                        chains = np.zeros((gp, mb), np.int32)
-                        for i, (_, pl) in enumerate(group):
-                            phys = [n.block for n in pl["chain"]]
-                            if pl["extra"] is not None:
-                                phys.append(pl["extra"].block)
-                            chains[i, :len(phys)] = phys
-
-                        def run_gather(st=self._st, chains=chains,
-                                       m=m):
-                            return self.cengine.gather_seed(
-                                st, chains, m)
-
-                        async with self.gpu_lock:
-                            pstate0 = await loop.run_in_executor(
-                                None, run_gather)
-                    else:
-                        pstate0 = None
-                    async with self.gpu_lock:
-                        pstate, firsts, flps = \
-                            await loop.run_in_executor(
-                                None, run_prefill, pstate0)
-            except Exception as e:  # noqa: BLE001
-                for it, pl in group:
-                    self._drop_plan(pl)
-                    self._fail(it[3], it[4], e)
-                continue
-            admit = []
-            for row, (item, plan) in enumerate(group):
-                if item[3].done():  # cancelled while prefilling
+        with self.profiler.phase("admit"):
+            for item in items:
+                if item[3].done():
+                    continue
+                try:
+                    await self._restore_spilled(item)
+                except Exception:  # noqa: BLE001 — best-effort
+                    pass  # (plain prefill covers whatever's missing)
+                plan = self._plan_blocks(item)
+                if plan is None:
+                    deferred.append(item)
+                    if item[6].priority == "interactive":
+                        # an interactive request couldn't get blocks:
+                        # let the worker consider preempting a batch
+                        # decode even though free SLOTS exist
+                        self._interactive_blocked = True
+                    continue
+                try:
+                    await self._adopt_one(loop, item, plan)
+                except Exception as e:  # noqa: BLE001
                     self._drop_plan(plan)
-                else:
-                    admit.append((row, item, plan))
-            if not admit:
-                continue
-            slots = [self._free.pop() for _ in admit]
-            # Pad the scatter list to a power of two by REPEATING the
-            # last (slot, row, aid, table, seed) tuple — re-inserting
-            # the same row into the same slot is idempotent under the
-            # sequential scan — so insert_many's compile set stays the
-            # warmed log2(max_slots) sizes instead of one program per
-            # novel arrival count (a mid-traffic TPU compile stalls
-            # every active decode for seconds).
-            pad = pow2_ceil(len(admit)) - len(admit)
-            ins_slots = slots + [slots[-1]] * pad
-            ins_rows = [r for r, _, _ in admit] + [admit[-1][0]] * pad
-            ins_aids = ([it[5] for _, it, _ in admit]
-                        + [admit[-1][1][5]] * pad)
-            tables = np.stack([pl["table"] for _, _, pl in admit]
-                              + [admit[-1][2]["table"]] * pad)
-            seed_lens = [m] * len(ins_slots)
-            try:
-                if self._st is None:
-                    self._st = self.cengine.init_slots()
-
-                def run_insert(st=self._st):
-                    return self.cengine.insert_many(
-                        st, ins_slots, pstate, ins_rows, firsts,
-                        ins_aids, tables=tables, seed_lens=seed_lens)
-
-                async with self.gpu_lock:
-                    # ONE dispatch for the whole group's scatters (the
-                    # admission-side sibling of the group prefill)
-                    self._st = await loop.run_in_executor(
-                        None, run_insert)
-            except Exception as e:  # noqa: BLE001
-                self._free.extend(slots)
-                for _, it, pl in admit:
-                    self._drop_plan(pl)
-                    self._fail(it[3], it[4], e)
-                # insert donates self._st: a failure that fired AFTER
-                # dispatch leaves the old buffers consumed, and keeping
-                # them would crash the NEXT decode step with a
-                # confusing deleted-buffer error. A failure BEFORE
-                # dispatch (bad shapes, host-side raise) leaves them
-                # intact — then only this group dies. Distinguish the
-                # two instead of guessing.
-                if self._st is not None and any(
-                        leaf.is_deleted() for leaf in
-                        jax.tree.leaves(self._st)
-                        if hasattr(leaf, "is_deleted")):
-                    self._fail_all(RuntimeError(
-                        f"slot state lost to donated insert: {e}"))
-                continue
-            for slot, (row, (tokens, max_new, sampling, fut, queue,
-                             aid, _, meta), plan) in zip(slots, admit):
-                self.requests += 1
-                rec = _Slot(fut, max_new, queue,
-                            stop=tuple(tuple(s) for s in
-                                       sampling.get("stop", ())))
-                rec.meta = meta
-                rec.sampling = sampling
-                rec.aid = aid
-                resumed = meta.resume is not None
-                if resumed:
-                    # preemption replay: restore the already-emitted
-                    # tokens and the ORIGINAL budget (item max_new was
-                    # only the remainder, for block planning)
-                    rec.out = list(meta.resume["out"])
-                    rec.lps = list(meta.resume["lps"])
-                    rec.max_new = meta.resume["max_new"]
-                    meta.resume = None
-                if self._ledger is not None:
-                    rec.block_charge = len(plan["fresh"])
-                    self._ledger.note_slot_taken(meta.tenant,
-                                                 rec.block_charge)
-                rec.kv_toks = list(plan["full"])
-                rec.node_refs = list(plan["chain"])
-                cut = len(plan["chain"])
-                rec.owned = {cut + i: blk
-                             for i, blk in enumerate(plan["fresh"])}
-                if plan["extra"] is not None:
-                    # the partial block was only a read-only seed
-                    # source; its content now lives in this row's own
-                    # fresh block (the copy half of copy-on-write)
-                    self._radix.unref([plan["extra"]])
-                self._active[slot] = rec
-                # make this prompt's blocks reusable immediately, not
-                # just at retirement (in-flight prefix sharing)
-                self._index_inflight(rec)
-                computed, reused = len(plan["suffix"]), plan["m"]
-                self.tokens_prefilled += computed
-                self.tokens_reused += reused
-                if reused > 0:
-                    self.prefix_hits += 1
-                else:
-                    self.prefix_misses += 1
-                if self.on_prefix is not None:
-                    try:
-                        self.on_prefix(computed, reused, reused > 0,
-                                       meta.tenant,
-                                       restored=meta.restored)
-                    except Exception:  # noqa: BLE001 — metrics hook
-                        pass           # must never kill the worker
-                if resumed:
-                    # zero-duration marker: the replay's cost already
-                    # lives in admit/prefill; the marker's COUNT is
-                    # what reconciles against timeline `resume` events
-                    self.profiler.record("resume", 0.0)
-                if meta.timeline is not None:
-                    meta.timeline.event(
-                        "resume" if resumed else "admit", slot=slot,
-                        prefill_computed=computed,
-                        prefill_reused=reused)
-                if not resumed and self.on_queue_wait is not None:
-                    try:
-                        self.on_queue_wait(
-                            self._clock() - meta.t_enqueue)
-                    except Exception:  # noqa: BLE001 — metrics hook
-                        pass
-                ec = self.engine.ec
-                self._temp[slot] = sampling.get(
-                    "temperature", ec.temperature)
-                self._topk[slot] = sampling.get("top_k", ec.top_k)
-                self._topp[slot] = sampling.get("top_p", ec.top_p)
-                self._sp_dirty = True
-                if self.cengine.draft is not None and self.spec_enabled:
-                    # seed the draft cache row BEFORE the first token
-                    # is appended: the draft row must hold exactly the
-                    # prompt's KV, aligned with the target cursor
-                    await self._draft_seed(loop, slot, rec)
-                self._emit(slot, rec, int(firsts[row]),
-                           float(flps[row]), decode=False)
-
-    async def _admit_chunked(self, loop, items: list) -> list:
-        """Chunked-prefill admission: reserve each request's blocks
-        (same planner as the monolithic path — radix seeding, CoW and
-        tenancy quotas identical), point a FROZEN slot at them, and
-        queue the suffix for budget-slice feeding by the worker loop.
-        Returns the items this path does not handle (registered-prefix
-        requests), for the monolithic admission to pick up."""
-        rest = [it for it in items if it[6]]
-        mine = [it for it in items if not it[6]]
-        if not mine:
-            return rest
-        deferred = []
-        for item in mine:
-            if item[3].done():
-                continue
-            try:
-                await self._restore_spilled(item)
-            except Exception:  # noqa: BLE001 — restore is best-effort
-                pass           # plain prefill covers whatever's missing
-            plan = self._plan_blocks(item)
-            if plan is None:
-                deferred.append(item)
-                if item[7].priority == "interactive":
-                    self._interactive_blocked = True
-                continue
-            try:
-                await self._adopt_one(loop, item, plan)
-            except Exception as e:  # noqa: BLE001
-                self._drop_plan(plan)
-                self._fail(item[3], item[4], e)
-                # adopt donates self._st: distinguish pre- from
-                # post-dispatch failure exactly like insert does
-                if self._st is not None and any(
-                        leaf.is_deleted() for leaf in
-                        jax.tree.leaves(self._st)
-                        if hasattr(leaf, "is_deleted")):
-                    self._fail_all(RuntimeError(
-                        f"slot state lost to donated adopt: {e}"))
-                    return []
-        for item in reversed(deferred):
-            self._pending.appendleft(item)
-        return rest
+                    self._fail(item[3], item[4], e)
+                    # adopt donates self._st: a failure that fired
+                    # AFTER dispatch leaves the old buffers consumed,
+                    # and keeping them would crash the NEXT decode
+                    # step with a confusing deleted-buffer error. A
+                    # failure BEFORE dispatch (bad shapes, host-side
+                    # raise) leaves them intact — then only this
+                    # request dies. Distinguish the two instead of
+                    # guessing.
+                    if self._st is not None and any(
+                            leaf.is_deleted() for leaf in
+                            jax.tree.leaves(self._st)
+                            if hasattr(leaf, "is_deleted")):
+                        self._fail_all(RuntimeError(
+                            f"slot state lost to donated adopt: {e}"))
+            for item in reversed(deferred):
+                self._pending.appendleft(item)
 
     async def _adopt_one(self, loop, item, plan) -> None:
         """Device + bookkeeping half of one chunked admission: install
         the planned block table on a free slot (frozen, cursor at the
         cached-seed length), copy the partial CoW seed block if any,
         and register the host record with its pending suffix."""
-        tokens, max_new, sampling, fut, queue, aid, _pfx, meta = item
+        tokens, max_new, sampling, fut, queue, aid, meta = item
         slot = self._free.pop()
         full, m = plan["full"], plan["m"]
         bs = self.cengine.block_size
@@ -2635,8 +2071,7 @@ class ContinuousBatcher:
         (at most max_slots - 1 of them), not the unbounded queue.
         The finishing slice samples the request's first token, unrefs
         the frozen flag, and indexes the now-complete prompt blocks in
-        the radix tree (the same in-flight indexing the monolithic
-        path does at admission)."""
+        the radix tree, where a concurrent request can share them."""
         best = None
         for cand in list(self._prefill_q):
             crec = self._active.get(cand)
@@ -2934,8 +2369,8 @@ class ContinuousBatcher:
             # admission can hand their freed blocks to a new request:
             # the reset rides the state-donation chain, so it lands
             # after the retiree's last in-flight garbage writes and
-            # before the new owner's insert. (Slots re-admitted in the
-            # same iteration are safe either way — insert overwrites
+            # before the new owner's adopt. (Slots re-admitted in the
+            # same iteration are safe either way — adopt overwrites
             # the table — but an idle freed slot must stop writing.)
             if self._dirty and self._st is not None:
                 dirty = sorted(set(self._dirty))
@@ -2978,16 +2413,15 @@ class ContinuousBatcher:
                         delay = min(max(
                             self._pending.pacing_delay(), 0.001), 0.05)
                     await asyncio.sleep(delay)
-            if self._prefill_q and self._st is not None:
-                # one prompt slice per iteration: the decode stall a
-                # monolithic prefill would impose is chopped into
-                # budget-size pieces interleaved with decode chunks
-                try:
-                    await self._advance_prefills(loop)
-                except Exception as e:  # noqa: BLE001
-                    self._fail_all(e)
-                    inflight.clear()
-                    continue
+            # one prompt slice per iteration: the decode stall a
+            # whole-prompt prefill would impose is chopped into
+            # budget-size pieces interleaved with decode chunks
+            try:
+                await self._advance_prefills(loop)
+            except Exception as e:  # noqa: BLE001
+                self._fail_all(e)
+                inflight.clear()
+                continue
             try:
                 # drain whatever already finished, without blocking.
                 # INSIDE the try: an async-dispatched chunk that failed
@@ -3042,39 +2476,33 @@ class ContinuousBatcher:
         for rec in self._active.values():
             if rec.fut.done() or rec.meta is None:
                 continue
-            # the replay tokens already embed any registered prefix —
-            # re-expanding it on the peer would double-prefix
-            samp = {k: v for k, v in (rec.sampling or {}).items()
-                    if k != "prefix"}
             out.append({
                 "request_id": rec.meta.request_id,
                 "tenant": rec.meta.tenant,
                 "tokens": list(rec.kv_toks),
                 "out": list(rec.out),
                 "max_new": rec.max_new,
-                "sampling": samp,
+                "sampling": dict(rec.sampling or {}),
             })
         pending = (self._pending.items() if self._ledger is not None
                    else list(self._pending))
         for item in pending:
-            tokens, max_new, sampling, fut, _q, _aid, _pfx, meta = item
+            tokens, max_new, sampling, fut, _q, _aid, meta = item
             if fut.done() or meta is None:
                 continue
             emitted: list[int] = []
-            samp = dict(sampling)
             if meta.resume is not None:
                 # preempted-and-parked: tokens is already the replay
                 # prompt (incl. emitted), budget is the original
                 emitted = list(meta.resume["out"])
                 max_new = meta.resume["max_new"]
-                samp.pop("prefix", None)
             out.append({
                 "request_id": meta.request_id,
                 "tenant": meta.tenant,
                 "tokens": list(tokens),
                 "out": emitted,
                 "max_new": max_new,
-                "sampling": samp,
+                "sampling": dict(sampling),
             })
         return out
 
@@ -3138,15 +2566,14 @@ class ContinuousBatcher:
             off += n
             meta = rec.meta
             rid = meta.request_id if meta is not None else ""
-            samp = {k: v for k, v in (rec.sampling or {}).items()
-                    if k != "prefix"}  # tokens already embed it
             records.append(migration.pack_record(
                 request_id=rid,
                 tenant=meta.tenant if meta is not None else "",
                 ns=meta.ns if meta is not None else "",
                 tokens=list(rec.kv_toks), out=list(rec.out),
                 lps=list(rec.lps), max_new=rec.max_new,
-                sampling=samp, geometry=geometry, kv=kv))
+                sampling=dict(rec.sampling or {}), geometry=geometry,
+                kv=kv))
             if meta is not None and meta.timeline is not None:
                 meta.timeline.event("migrate_out",
                                     emitted=len(rec.out), blocks=n)
@@ -3158,24 +2585,23 @@ class ContinuousBatcher:
             leftovers = list(self._pending)
             self._pending.clear()
         for item in leftovers:
-            tokens, max_new, sampling, fut, queue, _aid, _p, meta = item
+            tokens, max_new, sampling, fut, queue, _aid, meta = item
             if fut.done():
                 continue
             out_toks: list[int] = []
             lps: list[float] = []
-            samp = dict(sampling)
             if meta is not None and meta.resume is not None:
                 out_toks = list(meta.resume["out"])
                 lps = list(meta.resume["lps"])
                 max_new = meta.resume["max_new"]
-                samp.pop("prefix", None)
             rid = meta.request_id if meta is not None else ""
             records.append(migration.pack_record(
                 request_id=rid,
                 tenant=meta.tenant if meta is not None else "",
                 ns=meta.ns if meta is not None else "",
                 tokens=list(tokens), out=out_toks, lps=lps,
-                max_new=max_new, sampling=samp, geometry=geometry,
+                max_new=max_new, sampling=dict(sampling),
+                geometry=geometry,
                 kv=None))
             if meta is not None and meta.timeline is not None:
                 meta.timeline.event("migrate_out",

@@ -764,25 +764,6 @@ class InferenceEngine:
         return self._score_jit(self.params, tokens, self.init_state(b),
                                prompt_mask)
 
-    def precompute_prefix(self, tokens: list[int]):
-        """Run a shared prefix (system prompt) ONCE; returns a batch-1
-        DecodeState at length=len(tokens). Admissions seeded from this
-        state prefill only their suffix — the per-request cost of an
-        N-token system prompt drops to zero after the first compute.
-        Exact length (no bucketing): prefixes are few, registered at
-        startup, and their state is reused for the server's life."""
-        if not tokens:
-            raise ValueError("prefix must be non-empty")
-        if len(tokens) >= self.ec.max_len:
-            raise ValueError(
-                f"prefix {len(tokens)} leaves no cache room "
-                f"(max_len {self.ec.max_len})")
-        arr = jnp.asarray([tokens], jnp.int32)
-        _, state = self._forward_jit(
-            self.params, arr, self.init_state(1),
-            prompt_mask=jnp.ones_like(arr, bool))
-        return state
-
     def prefill_chunked(self, params, prompt, state, rng,
                         sp: SamplingParams, prompt_mask, *, chunk: int,
                         adapters=None, adapter_ids=None):

@@ -32,6 +32,7 @@ from aiohttp import web
 from kubeflow_tpu import obs as obs_lib
 from kubeflow_tpu.obs import endpoints as obs_endpoints
 from kubeflow_tpu.serving.continuous import (
+    PREFILL_CHUNK_TOKENS,
     ContinuousBatcher,
     MigratedAway,
     Overloaded,
@@ -650,8 +651,7 @@ def create_serving_app(engines: dict[str, InferenceEngine],
                        *, tokenizer=None, batch_window_ms: float = 0.0,
                        max_batch: int = 8, continuous: bool = False,
                        warmup: bool = False,
-                       prefill_chunk: int | None = None,
-                       prefill_chunk_tokens: int | None = None,
+                       prefill_chunk_tokens: int = PREFILL_CHUNK_TOKENS,
                        prefixes: dict[str, list[int]] | None = None,
                        max_pending: int | None = None,
                        pipeline_depth: int | None = None,
@@ -691,10 +691,10 @@ def create_serving_app(engines: dict[str, InferenceEngine],
     one fused batched pass — token-identical to plain decode, and it
     composes with radix caching, preemption and migration. Requires a
     draft for every served model. `prefill_chunk_tokens` (continuous
-    only) turns admission prefill into budget-size slices interleaved
-    with decode chunks: no decode stall longer than the budget while a
-    long prompt prefills (distinct from `prefill_chunk`, which only
-    buckets the monolithic prefill's compile shapes). `kv_block_size` /
+    only) is the budget of an admission prefill slice: prompts are fed
+    that many tokens at a time, interleaved with decode chunks, so no
+    decode stall is longer than the budget while a long prompt
+    prefills. `kv_block_size` /
     `kv_pool_blocks` (continuous only) shape the paged KV cache: pow2
     tokens per block and total pool blocks per model (default: the
     dense equivalent, every slot can reach max_len — shrink the pool
@@ -778,8 +778,8 @@ def create_serving_app(engines: dict[str, InferenceEngine],
     # and interleaved generate calls would just thrash compile caches.
     lock = asyncio.Lock()
     app[GPU_LOCK_KEY] = lock
-    if not continuous and (warmup or prefill_chunk or prefixes
-                           or prefill_chunk_tokens is not None
+    if not continuous and (warmup or prefixes
+                           or prefill_chunk_tokens != PREFILL_CHUNK_TOKENS
                            or spec_decode
                            or max_pending is not None
                            or pipeline_depth is not None
@@ -794,7 +794,7 @@ def create_serving_app(engines: dict[str, InferenceEngine],
         # caller believes overload sheds at that depth; tenancy
         # especially: the caller believes quotas are enforced)
         raise ValueError(
-            "warmup/prefill_chunk/prefill_chunk_tokens/prefixes/"
+            "warmup/prefill_chunk_tokens/prefixes/"
             "max_pending/pipeline_depth/kv_block_size/kv_pool_blocks/"
             "kv_spill_bytes/paged_attention_impl/spec_decode/tenancy "
             "require continuous=True")
@@ -808,14 +808,11 @@ def create_serving_app(engines: dict[str, InferenceEngine],
                 f"model; missing {sorted(missing)}")
     app[TENANCY_KEY] = tenancy
     if continuous:
-        # prefill_chunk: long prompts admit in fixed slices — chunk-
-        # multiple buckets, one [g, chunk] compile for every length.
-        # prefixes: named system prompts whose KV computes once; a
-        # request opts in with {"prefix": name}.
+        # prefixes: named system prompts; a request opts in with
+        # {"prefix": name} and the radix cache keeps their KV.
         app[BATCHERS_KEY] = {
             name: ContinuousBatcher(
                 eng, lock, max_slots=max_batch,
-                prefill_chunk=prefill_chunk,
                 prefill_chunk_tokens=prefill_chunk_tokens,
                 prefixes=prefixes,
                 max_pending=256 if max_pending is None else max_pending,
@@ -1212,8 +1209,8 @@ def fleet_stats(app: web.Application) -> dict:
     (the analog: requests co-scheduled per device call). `pool` is
     this replica's disaggregation role and `phase_seconds` folds the
     PhaseProfiler's cumulative totals into the two coarse phases the
-    pool autoscaler splits on (prefill + chunked prefill vs decode +
-    speculative draft/verify)."""
+    pool autoscaler splits on (prefill slices vs decode + speculative
+    draft/verify)."""
     queue_depth = active = max_slots = 0
     kv_free = kv_total = 0
     phase_prefill = phase_decode = 0.0
@@ -1227,8 +1224,7 @@ def fleet_stats(app: web.Application) -> dict:
             kv_total += b.cengine.num_blocks
             cache_digest.extend(b._radix.heat_digest(16))
             totals = b.profiler.totals()
-            phase_prefill += (totals.get("prefill", 0.0)
-                              + totals.get("prefill_chunk", 0.0))
+            phase_prefill += totals.get("prefill_chunk", 0.0)
             phase_decode += (totals.get("decode", 0.0)
                              + totals.get("draft", 0.0)
                              + totals.get("verify", 0.0))

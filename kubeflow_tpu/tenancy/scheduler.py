@@ -21,7 +21,7 @@ admittable right now", not as empty.
 
 Queue items are the batcher's pending tuples; this module only
 touches two indices: `item[3]` (the request future — cancelled
-requests don't count as waiting work) and `item[7]` (the `ReqMeta`
+requests don't count as waiting work) and `item[6]` (the `ReqMeta`
 below, which the batcher attaches at enqueue).
 """
 
@@ -32,7 +32,7 @@ import collections
 from kubeflow_tpu.tenancy.config import PRIORITIES, TenancyConfig
 from kubeflow_tpu.tenancy.ledger import TenantLedger
 
-_FUT, _META = 3, 7
+_FUT, _META = 3, 6
 
 
 class ReqMeta:

@@ -390,7 +390,8 @@ tenancy = config_from_dict({{"tenants": {{
 }}}})
 app = srv.create_serving_app({{"tiny": eng}}, continuous=True, warmup=True,
                              max_batch={max_batch},
-                             prefill_chunk_tokens={chunk} or None,
+                             prefill_chunk_tokens=(
+                                 {chunk} or srv.PREFILL_CHUNK_TOKENS),
                              tenancy=tenancy if {qos} else None,
                              slo_ttft_s={{"interactive": {slo_ttft_s}}})
 if not {qos}:
@@ -1118,9 +1119,9 @@ def run_disagg(clients: int, requests: int, max_new: int, *,
     construction, so zero client failures is the pass bar."""
     total = prefill_replicas + decode_replicas
     # Long prompts must be EXPENSIVE relative to a decode step for the
-    # split to pay: a monolithic admission prefill of `long_blocks`
-    # blocks stalls every decode slot on a mixed replica, which is the
-    # head-of-line blocking the prefill pool absorbs.
+    # split to pay: the prefill slices of `long_blocks` blocks delay
+    # every decode slot on a mixed replica, which is the head-of-line
+    # blocking the prefill pool absorbs.
     prompt_len = long_blocks * block_size
     if prompt_len + max_new > max_len:
         raise ValueError(
@@ -3493,13 +3494,13 @@ def main() -> int:
                         "there is no backlog to measure against")
     p.add_argument("--tenant-bulk-prompt", type=int, default=4,
                    help="tenants mode: batch-class prompt length in "
-                        "tokens — long prompts make every bulk "
-                        "admission a monolithic-prefill stall unless "
-                        "--prefill-chunk-tokens bounds it")
+                        "tokens — a long prompt stalls decode for "
+                        "a slice at a time; --prefill-chunk-tokens "
+                        "bounds the slice")
     p.add_argument("--prefill-chunk-tokens", type=int, default=0,
                    help="tenants mode: chunked-prefill token budget "
-                        "for BOTH arms' servers (0 = monolithic "
-                        "admission prefill)")
+                        "for BOTH arms' servers (0 = the server's "
+                        "default)")
     p.add_argument("--tenant-live-requests", type=int, default=8,
                    help="tenants mode: sequential interactive streams "
                         "measured for TTFT")

@@ -4,8 +4,7 @@ continuous-path feature.
 `prefill_chunk_tokens=N` changes WHEN prompt tokens are fed (budget
 slices interleaved with decode chunks, through the fused append path)
 but must never change WHAT any request receives: every test here pins
-bit-exact parity against the monolithic batcher / solo-generate
-oracle — across budgets (1 token per iteration up to >= the whole
+bit-exact parity against the solo-generate oracle — across budgets (1 token per iteration up to >= the whole
 prompt in one slice), model families, radix prefix reuse, tenancy
 preemption, and mid-flight migration export.
 """
@@ -58,9 +57,10 @@ def _solo(engine, prompt, max_new):
 
 
 def _batcher(engine, budget=None, **kw):
+    if budget is not None:
+        kw["prefill_chunk_tokens"] = budget
     return ContinuousBatcher(engine, asyncio.Lock(), max_slots=4,
-                             kv_block_size=BS,
-                             prefill_chunk_tokens=budget, **kw)
+                             kv_block_size=BS, **kw)
 
 
 async def _run_all(batcher, prompts, max_new):
@@ -72,20 +72,22 @@ async def _run_all(batcher, prompts, max_new):
         await batcher.close()
 
 
-async def test_chunked_parity_across_budgets_llama(llama_engine):
+@pytest.mark.parametrize("budget", [1, 3, 64, None])
+async def test_chunked_parity_across_budgets_llama(llama_engine, budget):
     """Budget 1 (one token per worker iteration — the most interleaved
     schedule possible), a mid-size budget that straddles block
-    boundaries, and a budget >= every prompt (one slice, the chunked
-    path's degenerate monolithic case) all emit the oracle's exact
-    tokens."""
+    boundaries, a budget >= every prompt (one slice) and the budget
+    left unset (the default, clamped to this engine's cache width) all
+    emit the oracle's exact tokens."""
     engine, cfg = llama_engine
     gen = np.random.default_rng(4)
     prompts = [gen.integers(0, cfg.vocab_size, n).tolist()
                for n in (4, 7, 12, 20)]
     want = [_solo(engine, p, 5) for p in prompts]
-    for budget in (1, 3, 64):
-        got = await _run_all(_batcher(engine, budget), prompts, 5)
-        assert got == want, f"budget={budget}"
+    b = _batcher(engine, budget)
+    if budget is None:
+        assert b.prefill_chunk_tokens == b.cengine.kv_width == 96
+    assert await _run_all(b, prompts, 5) == want
 
 
 @pytest.mark.slow
@@ -103,8 +105,8 @@ async def test_chunked_parity_gemma():
 
 
 async def test_chunked_radix_reuse(llama_engine):
-    """A chunk-admitted request seeds from the radix cache like a
-    monolithic one: the second identical prompt re-prefills only the
+    """A request seeds from the radix cache: the second identical
+    prompt re-prefills only the
     uncached tail, token-identically."""
     engine, cfg = llama_engine
     prompt = list(range(2, 2 + 21))
@@ -127,8 +129,8 @@ async def test_chunked_interleaves_decode_with_prefill(llama_engine):
     """The throughput mechanism itself: while a LONG prompt trickles
     in at budget 1, a short already-running request keeps emitting —
     its stream finishes well before the long prompt's first token.
-    (Monolithic admission would stall the short request for the whole
-    prefill.)"""
+    (A whole-prompt prefill would stall the short request for its
+    length.)"""
     engine, cfg = llama_engine
     gen = np.random.default_rng(11)
     short = gen.integers(0, cfg.vocab_size, 4).tolist()
@@ -266,3 +268,8 @@ def test_knob_validation(llama_engine):
     from kubeflow_tpu.serving.server import create_serving_app
     with pytest.raises(ValueError, match="require continuous"):
         create_serving_app({"m": engine}, prefill_chunk_tokens=4)
+    with pytest.raises(TypeError, match="prefill_chunk"):
+        ContinuousBatcher(engine, asyncio.Lock(), prefill_chunk=8)
+    with pytest.raises(TypeError, match="prefill_chunk"):
+        create_serving_app({"m": engine}, continuous=True,
+                           prefill_chunk=8)

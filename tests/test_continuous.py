@@ -38,6 +38,24 @@ def _solo(engine, prompt, max_new):
         jnp.asarray([prompt], jnp.int32), max_new=max_new))[0].tolist()
 
 
+def _greedy(engine, slots):
+    return engine._resolve_sampling(
+        np.zeros(slots, np.float32), np.zeros(slots, np.int64),
+        np.ones(slots, np.float32), jax.random.key(0), batch=slots)[0]
+
+
+def _fill_slot(ce, st, slot, prompt, sp, rng):
+    """Admit `prompt` into `slot` the way the batcher does: adopt the
+    slot's own run of pool blocks, frozen, then feed the whole prompt
+    as one slice. -> (state, first token, rng)."""
+    mb = ce.blocks_per_slot
+    table = 1 + slot * mb + np.arange(mb, dtype=np.int32)
+    st = ce.adopt_slot(st, slot, table, 0, prompt[0])
+    st, first, _, rng = ce.append_rows(
+        st, [slot], [prompt], [len(prompt)], [True], sp, rng)
+    return st, int(np.asarray(first)[0]), rng
+
+
 def test_bucket_pow2():
     assert bucket_pow2(3, 64) == 16
     assert bucket_pow2(16, 64) == 16
@@ -61,14 +79,11 @@ def test_slot_step_matches_generate_mixed_cursors():
     want = [_solo(engine, p, max_new) for p in prompts]
 
     st = ce.init_slots()
+    sp = _greedy(engine, 4)
     got = [[] for _ in prompts]
     for i, p in enumerate(prompts):
-        pstate, first, _, _ = ce.prefill(p, max_new, {}, rng)
-        st = ce.insert(st, i, pstate, first)
-        got[i].append(int(np.asarray(first)[0]))
-    sp = engine._resolve_sampling(
-        np.zeros(4, np.float32), np.zeros(4, np.int64),
-        np.ones(4, np.float32), rng, batch=4)[0]
+        st, first, rng = _fill_slot(ce, st, i, p, sp, rng)
+        got[i].append(first)
     for _ in range(max_new - 1):
         st, toks, _, rng = ce.step(st, sp, rng)
         toks = np.asarray(toks)       # [slots, 1]
@@ -87,13 +102,10 @@ def test_chunked_steps_emit_identical_tokens():
     p = np.random.default_rng(14).integers(
         0, cfg.vocab_size, 7).tolist()
     want = _solo(engine, p, 7)
-    pstate, first, _, _ = ce.prefill(p, 7, {}, rng)
-    st = ce.insert(ce.init_slots(), 0, pstate, first)
-    sp = engine._resolve_sampling(
-        np.zeros(2, np.float32), np.zeros(2, np.int64),
-        np.ones(2, np.float32), rng, batch=2)[0]
+    sp = _greedy(engine, 2)
+    st, first, rng = _fill_slot(ce, ce.init_slots(), 0, p, sp, rng)
     st, toks, _, rng = ce.step(st, sp, rng, steps=3)
-    got = [int(np.asarray(first)[0])] + np.asarray(toks)[0].tolist()
+    got = [first] + np.asarray(toks)[0].tolist()
     st, toks, _, rng = ce.step(st, sp, rng, steps=3)
     got += np.asarray(toks)[0].tolist()
     assert got == want
@@ -106,14 +118,15 @@ async def test_batcher_concurrent_requests_match_solo():
     gen = np.random.default_rng(4)
     prompts = [gen.integers(0, cfg.vocab_size, n).tolist()
                for n in (4, 7, 12, 20)]
-    want = [_solo(engine, p, 5) for p in prompts]
+    want = [_solo(engine, p, 13) for p in prompts]
     got = await asyncio.gather(
-        *(batcher.submit(p, 5, ()) for p in prompts))
+        *(batcher.submit(p, 13, ()) for p in prompts))
     assert list(got) == want
     assert batcher.requests == 4
-    # shared steps: 4 requests x 5 tokens each needed only 4 decode
-    # steps (token #1 comes from prefill), not 4 x 4
-    assert batcher.calls <= 8, batcher.calls
+    # shared steps: 4 requests x 12 decoded tokens each (token #1
+    # comes from prefill) start a slice apart, one dispatch of 4 steps
+    # a slice: 12 + 3 x 4 steps, not 4 x 12
+    assert batcher.calls <= 28, batcher.calls
     assert batcher.occupancy() > 1.0
     await batcher.close()
 
@@ -366,13 +379,11 @@ def test_chunked_prefill_width_validation():
 
 @pytest.mark.slow
 async def test_continuous_long_prompt_admits_in_chunks():
-    """A long prompt admitted with prefill_chunk set gets a chunk-
-    multiple bucket and decodes exactly its solo continuation."""
+    """A prompt of several slices beside one of a single slice: each
+    decodes exactly its solo continuation."""
     engine, cfg = _engine(max_len=128)
     batcher = ContinuousBatcher(engine, asyncio.Lock(), max_slots=2,
-                                prefill_chunk=8)
-    assert batcher.cengine.bucket_for(20, 16) == 24  # ceil multiple
-    assert batcher.cengine.bucket_for(5, 16) == 16   # short: pow2
+                                prefill_chunk_tokens=8)
     gen = np.random.default_rng(17)
     long_p = gen.integers(0, cfg.vocab_size, 20).tolist()
     short_p = gen.integers(0, cfg.vocab_size, 5).tolist()
@@ -388,8 +399,7 @@ async def test_continuous_long_prompt_admits_in_chunks():
 @pytest.mark.slow
 async def test_shared_prefix_decodes_like_full_prompt():
     """A request with a registered prefix must decode exactly what the
-    full concatenated prompt decodes — but the prefix KV computes once
-    per server, not per request. Mixed admissions (prefixed and plain)
+    full concatenated prompt decodes. Mixed admissions (prefixed and plain)
     share the slot batch."""
     engine, cfg = _engine(max_len=96)
     gen = np.random.default_rng(20)
@@ -409,8 +419,6 @@ async def test_shared_prefix_decodes_like_full_prompt():
     assert got1 == want1
     assert got2 == want2
     assert got_plain == want_plain
-    # prefix KV computed exactly once and cached
-    assert set(batcher._prefix_states) == {"sys"}
     # slot reuse after a prefixed request leaks nothing
     got3 = await batcher.submit(plain, 5, (("prefix", "sys"),))
     assert got3 == _solo(engine, sys_prompt + plain, 5)
@@ -488,16 +496,12 @@ def test_continuous_engine_under_tensor_parallel_mesh():
     ce = ContinuousEngine(engine, max_slots=2)
     with jax.set_mesh(mesh):
         st = ce.init_slots()
+        sp = _greedy(engine, 2)
+        rng = jax.random.key(3)
         got = [[] for _ in prompts]
         for i, p in enumerate(prompts):
-            pstate, first, _, _ = ce.prefill(p, max_new, {},
-                                             jax.random.key(1))
-            st = ce.insert(st, i, pstate, first)
-            got[i].append(int(np.asarray(first)[0]))
-        sp = engine._resolve_sampling(
-            np.zeros(2, np.float32), np.zeros(2, np.int64),
-            np.ones(2, np.float32), jax.random.key(2), batch=2)[0]
-        rng = jax.random.key(3)
+            st, first, rng = _fill_slot(ce, st, i, p, sp, rng)
+            got[i].append(first)
         st, toks, _, rng = ce.step(st, sp, rng, steps=max_new - 1)
         toks = np.asarray(toks)
     for i in range(len(prompts)):
@@ -619,7 +623,7 @@ async def test_backpressure_sheds_load():
     # stuff the pending deque directly (no worker running)
     for _ in range(3):
         batcher._pending.append((p, 4, {}, asyncio.get_event_loop()
-                                 .create_future(), None, 0, ""))
+                                 .create_future(), None, 0, None))
     with pytest.raises(Overloaded, match="max_pending=3"):
         batcher._enqueue(p, 4, (), queue=None)
     batcher._pending.clear()
@@ -776,7 +780,7 @@ async def test_logprobs_shape_uniform_across_paths_with_eos():
 
 @pytest.mark.slow
 async def test_insert_failure_before_dispatch_spares_active_slots():
-    """ADVICE r04: a host-side insert raise (donated state NOT consumed)
+    """ADVICE r04: a host-side adopt raise (donated state NOT consumed)
     must fail only the new admission — requests already decoding keep
     their KV and finish with correct tokens."""
     engine, cfg = _engine()
@@ -792,15 +796,15 @@ async def test_insert_failure_before_dispatch_spares_active_slots():
     while not batcher._active:
         await asyncio.sleep(0.01)
 
-    real_insert = batcher.cengine.insert_many
+    real_adopt = batcher.cengine.adopt_slot
 
     def boom(*a, **k):
         raise ValueError("host-side admission failure")
 
-    batcher.cengine.insert_many = boom
+    batcher.cengine.adopt_slot = boom
     with pytest.raises(ValueError, match="host-side admission"):
         await batcher.submit(p2, 4, ())
-    batcher.cengine.insert_many = real_insert
+    batcher.cengine.adopt_slot = real_adopt
 
     assert list(await t1) == want1  # survivor unharmed
     # pool healthy afterwards: a fresh request still serves
@@ -811,7 +815,7 @@ async def test_insert_failure_before_dispatch_spares_active_slots():
 @pytest.mark.slow
 async def test_insert_failure_after_dispatch_fails_actives_cleanly():
     """ADVICE r04: when the donated slot state WAS consumed by a failed
-    insert, active requests must get a deterministic RuntimeError now —
+    adopt, active requests must get a deterministic RuntimeError now —
     not a confusing deleted-buffer crash on the next decode step."""
     engine, cfg = _engine()
     batcher = ContinuousBatcher(engine, asyncio.Lock(), max_slots=2,
@@ -827,13 +831,13 @@ async def test_insert_failure_after_dispatch_fails_actives_cleanly():
     def consume_and_boom(st, *a, **k):
         for leaf in jax.tree.leaves(st):
             leaf.delete()  # what a post-dispatch donation does
-        raise ValueError("mid-insert failure")
+        raise ValueError("mid-adopt failure")
 
-    real_insert = batcher.cengine.insert_many
-    batcher.cengine.insert_many = consume_and_boom
-    with pytest.raises(ValueError, match="mid-insert"):
+    real_adopt = batcher.cengine.adopt_slot
+    batcher.cengine.adopt_slot = consume_and_boom
+    with pytest.raises(ValueError, match="mid-adopt"):
         await batcher.submit(p1, 4, ())
-    batcher.cengine.insert_many = real_insert
+    batcher.cengine.adopt_slot = real_adopt
 
     with pytest.raises(RuntimeError, match="slot state lost"):
         await t1
@@ -1004,34 +1008,76 @@ async def test_async_device_failure_in_drain_path_fails_cleanly():
     await batcher.close()
 
 
-def test_insert_many_equals_sequential_inserts():
-    """The fused group scatter must land EXACTLY the same state as
-    per-request inserts, including the pow2 padding's idempotent
-    repeat of the last triple."""
+def _jit_cache_sizes(ce):
+    """Signatures each admission and decode jit has met (the batcher
+    wraps three of them in its compile watch: `__wrapped__`)."""
+    return {name: (fn if hasattr(fn, "_cache_size")
+                   else fn.__wrapped__)._cache_size()
+            for name, fn in (("append_rows", ce._append_jit),
+                             ("adopt_slot", ce._adopt_jit),
+                             ("copy_cells", ce._copy_cells_jit),
+                             ("step", ce._step_jit),
+                             ("reset_slots", ce._reset_jit))}
+
+
+async def test_warmup_covers_every_program_traffic_runs():
+    """Ready means compiled: after `warmup()` a lone request, bursts
+    of 2 and 4 and a prompt sharing a block and a half with an earlier
+    one (the copy-on-write seed) meet no signature the jits and the
+    compile watch have not seen."""
     engine, cfg = _engine()
-    ce = ContinuousEngine(engine, max_slots=4)
-    gen = np.random.default_rng(40)
-    key = jax.random.key(2)
-    lists = [gen.integers(0, cfg.vocab_size, n).tolist()
-             for n in (4, 7, 3)]
-    greedy = {"temperature": 0.0, "top_k": 0, "top_p": 1.0}
-    pstate, first, _, _ = ce.prefill_batch(
-        lists + [[0]], 16, [greedy] * 4, key)
+    # weights put on a named device, as a restored checkpoint's are:
+    # what a program returns is then committed too, so a jit meets the
+    # state fresh from the host once and its own outputs ever after
+    engine.params = jax.device_put(engine.params, jax.devices()[0])
+    batcher = ContinuousBatcher(engine, asyncio.Lock(), max_slots=4,
+                                chunk=2, kv_block_size=8,
+                                prefill_chunk_tokens=16)
+    # adopt, copy, append; step x 2; reset at 1, 2, 4
+    assert batcher.warmup() == 3 + 2 + 3
+    warmed = _jit_cache_sizes(batcher.cengine)
+    assert all(warmed.values()), warmed
+    watched = batcher.compile_watch.counts()
+    gen = np.random.default_rng(41)
 
-    st_seq = ce.init_slots()
-    for slot, row in zip((2, 0, 3), range(3)):
-        st_seq = ce.insert(st_seq, slot, pstate, first, row)
+    def prompt(n):
+        return gen.integers(0, cfg.vocab_size, n).tolist()
 
-    st_many = ce.init_slots()
-    # padded to 4 by repeating the last (slot, row) — idempotent
-    st_many = ce.insert_many(st_many, [2, 0, 3, 3], pstate,
-                             [0, 1, 2, 2], first)
+    await batcher.submit(prompt(5), 4, ())  # decodes 2 steps, then 1
+    for burst in (2, 4):
+        await asyncio.gather(*(batcher.submit(prompt(20), 5, ())
+                               for _ in range(burst)))
+    head = prompt(24)
+    await batcher.submit(head, 3, ())
+    reused = batcher.tokens_reused
+    await batcher.submit(head[:12] + prompt(8), 3, ())
+    assert batcher.tokens_reused - reused == 12  # a block and a half
+    assert _jit_cache_sizes(batcher.cengine) == warmed
+    assert batcher.compile_watch.counts() == watched
+    await batcher.close()
 
-    for a, b in zip(jax.tree.leaves(st_seq), jax.tree.leaves(st_many)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    with pytest.raises(ValueError, match="insert_many"):
-        ce.insert_many(ce.init_slots(), [0, 1], pstate, [0], first)
+async def test_registered_prefix_rides_the_radix_cache():
+    """A registered prefix is a name for tokens: the first request
+    that names it computes them through the slices like any prompt,
+    the second finds the prefix's whole blocks in the radix cache, and
+    both decode what the concatenated prompt decodes."""
+    engine, cfg = _engine(max_len=96)
+    gen = np.random.default_rng(42)
+    sys_prompt = gen.integers(0, cfg.vocab_size, 23).tolist()
+    batcher = ContinuousBatcher(engine, asyncio.Lock(), max_slots=2,
+                                kv_block_size=8,
+                                prefixes={"sys": sys_prompt})
+    p1 = gen.integers(0, cfg.vocab_size, 6).tolist()
+    p2 = gen.integers(0, cfg.vocab_size, 9).tolist()
+    got1 = await batcher.submit(p1, 5, (("prefix", "sys"),))
+    assert (batcher.tokens_reused, batcher.tokens_prefilled) == (0, 29)
+    got2 = await batcher.submit(p2, 5, (("prefix", "sys"),))
+    assert batcher.tokens_reused >= 16  # 23 tokens: two blocks of 8
+    assert batcher.prefix_hits == 1
+    assert got1 == _solo(engine, sys_prompt + p1, 5)
+    assert got2 == _solo(engine, sys_prompt + p2, 5)
+    await batcher.close()
 
 
 @pytest.mark.slow
@@ -1077,7 +1123,7 @@ async def test_pipelined_depth2_with_chunked_prefill_and_prefixes():
     sys_prompt = gen.integers(0, cfg.vocab_size, 17).tolist()
     batcher = ContinuousBatcher(engine, asyncio.Lock(), max_slots=3,
                                 chunk=2, pipeline_depth=2,
-                                prefill_chunk=8,
+                                prefill_chunk_tokens=8,
                                 prefixes={"sys": sys_prompt})
     long_p = gen.integers(0, cfg.vocab_size, 21).tolist()
     pref_p = gen.integers(0, cfg.vocab_size, 6).tolist()

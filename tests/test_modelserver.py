@@ -47,16 +47,30 @@ def test_random_init_smoke_server(cluster):
 def test_pvc_checkpoint_and_quant(cluster):
     cluster.store.create(mk_ms(
         "srv2", model="llama3-1b", checkpoint="pvc://train-out/run7",
-        quant="int8", prefill_chunk=512))
+        quant="int8"))
     assert cluster.wait_idle()
     dep = cluster.store.get("Deployment", "user1", "srv2")
     c = dep.spec.template.spec.containers[0]
     assert "--checkpoint" in c.args and "/ckpt" in c.args
     assert "--quant" in c.args and "int8" in c.args
-    assert "--prefill-chunk" in c.args and "512" in c.args
     vol = dep.spec.template.spec.volumes[0]
     assert vol.pvc_name == "train-out"
     assert c.volume_mounts[0].sub_path == "run7"
+
+
+def test_prefill_chunk_renders_the_slice_budget(cluster):
+    """`spec.prefill_chunk` is the server's one prefill knob, the
+    budget of a prefill slice; 0 leaves the server its default."""
+    cluster.store.create(mk_ms("sized", prefill_chunk=512))
+    cluster.store.create(mk_ms("plain"))
+    assert cluster.wait_idle()
+    sized, plain = (
+        cluster.store.get("Deployment", "user1", name)
+        .spec.template.spec.containers[0].args
+        for name in ("sized", "plain"))
+    assert sized[sized.index("--prefill-chunk-tokens") + 1] == "512"
+    assert "--prefill-chunk" not in sized
+    assert not [a for a in plain if a.startswith("--prefill-chunk")]
 
 
 def test_tokenizer_flag_rendering(cluster):

@@ -45,7 +45,7 @@ class FakeClock:
 
 
 def test_exclusive_nesting_and_host_gap_residual():
-    """admit contains prefill; the parent records only its EXCLUSIVE
+    """admit contains a prefill slice; the parent records only its EXCLUSIVE
     time, and end_iteration books the unclaimed residual as host_gap —
     so the totals sum exactly to the iteration wall."""
     clk = FakeClock()
@@ -53,7 +53,7 @@ def test_exclusive_nesting_and_host_gap_residual():
     p.begin_iteration()
     with p.phase("admit"):
         clk.t = 1.0
-        with p.phase("prefill", tokens=16):
+        with p.phase("prefill_chunk", tokens=16):
             clk.t = 3.0
         clk.t = 3.5
     with p.phase("decode", tokens=8):
@@ -63,13 +63,13 @@ def test_exclusive_nesting_and_host_gap_residual():
 
     t = p.totals()
     assert t["admit"] == pytest.approx(1.5)    # 3.5 wall - 2.0 child
-    assert t["prefill"] == pytest.approx(2.0)
+    assert t["prefill_chunk"] == pytest.approx(2.0)
     assert t["decode"] == pytest.approx(2.0)
     assert t["host_gap"] == pytest.approx(0.5)  # 6.0 - 5.5 claimed
     assert sum(t.values()) == pytest.approx(6.0)
     assert p.wall_s() == pytest.approx(6.0)
     toks = p.phase_tokens()
-    assert toks["prefill"] == 16 and toks["decode"] == 8
+    assert toks["prefill_chunk"] == 16 and toks["decode"] == 8
 
 
 class FakeAnnotations:
@@ -100,7 +100,7 @@ def test_phases_are_also_annotated_with_their_name_and_tokens():
     p = PhaseProfiler(clock=clk, wall_clock=clk, annotate=ann)
     p.begin_iteration(active=3, pending=1)
     with p.phase("admit"):
-        with p.phase("prefill", tokens=16):
+        with p.phase("prefill_chunk", tokens=16):
             clk.t = 2.0
     with p.phase("decode", kv_blocks_live=9):   # a span's own stats
         clk.t = 3.0
@@ -108,8 +108,8 @@ def test_phases_are_also_annotated_with_their_name_and_tokens():
     assert ann.log == [
         ("open", "sched.iteration", {"active": 3, "pending": 1}),
         ("open", "sched.admit", {"tokens": 0}),
-        ("open", "sched.prefill", {"tokens": 16}),
-        ("close", "sched.prefill"), ("close", "sched.admit"),
+        ("open", "sched.prefill_chunk", {"tokens": 16}),
+        ("close", "sched.prefill_chunk"), ("close", "sched.admit"),
         ("open", "sched.decode", {"tokens": 0, "kv_blocks_live": 9}),
         ("close", "sched.decode"),
         ("close", "sched.iteration")]
@@ -144,7 +144,7 @@ def test_a_profiler_without_annotate_accounts_exactly_as_one_with():
         p.begin_iteration(active=1)
         with p.phase("admit"):
             clk.t += 1.0
-            with p.phase("prefill", tokens=16):
+            with p.phase("prefill_chunk", tokens=16):
                 clk.t += 2.0
         with p.phase("decode", tokens=8):
             clk.t += 2.0
@@ -526,7 +526,9 @@ async def test_debug_profile_endpoint_and_zero_seeded_families():
         assert set(SERVING_PHASES) <= set(m["phases"])
         assert m["phases"]["decode"]["count"] >= 1
         assert m["phases"]["decode"]["tokens"] > 0
-        assert set(WATCHED_SERVING_FNS) == set(m["recompiles"])
+        # a batcher without a draft model watches no spec program
+        assert set(m["recompiles"]) == (
+            set(WATCHED_SERVING_FNS) - {"spec_draft", "spec_verify"})
         assert 0 < m["goodput"]["goodput_ratio"] <= 1
         # /debug/profile totals reconcile: phases sum into the wall
         busy = sum(v["total_s"] for p, v in m["phases"].items()

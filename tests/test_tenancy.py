@@ -208,7 +208,7 @@ class _Fut:
 def _item(tenant, cost=8.0, priority="standard", weight=1.0):
     meta = ReqMeta(tenant=tenant, priority=priority, weight=weight,
                    cost=cost)
-    return (None, None, None, _Fut(), None, None, None, meta)
+    return (None, None, None, _Fut(), None, None, meta)
 
 
 def _mkq(tenants: dict, ledger=None):
@@ -221,7 +221,7 @@ def test_fair_share_alternates_equal_weights():
     for _ in range(10):
         q.append(_item("a"))
         q.append(_item("b"))
-    order = [q.popleft()[7].tenant for _ in range(20)]
+    order = [q.popleft()[6].tenant for _ in range(20)]
     assert order == ["a", "b"] * 10
     with pytest.raises(IndexError):
         q.popleft()
@@ -237,7 +237,7 @@ def test_fair_share_token_split_matches_weights():
     tokens = {"a": 0, "b": 0}
     for _ in range(40):                  # serve half the backlog
         it = q.popleft()
-        tokens[it[7].tenant] += it[7].cost
+        tokens[it[6].tenant] += it[6].cost
     total = sum(tokens.values())
     assert abs(tokens["a"] / total - 0.5) <= 0.10
 
@@ -248,7 +248,7 @@ def test_fair_share_token_split_matches_weights():
     tokens = {"a": 0, "b": 0}
     for _ in range(60):
         it = q2.popleft()
-        tokens[it[7].tenant] += it[7].cost
+        tokens[it[6].tenant] += it[6].cost
     assert tokens["a"] / sum(tokens.values()) == pytest.approx(
         2 / 3, abs=0.10)
 
@@ -265,7 +265,7 @@ def test_idle_tenant_banks_no_credit():
     for _ in range(4):
         q.append(_item("a"))
         q.append(_item("b"))
-    order = [q.popleft()[7].tenant for _ in range(8)]
+    order = [q.popleft()[6].tenant for _ in range(8)]
     assert order.count("b") == 4 and order[:2] != ["b", "b"]
 
 
@@ -281,7 +281,7 @@ def test_priority_classes_and_pacing_fallthrough():
     q.append(_item("live", priority="interactive"))
     q.append(_item("std"))
     # strict class order: interactive > standard > batch
-    assert [q.popleft()[7].tenant for _ in range(3)] \
+    assert [q.popleft()[6].tenant for _ in range(3)] \
         == ["live", "std", "bulk"]
     assert q.has_waiting("interactive") is False
 
@@ -289,13 +289,13 @@ def test_priority_classes_and_pacing_fallthrough():
     led.charge_tokens("live", 15)        # bucket 10/s -> 0.5s of debt
     q.append(_item("live", priority="interactive"))
     q.append(_item("bulk", priority="batch"))
-    assert q.popleft()[7].tenant == "bulk"
+    assert q.popleft()[6].tenant == "bulk"
     # nothing runnable at all -> None (not IndexError), with a delay
     assert q.popleft() is None
     assert len(q) == 1
     assert q.pacing_delay() == pytest.approx(0.5)
     clk.t = 0.5
-    assert q.popleft()[7].tenant == "live"
+    assert q.popleft()[6].tenant == "live"
 
 
 def test_appendleft_refunds_virtual_time():
@@ -303,11 +303,11 @@ def test_appendleft_refunds_virtual_time():
     q.append(_item("a"))
     q.append(_item("b"))
     it = q.popleft()                     # a charged 8 vt
-    assert it[7].tenant == "a" and it[7].charged > 0
+    assert it[6].tenant == "a" and it[6].charged > 0
     q.appendleft(it)                     # deferral: refund the charge
-    assert it[7].charged == 0.0
+    assert it[6].charged == 0.0
     # with the refund, a is still the lowest-vt tenant and pops first
-    assert q.popleft()[7].tenant == "a"
+    assert q.popleft()[6].tenant == "a"
 
 
 # -- label-cardinality guard ----------------------------------------------
