@@ -21,8 +21,10 @@ first_layer + num_layers - 1` of that pattern and the experts
 `experts_held = (first, count)` of each expert layer: the whole model,
 or the share of it that one chip of a deployment holds. The layers are
 unrolled (a list of per-layer dicts, each kind with its own
-parameters), each under `jax.checkpoint`: five or twenty-seven blocks
-of two kinds do not stack into one `lax.scan`.
+parameters): five or twenty-seven blocks of two kinds do not stack
+into one `lax.scan`. Each is under `jax.checkpoint` and rematerialised
+whole in the backward pass, but for a KDA layer's scan, whose output
+and segment states are kept (`_layer`).
 
 Parameters and activations are `param_dtype` / `dtype` (bf16 by
 default); KDA's state, its gates and their cumulative sums, the norms,
@@ -374,6 +376,21 @@ def _block(cfg: KimiLinearConfig, number: int, x, p):
     return wsc(x + y, ("batch", "seq", "act_embed")), load
 
 
+# One object for all layers: what JAX derives from a jitted call under a
+# checkpoint (`ops.kda`'s scans) it caches by the policy's identity, and
+# with a policy of its own each layer would derive and lower them again.
+_KEEP_THE_SCAN = jax.checkpoint_policies.save_only_these_names(*kda_ops.SAVED)
+
+
+def _layer(cfg: KimiLinearConfig, number: int):
+    """`_block` as a training step runs it: all its backward finds
+    kept of its forward is a KDA layer's scan (its output and segment
+    states, which that scan's own backward starts from); every other
+    value, and every other layer whole, is recomputed."""
+    return jax.checkpoint(lambda x, p: _block(cfg, number, x, p),
+                          policy=_KEEP_THE_SCAN)
+
+
 def hidden_and_load(params: Params, cfg: KimiLinearConfig, tokens):
     """tokens [b, s] -> (the final normed hidden [b, s, D] in
     `cfg.dtype`, the held experts' loads [expert layers, held] int32)."""
@@ -382,8 +399,7 @@ def hidden_and_load(params: Params, cfg: KimiLinearConfig, tokens):
         x = wsc(x, ("batch", "seq", "act_embed"))
     loads = []
     for number, p in zip(cfg.layer_numbers, params["layers"], strict=True):
-        x, load = jax.checkpoint(
-            lambda x, p, number=number: _block(cfg, number, x, p))(x, p)
+        x, load = _layer(cfg, number)(x, p)
         if load is not None:
             loads.append(load)
     held = cfg.experts_held[1]

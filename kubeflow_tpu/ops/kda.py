@@ -32,23 +32,42 @@ the diagonal block directly from the masked differences.
 
 **Memory.** The diagonal blocks' e^(G_t - G_i) is a `[16, 16, dk]`
 tensor a block of 16 tokens a head. So the sequence runs in segments
-(`segment` tokens, a `lax.scan` whose body is rematerialised): only one
-segment's intermediates are live, forward or backward.
+(`segment` tokens, a `lax.scan`): only one segment's intermediates are
+live, forward or backward.
+
+**Backward.** The scan over segments has its own (`jax.custom_vjp`):
+the forward keeps its inputs and the state after every segment, the
+backward walks the segments in reverse, runs each again from the state
+it started from and differentiates it by autodiff (the same `_segment`:
+the same mathematics in the same precision), carrying the state's
+cotangent back. So differentiating runs the forward twice. A caller
+whose `jax.checkpoint` keeps the names `SAVED` (the scan's output and
+those states) rematerialises without running it a third time
+(`models/kimi_linear.py`). Reverse mode only: `jax.jvp`, `jacfwd` and
+a second derivative through `kda` raise, as through any
+`jax.custom_vjp`. A caller that drops the final state still gives the
+backward a cotangent for every segment's state (zeros, float32, the
+size of the states: 33.5 MB at 2 x 8192 tokens in 8 segments).
 
 **Precision.** The state, the cumulative sums, the decay factors and
 the triangular solve are float32; the large products take their
 operands in the activations' dtype (`q.dtype`) and accumulate in
-float32. Differentiable by autodiff. Everything runs under the scope
-`kda`. XLA ops only: a Pallas kernel is a later change.
+float32. Everything runs under the scope `kda`. XLA ops only: a Pallas
+kernel is a later change.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 CHUNK = 64                 # tokens a chunk of the scan
 SUB = 16                   # rows of a diagonal block
+# `checkpoint_name`s of what the scan's backward needs of its forward
+# beside the inputs, and of what a caller reads of it: its output and
+# the state after every segment
+SAVED = ("kda_o", "kda_states")
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -147,6 +166,15 @@ def _decay_products(q, k, g_cum, g_excl):
     return off_diagonal(q), diagonal(q), off_diagonal(k), diagonal(k)
 
 
+# `_segment` and the two scans over it are jitted so that one trace
+# serves them all: the forward and the backward of every layer of a
+# model (they call at one shape) share `_segment`'s, which is most of
+# what a step's first call spends in Python (`_solve_blocks` alone is a
+# loop of 16 rows and 4 blocks), and the backward differentiates its
+# jaxpr whole, not operation by operation as a second trace would
+# (5 s of a training cell's set-up: PERF.md section 6, PR 32;
+# `tests/test_kimi_linear.py` counts the loops a step lowers to).
+@jax.jit
 def _segment(state, xs):
     """One segment of `n` chunks. state: [b, h, dk, dv] float32; xs:
     q, k [b, h, n, C, dk], v [b, h, n, C, dv], g [b, h, n, C, dk]
@@ -198,6 +226,58 @@ def _segment(state, xs):
     return state, jnp.moveaxis(o, 0, 2).astype(cd)   # [b, h, n, C, dv]
 
 
+@jax.jit
+def _forward(xs):
+    """Every segment in turn from a zero state -> (o [n_seg, b, h, n,
+    C, dv], the state after each segment [n_seg, b, h, dk, dv]
+    float32)."""
+    q, _, v = xs[:3]
+    state = jnp.zeros((*q.shape[1:3], q.shape[-1], v.shape[-1]), jnp.float32)
+
+    def body(state, x):
+        state, o = _segment(state, x)
+        return state, (o, state)
+
+    return jax.lax.scan(body, state, xs)[1]
+
+
+@jax.jit
+@jax.named_scope("kda")
+def _backward(xs, states, d_o, d_states):
+    """-> the cotangents of `xs`, given `_forward`'s results' (see the
+    module docstring). Opens the scope itself: nothing says a backward
+    rule is traced under the scope its forward was called in."""
+    started = jnp.concatenate([jnp.zeros_like(states[:1]), states[:-1]])
+
+    def body(d_state, x):
+        seg, s_in, d_o_seg, d_s_out = x
+        _, pull = jax.vjp(_segment, s_in, seg)
+        return pull((d_state + d_s_out, d_o_seg))
+
+    return jax.lax.scan(body, jnp.zeros_like(states[0]),
+                        (xs, started, d_o, d_states), reverse=True)[1]
+
+
+def _named_forward(xs):
+    """`_forward`, its outputs named: outside the jitted call, where a
+    `jax.checkpoint` around the caller sees the names."""
+    o, states = _forward(xs)
+    return checkpoint_name(o, SAVED[0]), checkpoint_name(states, SAVED[1])
+
+
+_segments = jax.custom_vjp(_named_forward)
+
+
+def _segments_fwd(xs):
+    o, states = _named_forward(xs)
+    return (o, states), (xs, states)
+
+
+_segments.defvjp(
+    _segments_fwd,
+    lambda residuals, cotangents: (_backward(*residuals, *cotangents),))
+
+
 @jax.named_scope("kda")
 def kda(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         log_a: jnp.ndarray, beta: jnp.ndarray, *, segment: int = 1024):
@@ -223,8 +303,6 @@ def kda(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     xs = (split(q), split(k), split(v), split(log_a.astype(jnp.float32)),
           split(beta.astype(jnp.float32)[..., None])[..., 0])
-    state = jnp.zeros((b, h, dk, dv), jnp.float32)
-    state, o = jax.lax.scan(
-        jax.checkpoint(_segment, prevent_cse=False), state, xs)
+    o, states = _segments(xs)
     o = jnp.transpose(o, (1, 0, 3, 4, 2, 5)).reshape(b, t, h, dv)
-    return o, state
+    return o, states[-1]

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +91,17 @@ def test_chunked_kda_matches_the_recurrence(t, decay):
     np.testing.assert_allclose(s, want_s, atol=5e-6)
 
 
+GRADIENTS = ("q", "k", "v", "log_a", "beta")
+
+
+def assert_gradients_match(got, want):
+    """To 1e-5 of the largest entry, each of the five."""
+    for name, g, g_want in zip(GRADIENTS, got, want, strict=True):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(
+            g, g_want, atol=1e-5 * float(jnp.abs(g_want).max()), err_msg=name)
+
+
 @pytest.mark.parametrize("decay", ["mild", "e-20"])
 @pytest.mark.parametrize("t", [64, 192])
 def test_chunked_kda_gradients_match_the_recurrence(t, decay):
@@ -99,10 +111,57 @@ def test_chunked_kda_gradients_match_the_recurrence(t, decay):
         kda_ops.kda(*a, segment=128)[0] * w), argnums=range(5))(*args)
     want = jax.grad(lambda *a: jnp.sum(recurrence(*a)[0] * w),
                     argnums=range(5))(*args)
-    for name, g, g_want in zip(("q", "k", "v", "log_a", "beta"), got, want):
-        assert bool(jnp.isfinite(g).all()), name
-        np.testing.assert_allclose(
-            g, g_want, atol=1e-5 * float(jnp.abs(g_want).max()), err_msg=name)
+    assert_gradients_match(got, want)
+
+
+def autodiff_segments(xs):
+    """`ops/kda.py`'s scan over segments as autodiff sees it, with no
+    backward of its own: the oracle for the one it has."""
+    q, _, v = xs[:3]
+    state = jnp.zeros((*q.shape[1:3], q.shape[-1], v.shape[-1]), jnp.float32)
+
+    def body(state, x):
+        state, o = kda_ops._segment(state, x)
+        return state, (o, state)
+
+    return jax.lax.scan(body, state, xs)[1]
+
+
+@pytest.mark.parametrize("decay", ["mild", "e-20"])
+@pytest.mark.parametrize("t", [64, 128, 192, 512])
+def test_the_scans_own_backward_matches_autodiff(t, decay, monkeypatch):
+    # segment 128: one segment of one and of two chunks, three of one,
+    # four of two
+    args = kda_inputs(t, decay, seed=2)
+    w_o = jax.random.normal(jax.random.key(7), args[2].shape)
+    w_s = jax.random.normal(jax.random.key(8), (2, 3, 32, 16))
+
+    @highest
+    def grads():
+        def loss(*a):
+            o, s = kda_ops.kda(*a, segment=128)
+            return jnp.sum(o * w_o) + jnp.sum(s * w_s)
+        return jax.grad(loss, argnums=range(5))(*args)
+
+    got = grads()
+    monkeypatch.setattr(kda_ops, "_segments", autodiff_segments)
+    want = grads()
+    assert_gradients_match(got, want)
+
+
+def test_the_final_states_cotangent_reaches_every_segment():
+    """A loss on the final state alone: its cotangent enters at the last
+    segment and is carried back through all four."""
+    args = kda_inputs(512, "mild", seed=3)
+    w = jax.random.normal(jax.random.key(5), (2, 3, 32, 16))
+    got = jax.grad(lambda *a: jnp.sum(
+        kda_ops.kda(*a, segment=128)[1] * w), argnums=range(5))(*args)
+    want = jax.grad(lambda *a: jnp.sum(recurrence(*a)[1] * w),
+                    argnums=range(5))(*args)
+    assert_gradients_match(got, want)
+    # the state holds what was written, not what read it
+    for name, g in zip(GRADIENTS, got):
+        assert (float(jnp.abs(g[:, :128]).max()) > 0) == (name != "q"), name
 
 
 def test_kda_refuses_a_length_that_is_no_multiple_of_the_chunk():
@@ -297,6 +356,96 @@ def test_model_loss_and_every_gradient_match_the_reference(model):
         np.testing.assert_allclose(
             a, b, atol=2e-4 * float(jnp.abs(b).max()) + 1e-7,
             err_msg=jax.tree_util.keystr(path))
+
+
+def sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (list, tuple)) else [value]:
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def scans(jaxpr, recomputing=False):
+    """-> (length, reverse, inside a rematerialised computation) of
+    every `scan` in `jaxpr`, at any depth."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "scan":
+            yield eqn.params["length"], eqn.params["reverse"], recomputing
+        for inner in sub_jaxprs(eqn):
+            yield from scans(inner, recomputing or name.startswith("remat"))
+
+
+@jax.grad
+def three_segment_step(params):
+    """A loss on the tiny five-layer model at three segments of two
+    chunks: the loop over segments is the only one of length 3."""
+    tokens = jnp.zeros((1, 3 * TINY.kda_segment), jnp.int32)
+    return jnp.mean(jnp.square(kl.hidden(params, TINY, tokens)))
+
+
+def test_the_scan_runs_twice_a_step_and_never_in_the_recomputed_layer():
+    """A step holds the loop over segments once a KDA layer going forward
+    and once (reversed: the scan's own backward, which runs each segment
+    again) inside that layer's rematerialised backward, which recomputes
+    everything of the layer but the scan."""
+    step = jax.make_jaxpr(three_segment_step)(
+        kl.init(jax.random.key(1), TINY))
+    over_segments = sorted(
+        (reverse, recomputing) for length, reverse, recomputing
+        in scans(step.jaxpr) if length == 3)
+    n_kda = sum(TINY.is_kda(n) for n in TINY.layer_numbers)
+    assert n_kda == 4
+    assert over_segments == [(False, False)] * n_kda + [(True, True)] * n_kda
+
+
+def test_four_layers_lower_one_forward_and_one_backward_scan():
+    """What holds a training cell's set-up (PERF.md section 6, PR 32):
+    the four KDA layers call the scan at one shape, and the step's
+    module holds its forward and its backward once, each calling
+    `_segment` as a function of its own, not once a layer. That rests on
+    what JAX caches by identity: the three `jax.jit` objects of
+    `ops/kda.py` and the one checkpoint policy of the model. A policy
+    made per layer lowers `_forward` four times (11 loops); a `_segment`
+    that is not jitted is differentiated operation by operation; the
+    scan as the parent had it lowered to 25 loops."""
+    text = jax.jit(three_segment_step).lower(
+        kl.init(jax.random.key(1), TINY)).as_text()
+
+    def functions(name):
+        return len(re.findall(
+            rf"func\.func private @{name}(_\d+)?\(", text))
+
+    # the loop over segments and, inside `_segment`, the one over its
+    # chunks; the same two in reverse and the chunks' again before them
+    assert text.count("stablehlo.while") == 5
+    assert (functions("_forward"), functions("_backward")) == (1, 1)
+    assert functions("_segment") >= 1
+    assert len(re.findall(r"call @_(forward|backward)\(", text)) == 8
+
+
+@pytest.mark.parametrize("number, kept", [
+    # o [segments, b, h, chunks, CHUNK, dv] and the states [segments, b,
+    # h, dk, dv] of `ops.kda.SAVED`
+    (2, {"f32[3,1,2,2,64,32]", "f32[3,1,2,32,32]"}),
+    (4, set()),
+], ids=["kda", "mla"])
+def test_a_layers_checkpoint_keeps_the_scan_and_nothing_else(
+        number, kept, capsys):
+    assert TINY.is_kda(number) == bool(kept)
+    params = kl.init(jax.random.key(1), TINY)
+    x = jnp.zeros((1, 3 * TINY.kda_segment, TINY.hidden_size), jnp.float32)
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda x, p: kl._layer(TINY, number)(x, p)[0], x,
+        params["layers"][number - 1])
+    lines = capsys.readouterr().out.splitlines()
+    assert any("from the argument x" in line for line in lines)
+    beyond = [line for line in lines if "from the argument" not in line]
+    assert {line.split()[0] for line in beyond} == kept
+    assert len(beyond) == len(kept)
+    if kept:
+        assert any(f"named '{kda_ops.SAVED[1]}'" in line for line in beyond)
 
 
 def test_trainer_steps_lower_the_loss_and_count_the_loads(model):

@@ -5,6 +5,9 @@ time. Nothing runs, so nothing is said about results or times.
 The topology is described inside a fixture and only there (libtpu loads
 in the worker that runs this file, once); where it cannot be described
 the tests skip. Keep such tests in this one file.
+
+The last case compiles a whole training step (over a minute, where a
+kernel takes two seconds): it is marked `slow`.
 """
 
 from __future__ import annotations
@@ -20,8 +23,13 @@ from jax.sharding import SingleDeviceSharding
 from kubeflow_tpu.ops.pallas.flash_attention import flash_attention
 
 
+# what a v5e's runtime leaves a program of its 16 GB: `bytes_limit` of
+# `jax.devices()[0].memory_stats()` on the chip (PERF.md section 4)
+V5E_BYTES_LIMIT = 16_909_336_064
+
+
 @pytest.fixture(scope="module")
-def one_chip():
+def chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -29,7 +37,12 @@ def one_chip():
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - whatever keeps libtpu from it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices[0]
+
+
+@pytest.fixture(scope="module")
+def one_chip(chip):
+    return SingleDeviceSharding(chip)
 
 
 def compile_flash(sharding, *, b, s, n_q, n_kv, hd, hd_v):
@@ -60,3 +73,43 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, shape):
     for kernel in ("flash_attention_fwd", "flash_attention_dq",
                    "flash_attention_dkv"):
         assert kernel in text
+
+
+@pytest.mark.slow
+def test_the_kimi_step_fits_a_v5e(chip):
+    """`kimi-linear-48b.train-8k`'s step as the benchmark builds it, at
+    the cell's shapes: what the compiler plans for arguments, results
+    and temporaries has to leave the chip's limit room. The KDA layers
+    keep their scans' outputs and segment states across their
+    rematerialisation (671 MB): a change to what is kept, or to what a
+    segment's backward holds at once, shows here first."""
+    from benchmarks import harness
+    from benchmarks.models import kimi_linear as model
+    from kubeflow_tpu import parallel
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = harness.load_cell(root, "kimi-linear-48b.train-8k")
+    mesh_from_env = parallel.mesh_from_env
+    with mock.patch.object(parallel, "mesh_from_env",
+                           lambda: mesh_from_env(devices=[chip])):
+        trainer = model.trainer(cell.config)
+    shape = (cell.traffic["sequences_per_chip"], cell.traffic["seq_len"])
+
+    def described(dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=trainer.batch_sharding)
+
+    # the flash kernel refuses any backend but the TPU
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            jax.set_mesh(trainer.mesh):
+        state = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            jax.eval_shape(trainer._init,
+                           jax.eval_shape(lambda: jax.random.key(0))),
+            trainer.state_shardings)
+        plan = trainer._jit_step.__wrapped__.lower(
+            state, described(jnp.int32), described(jnp.int32),
+            described(jnp.float32)).compile().memory_analysis()
+    planned = (plan.argument_size_in_bytes + plan.output_size_in_bytes
+               - plan.alias_size_in_bytes + plan.temp_size_in_bytes)
+    assert planned < V5E_BYTES_LIMIT, f"{planned / 1e9:.3f} GB"
