@@ -222,12 +222,19 @@ def transformer_block(cfg, fam: Family, p, x, rope_positions, inv_freq,
     b, s = x.shape[:2]
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
     with jax.named_scope("attn_proj"):
-        q = proj("wq", h, p["wq"]).reshape(
-            b, s, cfg.num_heads, cfg.head_dim)
-        k = proj("wk", h, p["wk"]).reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim)
-        v = proj("wv", h, p["wv"]).reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim)
+        # The barrier keeps the head split's layout on the activation:
+        # XLA's layout assignment otherwise carries the reshape's
+        # preferred layout back through the product to the stacked
+        # weight, and the programs transpose wq/wk/wv (whole, once a
+        # decode dispatch; a slice a layer, before the product can
+        # start) where wo and the MLP read theirs in place (PERF.md
+        # section 6, PR 35; tests/test_tpu_compile.py reads the
+        # compiled text).
+        q, k, v = jax.lax.optimization_barrier(
+            tuple(proj(name, h, p[name]) for name in ("wq", "wk", "wv")))
+        q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
+        k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
         q = apply_rope(q, rope_positions, inv_freq)
         k = apply_rope(k, rope_positions, inv_freq)
     with jax.named_scope("kv_write"):

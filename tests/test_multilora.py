@@ -17,11 +17,15 @@ from aiohttp.test_utils import TestClient, TestServer
 pytest_plugins = ("aiohttp.pytest_plugin",)
 
 from kubeflow_tpu.models import llama
+from kubeflow_tpu.ops.norms import rms_norm
+from kubeflow_tpu.ops.rotary import apply_rope
 from kubeflow_tpu.serving import (
     EngineConfig, InferenceEngine, LLAMA_FAMILY, build_pack,
 )
+from kubeflow_tpu.serving import engine as engine_lib
 from kubeflow_tpu.serving import server as server_lib
 from kubeflow_tpu.serving.continuous import ContinuousBatcher
+from kubeflow_tpu.serving.quant import qdot, quantize_blocks
 from kubeflow_tpu.train.lora import LoraConfig, init_lora, merge_lora
 
 CFG = llama.LLAMA_TINY
@@ -85,6 +89,65 @@ def test_mixed_adapter_rows_in_one_batch(setup):
     np.testing.assert_array_equal(got[0], base)
     assert got[1].tolist() == _merged_solo(params, adapters, "alice", p, 5)
     assert got[2].tolist() == _merged_solo(params, adapters, "bob", p, 5)
+
+
+def _block_with_unguarded_head_split(cfg, fam, p, x, rope_positions,
+                                     inv_freq, write_kv, attn, proj=None):
+    """`engine.transformer_block` as it stood while the head split sat
+    on the product itself, `(h @ w).reshape(heads)`, written out: the
+    yardstick for what the block computes whichever way a projection
+    is made."""
+    if proj is None:
+        def proj(name, h, w):
+            return qdot(h, w, cfg.dtype)
+    b, s = x.shape[:2]
+    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    q = proj("wq", h, p["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
+    k = proj("wk", h, p["wk"]).reshape(
+        b, s, cfg.num_kv_heads, cfg.head_dim)
+    v = proj("wv", h, p["wv"]).reshape(
+        b, s, cfg.num_kv_heads, cfg.head_dim)
+    q = apply_rope(q, rope_positions, inv_freq)
+    k = apply_rope(k, rope_positions, inv_freq)
+    k_cache, v_cache = write_kv(k, v)
+    out = attn(q, k_cache, v_cache)
+    x = x + proj("wo", out.reshape(b, s, cfg.q_dim), p["wo"])
+    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    gate = fam.gate_act(proj("w_gate", h, p["w_gate"]))
+    x = x + proj("w_down", gate * proj("w_up", h, p["w_up"]), p["w_down"])
+    return x, (k_cache, v_cache)
+
+
+@pytest.mark.parametrize("how", ["plain", "lora-base-row",
+                                 "lora-adapter-row", "int8"])
+def test_logits_are_the_unguarded_head_splits_bit_for_bit(
+        setup, monkeypatch, how):
+    """The block keeps the head split's layout off the stacked weights
+    (a barrier between product and reshape, tests/test_tpu_compile.py
+    reads the effect): that must change no bit of the logits, for a
+    plain product, `lora_proj`'s base plus delta with the reserved id
+    0 and with an adapter's, and a `QTensor`'s product times its
+    scale."""
+    engine, params, _ = setup
+    kwargs = {}
+    if how == "int8":
+        params = quantize_blocks(params)
+    elif how != "plain":
+        aid = 1 if how == "lora-adapter-row" else 0
+        kwargs = dict(adapters=engine.adapter_pack.blocks,
+                      adapter_ids=jnp.full((2,), aid, jnp.int32))
+    tokens = jnp.asarray(np.random.default_rng(3).integers(
+        0, CFG.vocab_size, (2, 7)), jnp.int32)
+
+    def logits():
+        return np.asarray(jax.jit(lambda p, t: engine._forward_cached(
+            p, t, engine.init_state(2), return_all=True,
+            **kwargs)[0])(params, tokens))
+
+    got = logits()
+    monkeypatch.setattr(engine_lib, "transformer_block",
+                        _block_with_unguarded_head_split)
+    np.testing.assert_array_equal(got, logits())
 
 
 def test_adapter_validation(setup):

@@ -6,13 +6,16 @@ The topology is described inside a fixture and only there (libtpu loads
 in the worker that runs this file, once); where it cannot be described
 the tests skip. Keep such tests in this one file.
 
-The last case compiles a whole training step (over a minute, where a
-kernel takes two seconds): it is marked `slow`.
+The serving step programs compile in about twenty seconds each at any
+depth (their layers are a scan). The last case compiles a whole
+training step (over a minute, where a kernel takes two seconds): it is
+marked `slow`.
 """
 
 from __future__ import annotations
 
 import os
+import re
 from unittest import mock
 
 import jax
@@ -20,7 +23,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from kubeflow_tpu.ops.pallas.flash_attention import flash_attention
+from kubeflow_tpu.ops.pallas import flash_attention, force_interpret
 
 
 # what a v5e's runtime leaves a program of its 16 GB: `bytes_limit` of
@@ -73,6 +76,82 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, shape):
     for kernel in ("flash_attention_fwd", "flash_attention_dq",
                    "flash_attention_dkv"):
         assert kernel in text
+
+
+def compile_serving_step(sharding, case):
+    """One of `mistral-7b.steady`'s two step programs, compiled from
+    shapes alone: the cell's widths, slots, block size and prefill
+    slice; depth 2 and a pool of two slots' blocks, which change
+    neither program's body. -> (the compiled text, the model's config)."""
+    from benchmarks import harness
+    from benchmarks.models import llama as model
+    from kubeflow_tpu.models import llama
+    from kubeflow_tpu.serving import engine as engine_lib
+    from kubeflow_tpu.serving.continuous import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = harness.load_cell(root, "mistral-7b.steady").config
+    cfg = model.program_config(dict(config, num_hidden_layers=2))
+    batcher = config["batcher"]
+    slots, block = batcher["max_slots"], batcher["kv_block_size"]
+
+    def described(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    def of(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    params = described(jax.eval_shape(lambda k: llama.init(k, cfg), rng))
+    sp = engine_lib.SamplingParams(
+        of((slots,), jnp.float32), of((slots,)), of((slots,), jnp.float32))
+    # "auto" picks the Pallas kernels where the backend is a TPU, and
+    # the chip's programs hold them compiled, where the suite runs them
+    # interpreted (conftest.py)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            force_interpret(False):
+        ce = ContinuousEngine(
+            engine_lib.InferenceEngine(
+                params, cfg, engine_lib.LLAMA_FAMILY,
+                engine_lib.EngineConfig(**config["engine"])),
+            max_slots=slots, block_size=block,
+            num_blocks=1 + 2 * config["engine"]["max_len"] // block)
+        st = described(jax.eval_shape(ce.init_slots))
+        if case == "decode-step":
+            lowered = ce._step_jit.lower(params, None, st, sp, rng, steps=4)
+        else:
+            lowered = ce._append_jit.lower(
+                params, None, st, of((1,)),
+                of((1, batcher["prefill_chunk_tokens"])), of((1,)),
+                of((1,), jnp.bool_), sp, rng)
+        return lowered.compile().as_text(), cfg
+
+
+@pytest.mark.parametrize("case", ["decode-step", "prefill-slice"])
+def test_qkv_weights_are_read_where_they_lie(one_chip, case):
+    """The q, k and v projections read the stacked weights in the
+    parameters' own layout, inside the product, as `wo` and the MLP do.
+    While the head split's layout reached them (`(h @ w).reshape(heads)`,
+    before PR 35), the decode program transposed the three stacks whole
+    once a dispatch and both programs copied a transposed slice a layer
+    before the product could start (PERF.md section 6, PR 35)."""
+    text, cfg = compile_serving_step(one_chip, case)
+    layers, d = cfg.num_layers, cfg.hidden_size
+    kv = cfg.num_kv_heads * cfg.head_dim
+    assert "tpu_custom_call" in text        # the Pallas kernels are in it
+    # the parameters' names and layout, which the rest reads by
+    assert re.search(rf"%params__blocks____wq__\S* = bf16\[{layers},{d},{d}\]"
+                     r"\{2,1,0", text)
+    whole = re.findall(
+        r"^.* copy\(%params__blocks____w[qkv]__.*$", text, re.M)
+    assert not whole, whole
+    # wq, wk or wv (and wo: wq's shape), a layer's slice or the stack,
+    # in another order of dimensions than the parameter's
+    relaid = [line.strip()[:160] for line in text.splitlines() if re.search(
+        rf"= bf16\[(1|{layers}),{d},({d}|{kv})\]\{{(?!2,1,0)"
+        rf"|= bf16\[{d},({d}|{kv})\]\{{(?!1,0)", line)]
+    assert not relaid, relaid
 
 
 @pytest.mark.slow
