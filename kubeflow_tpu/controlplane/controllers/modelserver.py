@@ -60,7 +60,8 @@ from kubeflow_tpu.parallel.mesh import SLICE_TOPOLOGIES
 # — controllers must never touch a TPU backend). Drift is pinned by
 # tests/test_modelserver.py.
 MODEL_NAMES = ("llama-tiny", "llama3-1b", "llama3-8b", "gemma-tiny",
-               "gemma-2b", "mixtral-tiny")
+               "gemma-2b", "mixtral-tiny", "granite-hybrid-tiny",
+               "granite-4.0-h-micro")
 
 DEFAULT_IMAGE = "kubeflow-tpu/serving:latest"  # KFTPU_SERVING_IMAGE env
 SERVE_PORT = 8000

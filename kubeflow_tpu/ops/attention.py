@@ -111,37 +111,52 @@ def layered_pool(k_pool, v_pool, layer):
            else f"of shape {jnp.shape(layer)}"))
 
 
-def resolve_paged_prefill_impl(impl: str, *, vmem_bytes: int = 0) -> str:
+def _kernel_copies_heads_of(head_dim: int | None) -> bool:
+    """The compiled paged kernels copy pool blocks in whole 128-lane
+    tiles, so they take heads whose size is a multiple of 128 and
+    raise on any other (ops/pallas/paged_attention.py). A caller that
+    does not say the size is not judged by it."""
+    return head_dim is None or head_dim % 128 == 0
+
+
+def resolve_paged_prefill_impl(impl: str, *, vmem_bytes: int = 0,
+                               head_dim: int | None = None) -> str:
     """Resolve a `paged_prefill_attention` impl request to "xla" or
     "pallas". "auto" is a rule on platform and shape, nothing else: the
     fused kernel on TPU while the VMEM it needs for the call's shapes
     (`prefill_append.vmem_bytes`, which grows with the chunk's
-    `s * n_q` query rows) fits `VMEM_BUDGET_BYTES`; the XLA
-    scatter+gather for longer chunks and on every other backend.
-    Without `vmem_bytes` only the platform is judged — what an engine
-    can say before it has seen a chunk."""
+    `s * n_q` query rows) fits `VMEM_BUDGET_BYTES` and the heads are a
+    size it copies (`head_dim`, a multiple of 128); the XLA
+    scatter+gather for longer chunks, other heads and on every other
+    backend. Without `vmem_bytes` or `head_dim` only the rest is
+    judged — what an engine can say before it has seen a chunk."""
     _check_impl(impl, "paged prefill")
     if impl != "auto":
         return impl
     from kubeflow_tpu.ops.pallas.prefill_append import VMEM_BUDGET_BYTES
 
-    if jax.default_backend() == "tpu" and vmem_bytes <= VMEM_BUDGET_BYTES:
+    if (jax.default_backend() == "tpu" and vmem_bytes <= VMEM_BUDGET_BYTES
+            and _kernel_copies_heads_of(head_dim)):
         return "pallas"
     return "xla"
 
 
-def resolve_paged_attention_impl(impl: str) -> str:
+def resolve_paged_attention_impl(impl: str, *,
+                                 head_dim: int | None = None) -> str:
     """Resolve a `paged_attention` impl request to "xla" or "pallas".
 
-    "auto" is the fused Pallas kernel on TPU and the XLA gather on
-    every other backend (there the kernel runs only where a test asks
-    for interpret mode). Resolving once at engine construction (rather
+    "auto" is the fused Pallas kernel on TPU for heads of a size it
+    copies (`head_dim`, a multiple of 128; not judged where it is not
+    given), and the XLA gather for other heads and on every other
+    backend (there the kernel runs only where a test asks for
+    interpret mode). Resolving once at engine construction (rather
     than per trace) is what lets serving label its metrics with the
     impl that actually runs.
     """
     _check_impl(impl, "paged attention")
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return ("pallas" if jax.default_backend() == "tpu"
+                and _kernel_copies_heads_of(head_dim) else "xla")
     return impl
 
 
@@ -324,7 +339,7 @@ def paged_attention(
             f"kv_mask shape {kv_mask.shape} does not match "
             f"blocks_per_slot * block_size = {blocks_per_slot} * "
             f"{block_size} = {width}")
-    impl = resolve_paged_attention_impl(impl)
+    impl = resolve_paged_attention_impl(impl, head_dim=hd)
     _impl_counts["paged"] += 1
     _impl_counts["paged_" + impl] += 1
     if impl == "pallas":
@@ -419,7 +434,7 @@ def paged_prefill_attention(
         vmem_bytes,
     )
 
-    impl = resolve_paged_prefill_impl(impl, vmem_bytes=vmem_bytes(
+    impl = resolve_paged_prefill_impl(impl, head_dim=hd, vmem_bytes=vmem_bytes(
         s, n_q, n_kv, hd, block_size, q.dtype.itemsize))
     _impl_counts["paged_prefill"] += 1
     _impl_counts["paged_prefill_" + impl] += 1

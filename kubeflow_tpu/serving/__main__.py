@@ -27,10 +27,13 @@ def model_registry():
     jax regardless — the serving package __init__ imports the engine —
     which is why the ModelServer CONTROLLER mirrors MODEL_NAMES as a
     literal instead of importing it; tests pin the two together.)"""
-    from kubeflow_tpu.models import gemma, llama, llama_moe
+    from kubeflow_tpu.models import gemma, granite_hybrid, llama, llama_moe
     from kubeflow_tpu.serving.engine import (
-        GEMMA_FAMILY, LLAMA_FAMILY, MOE_LLAMA_FAMILY,
+        GEMMA_FAMILY, LLAMA_FAMILY, MOE_LLAMA_FAMILY, granite_hybrid_family,
     )
+
+    def granite(cfg):
+        return cfg, granite_hybrid.init, granite_hybrid_family(cfg)
 
     return {
         "llama-tiny": (llama.LLAMA_TINY, llama.init, LLAMA_FAMILY),
@@ -40,6 +43,11 @@ def model_registry():
         "gemma-2b": (gemma.GEMMA_2B, gemma.init, GEMMA_FAMILY),
         "mixtral-tiny": (llama_moe.MIXTRAL_TINY, llama_moe.init,
                          MOE_LLAMA_FAMILY),
+        # Mamba-2 layers with a position-free GQA layer every tenth:
+        # continuous batching only (--continuous), a recurrent state
+        # per slot beside the paged pool
+        "granite-hybrid-tiny": granite(granite_hybrid.GRANITE_HYBRID_TINY),
+        "granite-4.0-h-micro": granite(granite_hybrid.GRANITE_4_0_H_MICRO),
     }
 
 
@@ -278,6 +286,11 @@ def main(argv=None) -> int:
     )
 
     cfg, init_fn, family = model_registry()[args.model]
+    if family.recurrent and not args.continuous:
+        raise SystemExit(
+            f"{args.model} has recurrent layers: it is served by the "
+            "continuous batcher alone (--continuous), which keeps a "
+            "state per slot beside the paged pool")
     params = _load_params(args, lambda k: init_fn(k, cfg))
     if args.quant == "int8":
         from kubeflow_tpu.serving.quant import quantize_blocks

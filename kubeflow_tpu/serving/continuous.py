@@ -70,7 +70,10 @@ from kubeflow_tpu.ops.pallas.paged_attention import group_blocks
 from kubeflow_tpu.ops.rotary import rope_frequencies
 from kubeflow_tpu.serving.engine import (
     InferenceEngine,
+    RecurrentState,
     SamplingParams,
+    mamba_block,
+    scan_layers,
     transformer_block,
 )
 from kubeflow_tpu.obs.cachestats import CacheLedger
@@ -115,7 +118,7 @@ class SlotState:
     """
 
     def __init__(self, k, v, length, tok, aid=None,
-                 block_table=None, frozen=None):
+                 block_table=None, frozen=None, rec=None):
         self.k = k            # [L, num_blocks, block_size, n_kv, hd]
         self.v = v            # (paged pool; block 0 is the trash block)
         self.length = length  # [S] int32 — filled cache cells per row
@@ -135,10 +138,21 @@ class SlotState:
         if frozen is None:
             frozen = jnp.zeros(length.shape, bool)
         self.frozen = frozen
+        # The second cache kind, beside the pool: the recurrent state
+        # of a model with Mamba layers, one row a slot and not paged —
+        # `RecurrentState(conv [Lm, S, K - 1, C], ssm [Lm, S, H, P,
+        # N])`, zeroed when a slot is adopted, advanced by append and
+        # decode, still while the row is frozen or idle. None (no
+        # leaves: the same programs as before) for every other model.
+        self.rec = rec
+
+    def replace(self, **fields) -> "SlotState":
+        """This state with `fields` changed."""
+        return SlotState(**{**vars(self), **fields})
 
     def tree_flatten(self):
         return (self.k, self.v, self.length, self.tok, self.aid,
-                self.block_table, self.frozen), None
+                self.block_table, self.frozen, self.rec), None
 
     @classmethod
     def tree_unflatten(cls, _, children):
@@ -199,6 +213,7 @@ class ContinuousEngine:
                  draft: InferenceEngine | None = None):
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
+        self.engine = engine
         if draft is not None:
             # continuous speculative decoding (ISSUE 9): the accept
             # rule compares draft and target distributions tokenwise,
@@ -218,6 +233,21 @@ class ContinuousEngine:
                     "multi-LoRA adapter pack (the verify pass would "
                     "score base-model logits against adapter rows)")
         self.draft = draft
+        # A model with recurrent layers keeps a second cache kind, a
+        # state per slot (`SlotState.rec`). What rests on a sequence's
+        # cache being KV cells alone is refused here, in one place: a
+        # block of cells cannot seed, move or restore a sequence whose
+        # recurrent layers have no state at that boundary, and a
+        # rejected draft token cannot be taken back out of a state.
+        self.recurrent = engine.family.recurrent
+        if draft is not None:
+            self.refuse_recurrent(
+                "a draft model (speculative decoding rolls rejected "
+                "tokens back, and a recurrent state cannot be rolled back)")
+        if engine.adapter_pack is not None:
+            self.refuse_recurrent(
+                "an adapter pack (multi-LoRA wraps the attention "
+                "block's matmuls only)")
         if block_size < 2 or block_size & (block_size - 1):
             raise ValueError(
                 f"block_size must be a power of two >= 2, got {block_size}")
@@ -227,17 +257,16 @@ class ContinuousEngine:
         # value. "auto" = pallas on TPU, xla elsewhere.
         self.paged_attention_impl = paged_attention_impl
         self.attention_impl = resolve_paged_attention_impl(
-            paged_attention_impl)
+            paged_attention_impl, head_dim=engine.cfg.head_dim)
         # chunked-prefill / draft-verify writes go through the fused
         # prefill/append op — same knob. This is the platform's answer;
         # each trace re-resolves the REQUEST with its chunk shape,
         # because "auto" also bounds the kernel's VMEM need.
         self.prefill_impl = resolve_paged_prefill_impl(
-            paged_attention_impl)
+            paged_attention_impl, head_dim=engine.cfg.head_dim)
         if "pallas" in (self.attention_impl, self.prefill_impl):
             # fail at construction, not at the first request's trace
             resolve_interpret(None)
-        self.engine = engine
         self.S = max_slots
         # Paged KV geometry. The cache is a POOL of fixed-size blocks
         # [L, num_blocks, block_size, n_kv, hd] plus a per-slot block
@@ -313,19 +342,55 @@ class ContinuousEngine:
             self._dinsert_jit = jax.jit(self._draft_insert,
                                         donate_argnums=(0,))
 
+    def refuse_recurrent(self, what: str) -> None:
+        """Raise where the model has recurrent layers and `what` was
+        asked of it."""
+        if self.recurrent:
+            raise ValueError(
+                f"{self.engine.family.name} has recurrent layers, whose "
+                f"state lives in the slot and not in the paged pool: it "
+                f"cannot be served with {what}")
+
+    def _inv_freq(self):
+        cfg = self.engine.cfg
+        if not self.engine.family.rotary:
+            return None
+        return rope_frequencies(cfg.head_dim, theta=cfg.rope_theta)
+
     # -- state ------------------------------------------------------------
 
     def init_slots(self) -> SlotState:
         cfg = self.engine.cfg
-        shape = (cfg.num_layers, self.num_blocks, self.block_size,
+        shape = (self.engine.kv_layers, self.num_blocks, self.block_size,
                  cfg.num_kv_heads, cfg.head_dim)
+        rec = None
+        if self.recurrent:
+            lm = self.engine.mamba_layers
+            rec = RecurrentState(
+                jnp.zeros((lm, self.S, cfg.mamba_d_conv - 1, cfg.conv_dim),
+                          cfg.state_dtype),
+                jnp.zeros((lm, self.S, cfg.mamba_n_heads, cfg.mamba_d_head,
+                           cfg.mamba_d_state), cfg.state_dtype))
         return SlotState(
             jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype),
             jnp.zeros((self.S,), jnp.int32),
             jnp.zeros((self.S,), jnp.int32),
             None,
             jnp.zeros((self.S, self.blocks_per_slot), jnp.int32),
+            rec=rec,
         )
+
+    def state_bytes_per_slot(self) -> int:
+        """HBM bytes of one slot's recurrent state, all Mamba layers:
+        what a decode step reads and writes once for every slot that
+        decodes in it. 0 for a model without recurrent layers."""
+        if not self.recurrent:
+            return 0
+        cfg = self.engine.cfg
+        cells = ((cfg.mamba_d_conv - 1) * cfg.conv_dim
+                 + cfg.mamba_n_heads * cfg.mamba_d_head * cfg.mamba_d_state)
+        return (self.engine.mamba_layers * cells
+                * jnp.dtype(cfg.state_dtype).itemsize)
 
     def decode_kv_steps(self, cursors) -> dict[str, int]:
         """What a decode step at these cursors (one per decoding slot)
@@ -353,7 +418,7 @@ class ContinuousEngine:
         `serving_kv_blocks_in_use` and bench_decode_paged report in."""
         cfg = self.engine.cfg
         itemsize = jnp.dtype(cfg.dtype).itemsize
-        return (2 * cfg.num_layers * self.block_size
+        return (2 * self.engine.kv_layers * self.block_size
                 * cfg.num_kv_heads * cfg.head_dim * itemsize)
 
     def _reset_slots(self, st: SlotState, slots):
@@ -365,7 +430,7 @@ class ContinuousEngine:
         bt = st.block_table.at[slots].set(0)
         length = st.length.at[slots].set(0)
         frozen = st.frozen.at[slots].set(False)
-        return SlotState(st.k, st.v, length, st.tok, st.aid, bt, frozen)
+        return st.replace(length=length, block_table=bt, frozen=frozen)
 
     def reset_slots(self, st: SlotState, slots: list[int]) -> SlotState:
         """Host entry: pads the slot list to a power of two by
@@ -393,8 +458,7 @@ class ContinuousEngine:
     def _import_blocks(self, st: SlotState, ids, k, v):
         kp = st.k.at[:, ids].set(k.astype(st.k.dtype))
         vp = st.v.at[:, ids].set(v.astype(st.v.dtype))
-        return SlotState(kp, vp, st.length, st.tok, st.aid,
-                         st.block_table, st.frozen)
+        return st.replace(k=kp, v=vp)
 
     def import_blocks(self, st: SlotState, block_ids, k, v) -> SlotState:
         """Scatter migrated block payloads into locally-allocated
@@ -437,7 +501,7 @@ class ContinuousEngine:
         rng, sub = jax.random.split(rng)
 
         positions = st.length[:, None]                      # [S, 1]
-        inv_freq = rope_frequencies(cfg.head_dim, theta=cfg.rope_theta)
+        inv_freq = self._inv_freq()
         kv_positions = jnp.broadcast_to(
             jnp.arange(self.kv_width, dtype=jnp.int32)[None, :],
             (S, self.kv_width))
@@ -461,7 +525,7 @@ class ContinuousEngine:
         # rewrote the whole cache every token, doubling decode HBM
         # traffic. Here the per-step write is S rows per layer.
         def layer(carry, scanned):
-            x, k_all, v_all = carry
+            x, k_all, v_all, rec = carry
             if adapters is None:
                 p, li = scanned
                 proj = None
@@ -497,25 +561,37 @@ class ContinuousEngine:
             x, (k_all, v_all) = transformer_block(
                 cfg, fam, p, x, positions, inv_freq, write_kv, attn,
                 proj)
-            return (x, k_all, v_all), None
+            return (x, k_all, v_all, rec), None
 
-        layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-        xs = ((params["blocks"], layer_ids) if adapters is None
-              else (params["blocks"], adapters, layer_ids))
-        (x, k_new, v_new), _ = jax.lax.scan(
-            layer, (x, st.k, st.v), xs)
+        # A recurrent layer moves a row's state by its one token, in
+        # place in the carry, as the pool is written. A frozen row
+        # (its prompt is still being fed by `append_rows`) and an idle
+        # one (no request: its table points at the trash block) stand
+        # still: for them the token does not count.
+        counts = jnp.where(
+            st.frozen | (st.block_table[:, 0] == 0), 0, 1)
+
+        def mamba_layer(carry, scanned):
+            x, k_all, v_all, rec = carry
+            p, li = scanned
+            x, rec = mamba_block(cfg, fam, p, x, rec, li, None, counts)
+            return (x, k_all, v_all, rec), None
+
+        x, k_new, v_new, rec = scan_layers(
+            cfg, fam, params, (x, st.k, st.v, st.rec), layer,
+            mamba_layer, adapters)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = eng._head(params, x[:, -1])
         nxt, lp = eng._sample(logits, sub, sp)
         # frozen rows keep their cursors: length marks the prefilled
         # frontier and tok the NEXT prompt token — a decode step's
         # garbage sample must not clobber either
-        st = SlotState(
-            k_new, v_new,
-            jnp.where(st.frozen, st.length,
-                      jnp.minimum(st.length + 1, ec.max_len)),
-            jnp.where(st.frozen, st.tok, nxt.astype(jnp.int32)),
-            st.aid, st.block_table, st.frozen)
+        st = st.replace(
+            k=k_new, v=v_new,
+            length=jnp.where(st.frozen, st.length,
+                             jnp.minimum(st.length + 1, ec.max_len)),
+            tok=jnp.where(st.frozen, st.tok, nxt.astype(jnp.int32)),
+            rec=rec)
         return st, nxt, lp, rng
 
     def _step(self, params, adapters, st: SlotState, sp: SamplingParams,
@@ -558,7 +634,8 @@ class ContinuousEngine:
         attended in the same fused op (ops.paged_prefill_attention).
         Shared by chunked prefill (`_append_rows`) and the speculative
         verify pass (`_spec_verify`) so the two paths cannot drift.
-        Returns (final-norm hidden states [g, s, D], k_pool, v_pool).
+        Returns (final-norm hidden states [g, s, D], the state with
+        the pools, and a recurrent model's rows, written).
 
         Write disjointness holds by construction: a row only ever
         writes cells at/above its own cursor, which land in its
@@ -571,11 +648,11 @@ class ContinuousEngine:
         s = tokens.shape[1]
         positions = (start[:, None]
                      + jnp.arange(s, dtype=jnp.int32)[None, :])
-        inv_freq = rope_frequencies(cfg.head_dim, theta=cfg.rope_theta)
+        inv_freq = self._inv_freq()
         x = eng._embed(params, tokens)
 
         def layer(carry, scanned):
-            x, k_all, v_all = carry
+            x, k_all, v_all, rec = carry
             if adapters is None:
                 p, li = scanned
                 proj = None
@@ -606,15 +683,22 @@ class ContinuousEngine:
             x, _ = transformer_block(
                 cfg, fam, p, x, positions, inv_freq, write_kv, attn,
                 proj)
-            return (x, cell["k"], cell["v"]), None
+            return (x, cell["k"], cell["v"], rec), None
 
-        layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-        xs = ((params["blocks"], layer_ids) if adapters is None
-              else (params["blocks"], adapters, layer_ids))
-        (x, k_new, v_new), _ = jax.lax.scan(
-            layer, (x, st.k, st.v), xs)
+        # A recurrent layer carries the rows' state in and out of the
+        # slice: in from the slots' rows of the carry, out to them
+        # again after the slice's valid tokens.
+        def mamba_layer(carry, scanned):
+            x, k_all, v_all, rec = carry
+            p, li = scanned
+            x, rec = mamba_block(cfg, fam, p, x, rec, li, slots, n_valid)
+            return (x, k_all, v_all, rec), None
+
+        x, k_new, v_new, rec = scan_layers(
+            cfg, fam, params, (x, st.k, st.v, st.rec), layer,
+            mamba_layer, adapters)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, k_new, v_new
+        return x, st.replace(k=k_new, v=v_new, rec=rec)
 
     def _append_rows(self, params, adapters, st: SlotState, slots,
                      tokens, n_valid, finish, sp, rng):
@@ -628,7 +712,7 @@ class ContinuousEngine:
         eng, ec = self.engine, self.engine.ec
         rng, sub = jax.random.split(rng)
         start = st.length[slots]
-        x, k_new, v_new = self._paged_forward(
+        x, st = self._paged_forward(
             params, adapters, st, slots, tokens, n_valid, start)
         last = jnp.maximum(n_valid - 1, 0)
         x_last = jnp.take_along_axis(
@@ -645,9 +729,8 @@ class ContinuousEngine:
         tok = st.tok.at[slots].set(newtok)
         frozen = st.frozen.at[slots].set(
             jnp.where(finish, False, st.frozen[slots]))
-        st = SlotState(k_new, v_new, length, tok, st.aid,
-                       st.block_table, frozen)
-        return st, nxt, lp, rng
+        return (st.replace(length=length, tok=tok, frozen=frozen),
+                nxt, lp, rng)
 
     def append_rows(self, st: SlotState, slots, tokens, n_valid,
                     finish, sp: SamplingParams, rng):
@@ -669,13 +752,18 @@ class ContinuousEngine:
         prefill: decode steps mask the row until `append_rows` has fed
         the whole suffix. `tok` is the next prompt token (kept for the
         cursor invariant; append feeds tokens explicitly)."""
-        return SlotState(
-            st.k, st.v,
-            st.length.at[slot].set(seed_len),
-            st.tok.at[slot].set(tok),
-            st.aid.at[slot].set(aid),
-            st.block_table.at[slot].set(table),
-            st.frozen.at[slot].set(True))
+        rec = st.rec
+        if rec is not None:
+            # a recurrent layer's state starts from zero: nothing of
+            # the slot's last request may reach this one
+            rec = RecurrentState(rec.conv.at[:, slot].set(0),
+                                 rec.ssm.at[:, slot].set(0))
+        return st.replace(
+            length=st.length.at[slot].set(seed_len),
+            tok=st.tok.at[slot].set(tok),
+            aid=st.aid.at[slot].set(aid),
+            block_table=st.block_table.at[slot].set(table),
+            frozen=st.frozen.at[slot].set(True), rec=rec)
 
     def adopt_slot(self, st: SlotState, slot: int, table, seed_len: int,
                    tok: int, aid: int = 0) -> SlotState:
@@ -694,9 +782,8 @@ class ContinuousEngine:
         sel = (i < n)[None, :, None, None]
         kd = jnp.where(sel, st.k[:, src], st.k[:, dst])
         vd = jnp.where(sel, st.v[:, src], st.v[:, dst])
-        return SlotState(
-            st.k.at[:, dst].set(kd), st.v.at[:, dst].set(vd),
-            st.length, st.tok, st.aid, st.block_table, st.frozen)
+        return st.replace(k=st.k.at[:, dst].set(kd),
+                          v=st.v.at[:, dst].set(vd))
 
     def copy_cells(self, st: SlotState, src: int, dst: int,
                    n: int) -> SlotState:
@@ -856,7 +943,7 @@ class ContinuousEngine:
         tin = jnp.concatenate([st.tok[:, None], drafted], axis=1)
         slots = jnp.arange(S, dtype=jnp.int32)
         n_valid = jnp.full((S,), gamma + 1, jnp.int32)
-        x, k_pool, v_pool = self._paged_forward(
+        x, st = self._paged_forward(
             params, None, st, slots, tin, n_valid, st.length)
         all_logits = eng._head(params, x)          # [S, gamma+1, V]
         ps = jax.vmap(lambda lg: _dist(lg, sp),
@@ -886,8 +973,7 @@ class ContinuousEngine:
             st.frozen, st.length,
             jnp.minimum(st.length + k + 1, ec.max_len))
         tok = jnp.where(st.frozen, st.tok, extra.astype(jnp.int32))
-        st = SlotState(k_pool, v_pool, length, tok, st.aid,
-                       st.block_table, st.frozen)
+        st = st.replace(length=length, tok=tok)
         # draft rollback: the scan advanced every row by gamma; keep
         # the k+1 cells the accepted tokens fed (capped at gamma),
         # then feed the last drafted token unconditionally — its write
@@ -1062,6 +1148,13 @@ class ContinuousBatcher:
         # `prefixes` are names for token lists and ride the same
         # cache: the first use computes the prefix, later uses hit.
         self._radix = RadixPrefixCache(self.cengine.pool)
+        # A block of KV cells cannot seed a slot whose recurrent layers
+        # have no state at that boundary (state snapshots are not kept):
+        # for such a model admission matches nothing and no block is
+        # indexed, so every prompt, a preempted request's replay too,
+        # is computed from its first token.
+        self._radix_on = not self.cengine.recurrent
+        self.state_resets = 0     # slots adopted with a zeroed state
         # Block lifecycle ledger (ISSUE 13): attached to the pool
         # before any alloc, so every block birth/death is booked to a
         # cause and births − frees reconciles against pool.in_use (the
@@ -1082,6 +1175,9 @@ class ContinuousBatcher:
                 f"kv_spill_bytes must be >= 0, got {kv_spill_bytes}")
         self._spill_tier: HostSpillTier | None = None
         if kv_spill_bytes is not None:
+            self.cengine.refuse_recurrent(
+                "kv_spill_bytes (a spilled block restores KV cells, not "
+                "the state at its boundary)")
             self._spill_tier = HostSpillTier(
                 kv_spill_bytes, self.cengine.kv_block_bytes())
             self._radix.attach_spill(self._spill_tier,
@@ -1240,6 +1336,12 @@ class ContinuousBatcher:
         `serving_kv_blocks_in_use` gauge; x `kv_block_bytes()` for
         HBM)."""
         return self.cengine.pool.in_use
+
+    def ssm_state_bytes(self) -> int:
+        """Recurrent-state bytes of the slots that hold a request (the
+        `serving_ssm_state_bytes` gauge); 0 for a model without
+        recurrent layers."""
+        return len(self._active) * self.cengine.state_bytes_per_slot()
 
     def prefix_cache_stats(self) -> dict:
         return {
@@ -1499,7 +1601,8 @@ class ContinuousBatcher:
         land strictly above it (the slot's cursor never moves back),
         so adopted blocks are immutable. Must run BEFORE
         `_release_blocks` frees the rest."""
-        if rec.freed or not rec.kv_toks or rec.prefilling is not None:
+        if (rec.freed or not rec.kv_toks or rec.prefilling is not None
+                or not self._radix_on):
             # mid-chunked-prefill retirement (cancel): cells past the
             # fed frontier are unwritten — nothing safely cacheable
             return
@@ -1535,7 +1638,7 @@ class ContinuousBatcher:
         tree must not evict a block our own table points at."""
         bs = self.cengine.block_size
         n_full = len(rec.kv_toks) // bs
-        if n_full <= 0:
+        if n_full <= 0 or not self._radix_on:
             return
         blocks = {i: rec.owned[i] for i in range(n_full)
                   if i in rec.owned}
@@ -1861,7 +1964,7 @@ class ContinuousBatcher:
         extra = None
         m = 0
         full = list(tokens)
-        if self._st is not None:
+        if self._st is not None and self._radix_on:
             nodes, pnode, plen = self._radix.match(full, ns=meta.ns)
             # always leave >= 1 token to prefill: sampling the
             # first output needs a forward pass over something
@@ -2009,6 +2112,8 @@ class ContinuousBatcher:
             self._free.append(slot)
             raise
         self.requests += 1
+        if self.cengine.recurrent:
+            self.state_resets += 1
         rec = _Slot(fut, max_new, queue,
                     stop=tuple(tuple(s) for s in
                                sampling.get("stop", ())))
@@ -2290,8 +2395,13 @@ class ContinuousBatcher:
         # span says how far the KV walk of this dispatch's first step
         # follows the live blocks: a row's cursor is its last token's
         # cell, which that step writes.
-        with self.profiler.phase("decode", **self.cengine.decode_kv_steps(
-                len(r.kv_toks) - 1 for r in snap.values())):
+        stats = self.cengine.decode_kv_steps(
+            len(r.kv_toks) - 1 for r in snap.values())
+        if self.cengine.recurrent:
+            # what the step reads and writes once beside the KV walk
+            stats["ssm_state_bytes"] = (
+                len(snap) * self.cengine.state_bytes_per_slot())
+        with self.profiler.phase("decode", **stats):
             async with self.gpu_lock:
                 st, toks, lps, rng = await loop.run_in_executor(
                     None, run_step)
@@ -2515,6 +2625,9 @@ class ContinuousBatcher:
         `MigratedAway` (the router absorbs it and resumes on the
         peer); all blocks are released, so the replica can exit
         immediately instead of waiting out its longest generation."""
+        self.cengine.refuse_recurrent(
+            "export_sequences (a migrated block carries KV cells, not "
+            "the state at its boundary)")
         self._draining = True
         w = self._worker
         if w is not None and not w.done():
@@ -2622,6 +2735,9 @@ class ContinuousBatcher:
         including a wedged transfer (`wedge=True`, the chaos harness's
         mid-transfer fault) — every allocated block is freed back: a
         failed import must leak nothing."""
+        self.cengine.refuse_recurrent(
+            "import_sequence (a migrated block carries KV cells, not "
+            "the state at its boundary)")
         rec = migration.unpack_record(record)
         migration.validate_geometry(rec["geometry"], self.cengine)
         if rec["kv"] is None:
@@ -2703,6 +2819,9 @@ class ContinuousBatcher:
         are ref-pinned for the duration of the device->host copy so
         concurrent admission cannot evict them mid-export."""
         ceng = self.cengine
+        ceng.refuse_recurrent(
+            "export_prefix (a prefilled block carries KV cells, not the "
+            "state at its boundary)")
         bs = ceng.block_size
         if self._st is None or len(tokens) < bs:
             return None

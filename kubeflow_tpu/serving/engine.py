@@ -30,6 +30,7 @@ from kubeflow_tpu.ops.attention import dot_product_attention
 from kubeflow_tpu.ops.embedding import embed_lookup
 from kubeflow_tpu.ops.norms import rms_norm
 from kubeflow_tpu.ops.rotary import apply_rope, rope_frequencies
+from kubeflow_tpu.ops.ssd import causal_conv, ssd_chunked, ssd_step
 from kubeflow_tpu.serving.quant import qdot
 
 Params = dict[str, Any]
@@ -48,6 +49,25 @@ class Family:
     gate_act: Callable[[jnp.ndarray], jnp.ndarray]
     scale_embed: bool          # multiply embeddings by sqrt(hidden)
     mlp: Callable[..., jnp.ndarray] | None = None
+    # Each layer's kind in model order, "attention" or "mamba"; None
+    # where every layer attends. A model with recurrent layers keeps
+    # its attention layers under params["blocks"] and the rest under
+    # params["mamba_blocks"], each stacked in model order
+    # (`scan_layers`), and is served by `ContinuousBatcher` alone.
+    layer_kinds: tuple[str, ...] | None = None
+    rotary: bool = True        # False: no positional encoding at all
+    # Fixed multipliers (the Granite families'): on the embeddings, on
+    # each half-block's output before the residual add, on q k^T (None:
+    # head_dim ** -0.5), and what the logits are divided by.
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    logits_scaling: float = 1.0
+
+    @property
+    def recurrent(self) -> bool:
+        return (self.layer_kinds is not None
+                and "mamba" in self.layer_kinds)
 
 
 LLAMA_FAMILY = Family("llama", jax.nn.silu, scale_embed=False)
@@ -79,6 +99,18 @@ def _moe_serving_mlp(cfg, p, h: jnp.ndarray) -> jnp.ndarray:
 
 MOE_LLAMA_FAMILY = Family(
     "llama-moe", jax.nn.silu, scale_embed=False, mlp=_moe_serving_mlp)
+
+
+def granite_hybrid_family(cfg) -> Family:
+    """The family of one `models.granite_hybrid.GraniteHybridConfig`:
+    its layer pattern and its four multipliers are the config's own."""
+    return Family(
+        "granite-hybrid", jax.nn.silu, scale_embed=False,
+        layer_kinds=tuple(cfg.layer_types), rotary=False,
+        embed_multiplier=cfg.embedding_multiplier,
+        residual_multiplier=cfg.residual_multiplier,
+        attention_multiplier=cfg.attention_multiplier,
+        logits_scaling=cfg.logits_scaling)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,6 +228,31 @@ jax.tree_util.register_pytree_node(
 )
 
 
+def _plain_proj(cfg):
+    def proj(name, h, w):
+        return qdot(h, w, cfg.dtype)
+    return proj
+
+
+def _residual(fam: Family, delta: jnp.ndarray) -> jnp.ndarray:
+    """A half-block's output as it joins the residual stream."""
+    if fam.residual_multiplier == 1.0:
+        return delta
+    return delta * jnp.asarray(fam.residual_multiplier, delta.dtype)
+
+
+def mlp_half(cfg, fam: Family, p, x, proj):
+    """The feed-forward half of a block, whatever its first half was:
+    norm, gated MLP (or the family's own), residual add."""
+    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if fam.mlp is not None:
+            return x + _residual(fam, fam.mlp(cfg, p, h))
+        gate = fam.gate_act(proj("w_gate", h, p["w_gate"]))
+        ff = gate * proj("w_up", h, p["w_up"])
+        return x + _residual(fam, proj("w_down", ff, p["w_down"]))
+
+
 def transformer_block(cfg, fam: Family, p, x, rope_positions, inv_freq,
                       write_kv, attn, proj=None):
     """One decoder block on `x` [b, s, h]: norms, QKV/output projections,
@@ -216,8 +273,7 @@ def transformer_block(cfg, fam: Family, p, x, rope_positions, inv_freq,
     training forward's (models/llama.py). Scopes are HLO metadata: they change
     no compiled instruction."""
     if proj is None:
-        def proj(name, h, w):
-            return qdot(h, w, cfg.dtype)
+        proj = _plain_proj(cfg)
 
     b, s = x.shape[:2]
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
@@ -235,23 +291,173 @@ def transformer_block(cfg, fam: Family, p, x, rope_positions, inv_freq,
         q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
         k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
         v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-        q = apply_rope(q, rope_positions, inv_freq)
-        k = apply_rope(k, rope_positions, inv_freq)
+        if fam.rotary:
+            q = apply_rope(q, rope_positions, inv_freq)
+            k = apply_rope(k, rope_positions, inv_freq)
+        if fam.attention_multiplier is not None:
+            # the attention calls scale q k^T by head_dim ** -0.5: q
+            # carries the rest (a power of two at Granite's sizes)
+            q = q * jnp.asarray(
+                fam.attention_multiplier * cfg.head_dim ** 0.5, q.dtype)
     with jax.named_scope("kv_write"):
         k_cache, v_cache = write_kv(k, v)
     out = attn(q, k_cache, v_cache)
     with jax.named_scope("attn_proj"):
-        x = x + proj("wo", out.reshape(b, s, cfg.q_dim), p["wo"])
+        x = x + _residual(
+            fam, proj("wo", out.reshape(b, s, cfg.q_dim), p["wo"]))
+    return mlp_half(cfg, fam, p, x, proj), (k_cache, v_cache)
 
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    with jax.named_scope("mlp"):
-        if fam.mlp is not None:
-            x = x + fam.mlp(cfg, p, h)
+
+class RecurrentState(NamedTuple):
+    """The recurrent state of every slot at every Mamba layer, in
+    `cfg.state_dtype`: the second cache kind of a continuous batch,
+    beside the paged KV pool (`serving/continuous.py` `SlotState.rec`)."""
+
+    conv: jnp.ndarray     # [Lm, S, K - 1, conv_dim] — last conv inputs
+    ssm: jnp.ndarray      # [Lm, S, n_heads, d_head, d_state]
+
+
+def mamba_block(cfg, fam: Family, p, x, rec: RecurrentState, li, slots,
+                n_valid, proj=None):
+    """One Mamba-2 block on `x` [b, s, h], from and to the rows' state
+    in `rec`: layer `li` (an int32 scalar) of slots `slots` ([b] int32;
+    None where x's rows are all the slots, in order). `n_valid` [b]
+    says how many of a row's `s` tokens count: the rest are padding,
+    and a row with none stands still (its state is written back bit
+    for bit, or, where `slots` names it, not written at all: a slot
+    listed twice has its real row in the same scatter).
+    -> (x, rec), the state after the valid tokens, written in place.
+
+    The recurrence runs in float32 whatever the state is stored in
+    (`ops/ssd.py`); one token a row takes the direct update (scope
+    `ssm_update`, a decode step), a slice the chunked form (`ssd_scan`);
+    each scope holds the state's read and its write. The other scopes:
+    `ssm_proj` (the projections in and out), `short_conv` (with its
+    tail's read and write), `norm`; the feed-forward half is
+    `mlp_half`, the attention block's own."""
+    if proj is None:
+        proj = _plain_proj(cfg)
+    b, s = x.shape[:2]
+    nh, hd = cfg.mamba_n_heads, cfg.mamba_d_head
+    ng, ns = cfg.mamba_n_groups, cfg.mamba_d_state
+    f32 = jnp.float32
+    if slots is None:
+        def read(state):
+            return state[li]
+
+        def write(state, new):
+            return state.at[li].set(new.astype(state.dtype))
+    else:
+        put = jnp.where(n_valid > 0, slots, rec.ssm.shape[1])
+
+        def read(state):
+            return state[li, slots]
+
+        def write(state, new):
+            return state.at[li, put].set(new.astype(state.dtype),
+                                         mode="drop")
+
+    h = rms_norm(x, p["ssm_norm"], cfg.norm_eps)
+    with jax.named_scope("ssm_proj"):
+        z, xbc, dt = (proj(name, h, p[name])
+                      for name in ("w_z", "w_xbc", "w_dt"))
+    with jax.named_scope("short_conv"):
+        xbc, tail = causal_conv(xbc, read(rec.conv), p["conv_w"],
+                                p["conv_b"], n_valid)
+        conv = write(rec.conv, tail)
+        xbc = jax.nn.silu(xbc)
+    xs = xbc[..., :cfg.d_inner].reshape(b, s, nh, hd)
+    bm = xbc[..., cfg.d_inner:cfg.d_inner + ng * ns].reshape(b, s, ng, ns)
+    cm = xbc[..., cfg.d_inner + ng * ns:].reshape(b, s, ng, ns)
+    valid = jnp.arange(s)[None, :] < n_valid[:, None]
+    dt = jnp.where(
+        valid[..., None],
+        jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32)), 0.0)
+    a = -jnp.exp(p["A_log"].astype(f32))
+    if s == 1:
+        with jax.named_scope("ssm_update"):
+            y, state = ssd_step(xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
+                                p["D"], read(rec.ssm))
+            y, ssm = y[:, None], write(rec.ssm, state)
+    else:
+        with jax.named_scope("ssd_scan"):
+            y, state = ssd_chunked(xs, dt, a, bm, cm, p["D"], read(rec.ssm))
+            ssm = write(rec.ssm, state)
+    with jax.named_scope("norm"):
+        # gated RMSNorm over the whole inner width, the gate before
+        # the norm, in float32
+        y = (y.astype(cfg.dtype).reshape(b, s, cfg.d_inner).astype(f32)
+             * jax.nn.silu(z.astype(f32)))
+    y = rms_norm(y, p["gate_norm"], cfg.norm_eps).astype(cfg.dtype)
+    with jax.named_scope("ssm_proj"):
+        x = x + _residual(fam, proj("w_out", y, p["w_out"]))
+    return mlp_half(cfg, fam, p, x, proj), RecurrentState(conv, ssm)
+
+
+def _layer_plan(kinds: tuple[str, ...]):
+    """-> (periods, runs): the shortest pattern `kinds` repeats, as
+    runs of one kind [(kind, count), ...], and how often it repeats."""
+    n = len(kinds)
+    period = next(p for p in range(1, n + 1)
+                  if n % p == 0 and kinds == kinds[:p] * (n // p))
+    runs: list[list] = []
+    for kind in kinds[:period]:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
         else:
-            gate = fam.gate_act(proj("w_gate", h, p["w_gate"]))
-            ff = gate * proj("w_up", h, p["w_up"])
-            x = x + proj("w_down", ff, p["w_down"])
-    return x, (k_cache, v_cache)
+            runs.append([kind, 1])
+    return n // period, [tuple(r) for r in runs]
+
+
+def scan_layers(cfg, fam: Family, params, carry, attention_layer,
+                mamba_layer=None, adapters=None):
+    """Every block of the model over `carry`, in model order, as loops
+    and not as unrolled layers. `attention_layer(carry, (p, [ab,] li))`
+    and `mamba_layer(carry, (p, li))` are scan bodies: `p` is one
+    layer's parameters and `li` its index in its own kind's stack
+    (which is the layer axis of that kind's cache). -> the carry.
+
+    Where every layer attends this is the one `lax.scan` over
+    `params["blocks"]` the serving paths always ran. With
+    `fam.layer_kinds` it is a scan over the pattern's periods, and
+    inside a period a scan over each run of one kind, every layer's
+    parameters indexed out of its kind's stack where they lie."""
+    if fam.layer_kinds is None:
+        ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        xs = ((params["blocks"], ids) if adapters is None
+              else (params["blocks"], adapters, ids))
+        return jax.lax.scan(attention_layer, carry, xs)[0]
+    periods, runs = _layer_plan(fam.layer_kinds)
+    stacks = {"attention": params["blocks"],
+              "mamba": params.get("mamba_blocks")}
+    bodies = {"attention": attention_layer, "mamba": mamba_layer}
+    per_period = {kind: sum(c for k, c in runs if k == kind)
+                  for kind in bodies}
+
+    def one(carry, kind, li):
+        p = jax.tree.map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, li, 0, keepdims=False),
+            stacks[kind])
+        return bodies[kind](carry, (p, li))[0]
+
+    def period(carry, pi):
+        seen = dict.fromkeys(bodies, 0)
+        for kind, count in runs:
+            first = pi * per_period[kind] + seen[kind]
+            if count == 1:
+                carry = one(carry, kind, first)
+            else:
+                carry, _ = jax.lax.scan(
+                    lambda c, j, kind=kind, first=first:
+                        (one(c, kind, first + j), None),
+                    carry, jnp.arange(count, dtype=jnp.int32))
+            seen[kind] += count
+        return carry, None
+
+    if periods == 1:
+        return period(carry, jnp.int32(0))[0]
+    return jax.lax.scan(period, carry,
+                        jnp.arange(periods, dtype=jnp.int32))[0]
 
 
 class InferenceEngine:
@@ -284,6 +490,18 @@ class InferenceEngine:
 
     # -- model internals ---------------------------------------------------
 
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep K and V: the layer axis of every KV cache."""
+        kinds = self.family.layer_kinds
+        return (self.cfg.num_layers if kinds is None
+                else kinds.count("attention"))
+
+    @property
+    def mamba_layers(self) -> int:
+        """Layers that keep a recurrent state a slot; 0 for most models."""
+        return (self.family.layer_kinds or ()).count("mamba")
+
     @jax.named_scope("embed")
     def _embed(self, params, tokens):
         cfg = self.cfg
@@ -293,13 +511,18 @@ class InferenceEngine:
         x = embed_lookup(params["embed"], tokens, cfg.dtype)
         if self.family.scale_embed:
             x = x * jnp.asarray(cfg.hidden_size ** 0.5, cfg.dtype)
+        if self.family.embed_multiplier != 1.0:
+            x = x * jnp.asarray(self.family.embed_multiplier, cfg.dtype)
         return x
 
     @jax.named_scope("head")
     def _head(self, params, x):
         tied = "lm_head" not in params
         head = params["embed"].T if tied else params["lm_head"]
-        return x.astype(jnp.float32) @ head.astype(jnp.float32)
+        logits = x.astype(jnp.float32) @ head.astype(jnp.float32)
+        if self.family.logits_scaling != 1.0:
+            logits = logits / self.family.logits_scaling
+        return logits
 
     def _forward_cached(self, params, tokens, state: DecodeState, *,
                         prompt_mask=None, return_all: bool = False,
@@ -319,6 +542,11 @@ class InferenceEngine:
         `params` is threaded as an argument, never closed over — see
         the constructor note on compile-time cost."""
         cfg, fam = self.cfg, self.family
+        if fam.recurrent:
+            raise NotImplementedError(
+                f"{fam.name} has recurrent layers, whose state the dense "
+                "cache does not hold: serve it through ContinuousBatcher "
+                "(a recurrent state per slot beside the paged pool)")
         b, s = tokens.shape
         start = state.length
         # Slot positions order the cache for causal masking; rope gets
@@ -420,7 +648,7 @@ class InferenceEngine:
         if cells is None:
             cells = self.ec.max_len
         itemsize = jnp.dtype(cfg.dtype).itemsize
-        return (2 * cfg.num_layers * batch * cells
+        return (2 * self.kv_layers * batch * cells
                 * cfg.num_kv_heads * cfg.head_dim * itemsize)
 
     @jax.named_scope("sample")

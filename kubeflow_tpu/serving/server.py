@@ -165,6 +165,17 @@ class ServingObs:
             "serving_kv_blocks_in_use",
             "KV pool blocks held by active requests plus the radix "
             "prefix cache, per model", self.registry)
+        self.ssm_state_bytes = Gauge(
+            "serving_ssm_state_bytes",
+            "Recurrent-state bytes of the slots that hold a request "
+            "(a model with Mamba layers keeps a state per slot beside "
+            "the paged pool; 0 for every other model), per model",
+            self.registry)
+        self.ssm_state_resets = Counter(
+            "serving_ssm_state_resets_total",
+            "Slots adopted with their recurrent state zeroed: one per "
+            "admission, a preempted request's replay included",
+            self.registry)
         self.prefill_tokens = obs_lib.get_or_create_histogram(
             self.registry, "serving_prefill_tokens",
             "Per-admission prompt tokens by source: computed (suffix "
@@ -999,6 +1010,11 @@ def create_serving_app(engines: dict[str, InferenceEngine],
             for _m, _b in app[BATCHERS_KEY].items():
                 if isinstance(_b, ContinuousBatcher):
                     sobs.kv_blocks.set(_b.kv_blocks_in_use(), model=_m)
+                    sobs.ssm_state_bytes.set(_b.ssm_state_bytes(), model=_m)
+                    # a counter can only inc: the delta since last scrape
+                    sobs.ssm_state_resets.inc(
+                        _b.state_resets
+                        - sobs.ssm_state_resets.value(model=_m), model=_m)
                     tier = _b._spill_tier
                     sobs.kv_spill_bytes.set(
                         tier.spilled_bytes if tier is not None else 0,

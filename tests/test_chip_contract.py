@@ -261,7 +261,8 @@ def test_paged_xla_never_counts_a_pallas_impl(monkeypatch):
     """`impl="xla"` used to end in `dot_product_attention(impl="auto")`,
     which on TPU hands a single-token step over >= 256 cells to the
     Pallas decode kernel."""
-    args = _paged_inputs()
+    # heads of 128: "auto" sends other sizes to XLA on the chip too
+    args = _paged_inputs(hd=128)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     attention.reset_impl_counts()
     jax.eval_shape(lambda *a: attention.paged_attention(*a, impl="xla"),
@@ -272,7 +273,7 @@ def test_paged_xla_never_counts_a_pallas_impl(monkeypatch):
     # the same shapes under "auto" do go to the kernels on TPU
     attention.reset_impl_counts()
     q, kp, vp, table, qpos, kvpos = args
-    k = kp[table].reshape(2, 256, 2, 16)
+    k = kp[table].reshape(2, 256, 2, 128)
     jax.eval_shape(lambda q, k: attention.dot_product_attention(
         q, k, k, qpos, kvpos, contiguous_positions=True), q, k)
     jax.eval_shape(lambda *a: attention.paged_attention(*a, impl="auto"),
@@ -334,6 +335,28 @@ def test_auto_is_a_rule_on_platform_and_shape(monkeypatch):
         "auto", vmem_bytes=too_big) == "xla"
     assert attention.resolve_paged_prefill_impl(
         "pallas", vmem_bytes=too_big) == "pallas"     # said explicitly
+
+
+@pytest.mark.parametrize("head_dim,auto", [
+    (128, "pallas"),     # mistral-7b.steady's heads: as before
+    (256, "pallas"),
+    (None, "pallas"),    # not said: only the platform is judged
+    (64, "xla"),         # granite-4.0-h-micro's: half a 128-lane tile
+    (192, "xla"),
+])
+def test_auto_takes_xla_for_heads_the_paged_kernels_do_not_copy(
+        monkeypatch, head_dim, auto):
+    """The compiled paged kernels copy pool blocks in whole 128-lane
+    tiles and raise on any other head size (inside a trace): "auto"
+    answers from the shape instead, on the chip too."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention.resolve_paged_attention_impl(
+        "auto", head_dim=head_dim) == auto
+    assert attention.resolve_paged_prefill_impl(
+        "auto", head_dim=head_dim) == auto
+    # said explicitly it stays what was said
+    assert attention.resolve_paged_attention_impl(
+        "pallas", head_dim=head_dim) == "pallas"
 
 
 def test_no_implementation_is_chosen_by_catching_an_error():

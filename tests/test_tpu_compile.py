@@ -154,6 +154,65 @@ def test_qkv_weights_are_read_where_they_lie(one_chip, case):
     assert not relaid, relaid
 
 
+def test_a_recurrent_state_is_updated_where_it_lies(one_chip):
+    """`granite-4.0-h-micro.decode-heavy`'s decode program at the cell's
+    widths and slots (one period of the layer pattern, a pool of two
+    slots' blocks: neither changes a layer's body): the slots' state, 67
+    MB a Mamba layer and 2.47 GB in all, rides the layer loops as a
+    donated carry and is read and written in place. A `copy` of the
+    stack, or of one layer's `[64, 64, 64, 128]`, would be a pass over
+    it that the update does not need."""
+    from benchmarks import harness
+    from benchmarks.models import granite_hybrid as model
+    from kubeflow_tpu.models import granite_hybrid
+    from kubeflow_tpu.serving import engine as engine_lib
+    from kubeflow_tpu.serving.continuous import ContinuousEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = harness.load_cell(
+        root, "granite-4.0-h-micro.decode-heavy").config
+    cfg = model.program_config(
+        dict(config, layer_types=config["layer_types"][:10]))
+    batcher = config["batcher"]
+    slots, block = batcher["max_slots"], batcher["kv_block_size"]
+
+    def described(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    def of(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
+    params = described(jax.eval_shape(
+        lambda k: granite_hybrid.init(k, cfg), rng))
+    sp = engine_lib.SamplingParams(
+        of((slots,), jnp.float32), of((slots,)), of((slots,), jnp.float32))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            force_interpret(False):
+        ce = ContinuousEngine(
+            engine_lib.InferenceEngine(
+                params, cfg, engine_lib.granite_hybrid_family(cfg),
+                engine_lib.EngineConfig(**config["engine"])),
+            max_slots=slots, block_size=block,
+            num_blocks=1 + 2 * config["engine"]["max_len"] // block)
+        # heads of 64: the resolver answers from the shape
+        assert (ce.attention_impl, ce.prefill_impl) == ("xla", "xla")
+        st = described(jax.eval_shape(ce.init_slots))
+        text = ce._step_jit.lower(
+            params, None, st, sp, rng, steps=4).compile().as_text()
+    heads, hd, state = (cfg.mamba_n_heads, cfg.mamba_d_head,
+                        cfg.mamba_d_state)
+    assert st.rec.ssm.shape == (9, slots, heads, hd, state)
+    # the stack is a parameter and a result of the program, aliased
+    assert re.search(
+        rf"bf16\[9,{slots},{heads},{hd},{state}\]\S* parameter\(", text)
+    copies = [line.strip()[:160] for line in text.splitlines() if re.search(
+        rf"= (bf16|f32)\[(9,)?{slots},{heads},{hd},{state}\]\S* copy\(",
+        line)]
+    assert not copies, copies
+
+
 @pytest.mark.slow
 def test_the_kimi_step_fits_a_v5e(chip):
     """`kimi-linear-48b.train-8k`'s step as the benchmark builds it, at
