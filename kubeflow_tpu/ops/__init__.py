@@ -11,6 +11,7 @@ from kubeflow_tpu.ops.attention import (
     dot_product_attention,
     paged_attention,
     paged_prefill_attention,
+    pool_cell_shape,
     resolve_paged_attention_impl,
     resolve_paged_prefill_impl,
 )

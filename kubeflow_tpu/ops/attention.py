@@ -89,12 +89,13 @@ def _check_impl(impl: str, what: str) -> None:
 
 
 def layered_pool(k_pool, v_pool, layer):
-    """The paged entries' pool as `[L, num_blocks, block_size, n_kv,
-    hd]` plus an int32 scalar layer. What decides is the pool's rank: a
-    rank-5 pool (every layer's, the serving engines' scan carry) comes
-    with the layer to read; a rank-4 pool is one layer's and is lifted
-    to `[1, ...]` at layer 0 — a bitcast, so both reach one kernel
-    body. -> (k_pool, v_pool, layer)."""
+    """The paged entries' pool as `[L, num_blocks, block_size, *cell]`
+    plus an int32 scalar layer (`cell` is a token's K, or V, of one
+    layer, in one of `pool_cell_shape`'s forms). What decides is the
+    pool's rank: a rank-5 pool (every layer's, the serving engines'
+    scan carry) comes with the layer to read; a rank-4 pool is one
+    layer's and is lifted to `[1, ...]` at layer 0 — a bitcast, so
+    both reach one kernel body. -> (k_pool, v_pool, layer)."""
     if k_pool.shape != v_pool.shape:
         raise ValueError(
             f"k_pool/v_pool shapes disagree: {k_pool.shape} vs "
@@ -104,8 +105,8 @@ def layered_pool(k_pool, v_pool, layer):
     if k_pool.ndim == 5 and layer is not None:
         return k_pool, v_pool, jnp.asarray(layer, jnp.int32)
     raise ValueError(
-        "a pool [L, num_blocks, block_size, n_kv, hd] comes with a "
-        "layer, a pool [num_blocks, block_size, n_kv, hd] without one; "
+        "a pool [L, num_blocks, block_size, *cell] comes with a "
+        "layer, a pool [num_blocks, block_size, *cell] without one; "
         f"got pools {k_pool.shape} / {v_pool.shape} and layer "
         + ("None" if layer is None
            else f"of shape {jnp.shape(layer)}"))
@@ -117,6 +118,37 @@ def _kernel_copies_heads_of(head_dim: int | None) -> bool:
     raise on any other (ops/pallas/paged_attention.py). A caller that
     does not say the size is not judged by it."""
     return head_dim is None or head_dim % 128 == 0
+
+
+def pool_cell_shape(n_kv: int, head_dim: int) -> tuple[int, int]:
+    """The form `(rows, lanes)` of one KV pool cell: a token's K, or V,
+    of one layer, the two minor dimensions of the pool
+    `[L, num_blocks, block_size, rows, lanes]`. Heads the paged kernels
+    copy (a multiple of 128: whole lane tiles) lie a head a row,
+    `(n_kv, head_dim)`. Any other size would leave part of every
+    128-lane tile empty, and the chip then rests the pool in a layout of
+    its own (the block index minor) which no program works on: each
+    copied the pool whole on entry, on exit and around every scatter
+    (PERF.md, PR 37). So there a cell's heads lie side by side in one
+    row, `(1, n_kv * head_dim)`: the same bytes in the same order. The
+    one place that decides the form; the paged entries below take
+    either, from the shapes."""
+    if _kernel_copies_heads_of(head_dim):
+        return n_kv, head_dim
+    return 1, n_kv * head_dim
+
+
+def _split_heads(pool, head_dim: int):
+    """A pool `[L, num_blocks, block_size, *cell]` in either form of
+    its cell -> (n_kv, the pool's shape with the cell as
+    `[n_kv, head_dim]`)."""
+    rows, lanes = pool.shape[3:]
+    if (rows * lanes) % head_dim:
+        raise ValueError(
+            f"a pool cell {pool.shape[3:]} does not hold whole heads of "
+            f"{head_dim} (pool {pool.shape})")
+    n_kv = rows * lanes // head_dim
+    return n_kv, pool.shape[:3] + (n_kv, head_dim)
 
 
 def resolve_paged_prefill_impl(impl: str, *, vmem_bytes: int = 0,
@@ -268,8 +300,8 @@ def _attention(q, k, v, q_positions, kv_positions, *, causal, kv_mask,
 @jax.named_scope("paged_attention")
 def paged_attention(
     q: jnp.ndarray,            # [b, 1, n_q, hd] — single decode step
-    k_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
-    v_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
+    k_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, *cell]
+    v_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, *cell]
     block_table: jnp.ndarray,  # [b, blocks_per_slot] int32 physical ids
     q_positions: jnp.ndarray,  # [b, 1]
     kv_positions: jnp.ndarray, # [b, blocks_per_slot * block_size]
@@ -286,7 +318,9 @@ def paged_attention(
     The pool is one layer's (rank 4) or every layer's with `layer`
     saying which to read (rank 5; see `layered_pool`): a caller whose
     layers share one array hands it over whole, and neither impl
-    materialises the layer's slice.
+    materialises the layer's slice. A cell of it is `[n_kv, hd]` or
+    `[1, n_kv * hd]` (`pool_cell_shape`): `hd` is `q`'s, and `n_kv` is
+    what the cell holds of it.
 
     impl: "auto" | "xla" | "pallas".
 
@@ -322,7 +356,8 @@ def paged_attention(
             f"{block_table.shape}")
     k_pool, v_pool, layer = layered_pool(k_pool, v_pool, layer)
     blocks_per_slot = block_table.shape[1]
-    block_size, n_kv, hd = k_pool.shape[2:]
+    block_size, hd = k_pool.shape[2], q.shape[-1]
+    n_kv, split = _split_heads(k_pool, hd)
     width = blocks_per_slot * block_size
     # Geometry mismatches (a pool rebuilt with a different block_size
     # than the tables/masks were laid out for) used to surface as an
@@ -352,9 +387,14 @@ def paged_attention(
             paged_decode_attention,
         )
 
+        # the kernel takes a head a row: its own form where it compiles
+        # (heads of 128), a view of the rows where a test interprets it
+        # at smaller heads
         return paged_decode_attention(
-            q, k_pool, v_pool, block_table, q_positions[:, 0],
-            kv_mask, layer=layer, window=window, interpret=interpret)
+            q, k_pool.reshape(split), v_pool.reshape(split), block_table,
+            q_positions[:, 0], kv_mask, layer=layer, window=window,
+            interpret=interpret)
+    # the gathered window is split into heads, never the pool
     k = k_pool[layer, block_table].reshape(b, width, n_kv, hd)
     v = v_pool[layer, block_table].reshape(b, width, n_kv, hd)
     # impl="xla" said explicitly: "auto" would hand this single-token
@@ -371,8 +411,8 @@ def paged_prefill_attention(
     q: jnp.ndarray,            # [b, s, n_q, hd] — s new tokens per row
     k_new: jnp.ndarray,        # [b, s, n_kv, hd]
     v_new: jnp.ndarray,        # [b, s, n_kv, hd]
-    k_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
-    v_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, n_kv, hd]
+    k_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, *cell]
+    v_pool: jnp.ndarray,       # [(L,) num_blocks, block_size, *cell]
     block_table: jnp.ndarray,  # [b, blocks_per_slot] int32 physical ids
     q_start: jnp.ndarray,      # [b] int32 — append cursor per row
     q_lens: jnp.ndarray | None = None,  # [b] int32 — valid new tokens
@@ -387,10 +427,11 @@ def paged_prefill_attention(
     them against everything written so far. Returns
     `(out [b, s, n_q, hd], k_pool, v_pool)` — the serving primitive
     behind chunked prefill (the chunk's tokens) and speculative verify
-    (the γ+1 draft-window tokens). The pools come back in the rank
-    they were given: with a rank-5 pool and `layer` (`layered_pool`)
-    only layer `layer`'s visited blocks are written, and every other
-    byte of the array stays where it is.
+    (the γ+1 draft-window tokens). The pools come back in the shape
+    they were given (rank, and the cell's form: `[n_kv, hd]` or
+    `[1, n_kv * hd]`, `pool_cell_shape`): with a rank-5 pool and `layer`
+    (`layered_pool`) only layer `layer`'s visited blocks are written,
+    and every other byte of the array stays where it is.
 
     Row r's token t lands at logical cell `q_start[r] + t` (physical:
     through the row's block table) and attends causally by absolute
@@ -415,7 +456,8 @@ def paged_prefill_attention(
     b, s, n_q, hd = q.shape
     given = k_pool.shape
     k_pool, v_pool, layer = layered_pool(k_pool, v_pool, layer)
-    block_size, n_kv = k_pool.shape[2:4]
+    block_size = k_pool.shape[2]
+    n_kv, split = _split_heads(k_pool, hd)
     if block_table.ndim != 2 or block_table.shape[0] != b:
         raise ValueError(
             f"block_table must be [b={b}, blocks_per_slot], got "
@@ -439,10 +481,11 @@ def paged_prefill_attention(
     _impl_counts["paged_prefill"] += 1
     _impl_counts["paged_prefill_" + impl] += 1
     if impl == "pallas":
+        # the kernel takes a head a row (as `paged_attention`'s does)
         out, k_pool, v_pool = paged_prefill_append(
-            q, k_new, v_new, k_pool, v_pool, block_table,
-            q_start, q_lens, kv_mask, layer=layer, window=window,
-            interpret=interpret)
+            q, k_new, v_new, k_pool.reshape(split), v_pool.reshape(split),
+            block_table, q_start, q_lens, kv_mask, layer=layer,
+            window=window, interpret=interpret)
         return out, k_pool.reshape(given), v_pool.reshape(given)
     # XLA reference: scatter the new cells through the table (invalid
     # tokens to the trash block — the pool's garbage-write convention),
@@ -454,8 +497,11 @@ def paged_prefill_attention(
     blk = jnp.take_along_axis(block_table, safe // block_size, axis=1)
     blk = jnp.where(valid, blk, 0)
     off = safe % block_size
-    k_pool = k_pool.at[layer, blk, off].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[layer, blk, off].set(v_new.astype(v_pool.dtype))
+    cell = k_pool.shape[3:]
+    k_pool = k_pool.at[layer, blk, off].set(
+        k_new.reshape(b, s, *cell).astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, blk, off].set(
+        v_new.reshape(b, s, *cell).astype(v_pool.dtype))
     k = k_pool[layer, block_table].reshape(b, width, n_kv, hd)
     v = v_pool[layer, block_table].reshape(b, width, n_kv, hd)
     kv_positions = jnp.broadcast_to(

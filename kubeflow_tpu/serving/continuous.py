@@ -61,6 +61,7 @@ from kubeflow_tpu.ops.attention import (
     dot_product_attention,
     paged_attention,
     paged_prefill_attention,
+    pool_cell_shape,
     resolve_paged_attention_impl,
     resolve_paged_prefill_impl,
 )
@@ -119,8 +120,12 @@ class SlotState:
 
     def __init__(self, k, v, length, tok, aid=None,
                  block_table=None, frozen=None, rec=None):
-        self.k = k            # [L, num_blocks, block_size, n_kv, hd]
-        self.v = v            # (paged pool; block 0 is the trash block)
+        # the paged pool, [L, num_blocks, block_size, *cell]: block 0 is
+        # the trash block; a cell (one token's K, or V, of one layer) is
+        # [n_kv, hd] at heads of whole lane tiles and [1, n_kv * hd] at
+        # smaller ones (ops.attention.pool_cell_shape)
+        self.k = k
+        self.v = v
         self.length = length  # [S] int32 — filled cache cells per row
         self.tok = tok        # [S] int32 — last sampled token per row
         if aid is None:       # multi-LoRA adapter id (0 = plain base)
@@ -269,13 +274,16 @@ class ContinuousEngine:
             resolve_interpret(None)
         self.S = max_slots
         # Paged KV geometry. The cache is a POOL of fixed-size blocks
-        # [L, num_blocks, block_size, n_kv, hd] plus a per-slot block
-        # table; block 0 is the reserved trash block (unallocated table
-        # entries point there, so a retired-but-unreset slot's garbage
-        # writes land harmlessly). The default pool is the dense
+        # [L, num_blocks, block_size, *cell] (`init_slots`) plus a
+        # per-slot block table; block 0 is the reserved trash block
+        # (unallocated table entries point there, so a retired-but-unreset
+        # slot's garbage writes land harmlessly). The default pool is the dense
         # equivalent (every slot can hold max_len) — shrink num_blocks
         # to cap KV HBM below S * max_len when real requests are short.
         self.block_size = block_size
+        # a cell's form (rows, lanes) follows the head size
+        self.kv_cell = pool_cell_shape(engine.cfg.num_kv_heads,
+                                       engine.cfg.head_dim)
         self.blocks_per_slot = -(-engine.ec.max_len // block_size)
         self.kv_width = self.blocks_per_slot * block_size
         if num_blocks is None:
@@ -362,7 +370,7 @@ class ContinuousEngine:
     def init_slots(self) -> SlotState:
         cfg = self.engine.cfg
         shape = (self.engine.kv_layers, self.num_blocks, self.block_size,
-                 cfg.num_kv_heads, cfg.head_dim)
+                 *self.kv_cell)
         rec = None
         if self.recurrent:
             lm = self.engine.mamba_layers
@@ -441,23 +449,35 @@ class ContinuousEngine:
 
     # -- migration --------------------------------------------------------
 
+    def _wire_shape(self, n: int) -> tuple[int, ...]:
+        """`n` pool blocks as they leave and enter this engine: a head a
+        row, whichever form the pool keeps a cell in."""
+        cfg = self.engine.cfg
+        return (self.engine.kv_layers, n, self.block_size,
+                cfg.num_kv_heads, cfg.head_dim)
+
     def _export_blocks(self, k_pool, v_pool, ids):
-        return k_pool[:, ids], v_pool[:, ids]
+        wire = self._wire_shape(ids.shape[0])
+        return k_pool[:, ids].reshape(wire), v_pool[:, ids].reshape(wire)
 
     def export_blocks(self, st: SlotState, block_ids):
         """Host copies of the K/V payloads held by physical blocks
         `block_ids` — `(k, v)`, each `[L, n, block_size, n_kv, hd]`
-        numpy, in id order. The transfer unit of live sequence
-        migration (serving/migration.py): one device gather + one
-        transfer covers an arbitrary id list (one cheap compile per
-        list LENGTH). Does not touch the state."""
+        numpy, in id order: the wire form, a head a row whatever form
+        the pool keeps a cell in (the gathered blocks are reshaped, not
+        the pool). The transfer unit of live sequence migration
+        (serving/migration.py): one device gather + one transfer covers
+        an arbitrary id list (one cheap compile per list LENGTH). Does
+        not touch the state."""
         ids = jnp.asarray(list(block_ids), jnp.int32)
         k, v = self._export_jit(st.k, st.v, ids)
         return np.asarray(k), np.asarray(v)
 
     def _import_blocks(self, st: SlotState, ids, k, v):
-        kp = st.k.at[:, ids].set(k.astype(st.k.dtype))
-        vp = st.v.at[:, ids].set(v.astype(st.v.dtype))
+        # wire form -> the pool's cells, on the n blocks
+        blocks = st.k.shape[:1] + ids.shape + st.k.shape[2:]
+        kp = st.k.at[:, ids].set(k.reshape(blocks).astype(st.k.dtype))
+        vp = st.v.at[:, ids].set(v.reshape(blocks).astype(st.v.dtype))
         return st.replace(k=kp, v=vp)
 
     def import_blocks(self, st: SlotState, block_ids, k, v) -> SlotState:
@@ -465,13 +485,12 @@ class ContinuousEngine:
         blocks `block_ids` (donates `st` — in-place pool update, same
         policy as append/step). Payloads keep the exporter's canonical
         form (cell index == logical token position), so imported
-        blocks are immediately radix-shareable. Raises ValueError when
-        the payload shape disagrees with this pool's block geometry —
-        a silent shape coercion here would corrupt every sequence that
-        later seeds from these blocks."""
-        cfg = self.engine.cfg
-        want = (cfg.num_layers, len(list(block_ids)), self.block_size,
-                cfg.num_kv_heads, cfg.head_dim)
+        blocks are immediately radix-shareable. Payloads come in
+        `export_blocks`' wire form, `[L, n, block_size, n_kv, hd]`.
+        Raises ValueError when the payload shape disagrees with this
+        pool's block geometry — a silent shape coercion here would
+        corrupt every sequence that later seeds from these blocks."""
+        want = self._wire_shape(len(list(block_ids)))
         k = np.asarray(k)
         v = np.asarray(v)
         if tuple(k.shape) != want or tuple(v.shape) != want:
@@ -537,14 +556,17 @@ class ContinuousEngine:
 
             def write_kv(k, v):
                 # one [S]-row scatter into the shared block pool:
-                # slot s's token lands at (table[s, at//bs], at%bs).
+                # slot s's token lands at (table[s, at//bs], at%bs),
+                # its [n_kv, hd] row in the form of the pool's cell.
                 # The WHOLE pool goes on to the attention call: a
-                # layer's slice taken here would be a 268 MB copy a
-                # layer on the chip (PERF.md, PR 26)
+                # layer's slice taken here would be a copy of the
+                # layer's pool a layer on the chip (268 MB at Mistral's
+                # cell: PERF.md, PR 26)
+                cell = (S, *self.kv_cell)
                 return (k_all.at[li, write_blk, write_off].set(
-                            k[:, 0].astype(k_all.dtype)),
+                            k[:, 0].reshape(cell).astype(k_all.dtype)),
                         v_all.at[li, write_blk, write_off].set(
-                            v[:, 0].astype(v_all.dtype)))
+                            v[:, 0].reshape(cell).astype(v_all.dtype)))
 
             def attn(q, kp, vp):
                 # kp/vp are every layer's block POOL and `li` says
@@ -2397,6 +2419,8 @@ class ContinuousBatcher:
         # cell, which that step writes.
         stats = self.cengine.decode_kv_steps(
             len(r.kv_toks) - 1 for r in snap.values())
+        # the pool's minor dimension: the form its cells are kept in
+        stats["kv_cell_lanes"] = self.cengine.kv_cell[1]
         if self.cengine.recurrent:
             # what the step reads and writes once beside the KV walk
             stats["ssm_state_bytes"] = (

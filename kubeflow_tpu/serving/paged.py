@@ -1,7 +1,7 @@
 """Paged KV-cache bookkeeping: block pool + radix prefix cache.
 
 This module is pure host-side Python — no jax. The device arrays (the
-block pool itself, `[L, num_blocks, block_size, n_kv, hd]`) live inside
+block pool itself, `[L, num_blocks, block_size, *cell]`) live inside
 `ContinuousEngine`'s `SlotState`; here we only track which physical
 blocks are free, which are owned by an in-flight request, and which are
 retained by the radix tree for cross-request prefix reuse.

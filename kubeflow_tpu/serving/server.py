@@ -165,6 +165,12 @@ class ServingObs:
             "serving_kv_blocks_in_use",
             "KV pool blocks held by active requests plus the radix "
             "prefix cache, per model", self.registry)
+        self.kv_pool_cell_lanes = Gauge(
+            "serving_kv_pool_cell_lanes",
+            "Minor dimension of the KV pool: the head size where a "
+            "cell lies a head a row (heads of whole 128-lane tiles), "
+            "n_kv x head size where a cell's heads lie side by side "
+            "in one row (smaller heads), per model", self.registry)
         self.ssm_state_bytes = Gauge(
             "serving_ssm_state_bytes",
             "Recurrent-state bytes of the slots that hold a request "
@@ -1010,6 +1016,8 @@ def create_serving_app(engines: dict[str, InferenceEngine],
             for _m, _b in app[BATCHERS_KEY].items():
                 if isinstance(_b, ContinuousBatcher):
                     sobs.kv_blocks.set(_b.kv_blocks_in_use(), model=_m)
+                    sobs.kv_pool_cell_lanes.set(
+                        _b.cengine.kv_cell[1], model=_m)
                     sobs.ssm_state_bytes.set(_b.ssm_state_bytes(), model=_m)
                     # a counter can only inc: the delta since last scrape
                     sobs.ssm_state_resets.inc(

@@ -473,6 +473,8 @@ def test_decode_dispatch_span_carries_the_kv_steps():
         assert st["kv_blocks_live"] == 2
         assert st["kv_steps_fetching"] == 1
         assert st["kv_steps"] == ce.S == 2
+        # two KV heads of 32 side by side: the pool's minor dimension
+        assert st["kv_cell_lanes"] == ce.kv_cell[1] == 64
 
 
 def test_engine_rejects_bad_impl_name():
@@ -503,6 +505,8 @@ def test_server_exports_attention_impl_and_wires_tracer():
     assert b.cengine.attention_impl == "xla"  # CPU auto-resolution
     text = sobs.registry.render()
     assert 'serving_attention_impl{impl="xla",model="m"} 1' in text
+    # the form the pool keeps a cell in: two heads of 32 in one row
+    assert 'serving_kv_pool_cell_lanes{model="m"} 64' in text
     # the knob is continuous-only, like the rest of the paged config
     with pytest.raises(ValueError, match="paged_attention_impl"):
         create_serving_app({"m": engine},

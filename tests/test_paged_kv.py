@@ -19,7 +19,10 @@ import pytest
 pytest_plugins = ("aiohttp.pytest_plugin",)
 
 from kubeflow_tpu.models import gemma, llama
-from kubeflow_tpu.ops import dot_product_attention, paged_attention
+from kubeflow_tpu.ops import (
+    dot_product_attention, paged_attention, paged_prefill_attention,
+    pool_cell_shape,
+)
 from kubeflow_tpu.serving import (
     EngineConfig, GEMMA_FAMILY, InferenceEngine, LLAMA_FAMILY,
 )
@@ -163,6 +166,73 @@ def test_paged_attention_matches_dense_layout():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def test_a_pool_cell_is_whole_lane_tiles():
+    """Heads the paged kernels copy lie a head a row; any other size
+    lies side by side in one row, so no 128-lane tile is part empty."""
+    assert pool_cell_shape(8, 128) == (8, 128)      # Mistral
+    assert pool_cell_shape(8, 256) == (8, 256)
+    assert pool_cell_shape(8, 64) == (1, 512)       # granite
+    assert pool_cell_shape(2, 32) == (1, 64)        # the tiny models
+    assert pool_cell_shape(1, 192) == (1, 192)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("hd", [16, 64])
+@pytest.mark.parametrize("entry", ["decode", "prefill"])
+def test_paged_entries_take_either_form_of_a_cell(entry, hd, impl):
+    """A pool `[L, nb, bs, n_kv, hd]` and the same bytes as
+    `[L, nb, bs, 1, n_kv * hd]` are one pool to both paged entries: the
+    outputs are bit-identical, and so are the pools an append returns
+    (each in the form it was given)."""
+    rng = np.random.default_rng(hd)
+    layers, b, n_q, n_kv, bs, mb, s = 2, 2, 4, 2, 4, 3, 3
+    width, num_blocks = mb * bs, 1 + b * mb
+    split = (layers, num_blocks, bs, n_kv, hd)
+    merged = (layers, num_blocks, bs, 1, n_kv * hd)
+    k_pool = jnp.asarray(rng.standard_normal(split), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal(split), jnp.float32)
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, num_blocks)).reshape(b, mb), jnp.int32)
+    cursors = jnp.asarray([7, 2], jnp.int32)
+    layer = jnp.int32(1)
+    q, kn, vn = (jnp.asarray(rng.standard_normal(
+        (b, 1 if entry == "decode" else s, n, hd)), jnp.float32)
+        for n in (n_q, n_kv, n_kv))
+    kv_pos = jnp.tile(jnp.arange(width, dtype=jnp.int32)[None], (b, 1))
+
+    def run(form):
+        kp, vp = k_pool.reshape(form), v_pool.reshape(form)
+        if entry == "decode":
+            return (paged_attention(
+                q, kp, vp, table, cursors[:, None], kv_pos, causal=True,
+                layer=layer, impl=impl, interpret=True),)
+        return paged_prefill_attention(
+            q, kn, vn, kp, vp, table, cursors, jnp.asarray([s, 1]),
+            layer=layer, impl=impl, interpret=True)
+
+    want, got = run(split), run(merged)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    for g, w in zip(got[1:], want[1:]):
+        assert g.shape == merged and w.shape == split
+        np.testing.assert_array_equal(
+            np.asarray(g).reshape(split), np.asarray(w))
+    if entry == "prefill":
+        # and the append did write: layer 1 differs from what it was
+        assert not np.array_equal(np.asarray(want[1][1]),
+                                  np.asarray(k_pool[1]))
+        np.testing.assert_array_equal(np.asarray(want[1][0]),
+                                      np.asarray(k_pool[0]))
+
+
+def test_a_cell_that_holds_no_whole_heads_is_refused():
+    q = jnp.zeros((1, 1, 4, 16), jnp.float32)
+    pool = jnp.zeros((1, 3, 4, 1, 24), jnp.float32)
+    with pytest.raises(ValueError, match="whole heads of 16"):
+        paged_attention(q, pool, pool, jnp.zeros((1, 2), jnp.int32),
+                        jnp.zeros((1, 1), jnp.int32),
+                        jnp.zeros((1, 8), jnp.int32), layer=0)
+
+
 def test_continuous_engine_block_validation():
     engine, _ = _llama_engine()
     with pytest.raises(ValueError):
@@ -226,9 +296,16 @@ def test_step_programs_take_no_layer_slice_of_the_pool(program, impl):
             jnp.zeros((1, 4), jnp.int32), jnp.asarray([4]),
             jnp.asarray([0])))(st)
 
+    # a layer of the pool in the form it is kept in, or a head a row as
+    # the kernel is handed it
+    assert st.k.shape[1:] == (ce.num_blocks, 8, 1,
+                              cfg.num_kv_heads * cfg.head_dim)
+    forms = (st.k.shape[1:],
+             st.k.shape[1:3] + (cfg.num_kv_heads, cfg.head_dim))
+
     def a_layer(var):
         shape = var.aval.shape
-        return (shape[-4:] == st.k.shape[1:]
+        return (shape[-4:] in forms
                 and int(np.prod(shape[:-4])) == 1)
 
     names = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
@@ -417,3 +494,39 @@ def test_import_blocks_geometry_guard_and_roundtrip():
     # block-count mismatch between ids and payload
     with pytest.raises(ValueError, match="pool block geometry"):
         ce.import_blocks(st, [1], k, v)
+
+
+def test_blocks_leave_and_enter_a_merged_pool_in_the_wire_form():
+    """The tiny model's heads of 32 keep a pool cell as one row of
+    `n_kv * hd`; blocks still leave and enter as `[L, n, bs, n_kv, hd]`
+    (what migration, the spill tier and a peer of another build hold),
+    land in the cells the programs read, and a payload in the pool's
+    own form is another geometry: refused."""
+    engine, cfg = _llama_engine()
+    ce = ContinuousEngine(engine, max_slots=2, block_size=8)
+    n_kv, hd = cfg.num_kv_heads, cfg.head_dim
+    assert ce.kv_cell == (1, n_kv * hd)
+    st = ce.init_slots()
+    assert st.k.shape == st.v.shape == (
+        cfg.num_layers, ce.num_blocks, 8, 1, n_kv * hd)
+    rng = np.random.default_rng(4)
+    wire = (cfg.num_layers, 2, 8, n_kv, hd)
+    k = rng.standard_normal(wire).astype(np.float32)
+    v = rng.standard_normal(wire).astype(np.float32)
+    st = ce.import_blocks(st, [3, 1], k, v)
+    assert st.k.shape == (cfg.num_layers, ce.num_blocks, 8, 1, n_kv * hd)
+    # head h of a token lies at lanes [h * hd, (h + 1) * hd) of its cell
+    np.testing.assert_array_equal(
+        np.asarray(st.k[:, 3, :, 0, hd:2 * hd]), k[:, 0, :, 1])
+    np.testing.assert_array_equal(
+        np.asarray(st.v[:, 1, :, 0, :hd]), v[:, 1, :, 0])
+    assert not np.asarray(st.k[:, 2]).any()         # untouched
+    got_k, got_v = ce.export_blocks(st, [3, 1])
+    assert got_k.shape == got_v.shape == wire
+    np.testing.assert_array_equal(got_k, k)
+    np.testing.assert_array_equal(got_v, v)
+    merged = (cfg.num_layers, 2, 8, 1, n_kv * hd)
+    with pytest.raises(ValueError, match="pool block geometry"):
+        ce.import_blocks(st, [3, 1], k.reshape(merged), v.reshape(merged))
+    with pytest.raises(ValueError, match="pool block geometry"):
+        ce.import_blocks(st, [3, 1], k[..., :hd // 2], v[..., :hd // 2])

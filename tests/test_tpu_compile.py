@@ -14,6 +14,8 @@ marked `slow`.
 
 from __future__ import annotations
 
+import functools
+import math
 import os
 import re
 from unittest import mock
@@ -78,22 +80,50 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, shape):
         assert kernel in text
 
 
-def compile_serving_step(sharding, case):
-    """One of `mistral-7b.steady`'s two step programs, compiled from
-    shapes alone: the cell's widths, slots, block size and prefill
-    slice; depth 2 and a pool of two slots' blocks, which change
-    neither program's body. -> (the compiled text, the model's config)."""
-    from benchmarks import harness
+def mistral_model(config):
+    """`mistral-7b.steady`'s model cut to depth 2, which changes neither
+    step program's body. -> (config, parameters' init, family)."""
     from benchmarks.models import llama as model
     from kubeflow_tpu.models import llama
+    from kubeflow_tpu.serving import engine as engine_lib
+
+    cfg = model.program_config(dict(config, num_hidden_layers=2))
+    return cfg, llama.init, engine_lib.LLAMA_FAMILY
+
+
+def granite_model(config, *, periods):
+    """`granite-4.0-h-micro.decode-heavy`'s model cut to `periods` of its
+    layer pattern (nine Mamba layers and an attention layer each), which
+    changes no layer's body. -> as `mistral_model`."""
+    from benchmarks.models import granite_hybrid as model
+    from kubeflow_tpu.models import granite_hybrid
+    from kubeflow_tpu.serving import engine as engine_lib
+
+    cfg = model.program_config(dict(
+        config, layer_types=config["layer_types"][:10 * periods]))
+    return cfg, granite_hybrid.init, engine_lib.granite_hybrid_family(cfg)
+
+
+def compile_serving_step(sharding, cell, build, case, *, steps,
+                         num_blocks=None):
+    """One of a serving cell's two step programs (`case`: "decode-step",
+    `steps` decode steps a dispatch, or "prefill-slice"), compiled from
+    shapes alone: the cell's widths, slots, block size and prefill
+    slice, the model as `build` cuts it, and a pool of `num_blocks` (two
+    slots' blocks where not given, which changes neither program's
+    body). -> (the compiled program, the model's config, the
+    `ContinuousEngine`, the slots' state as shapes)."""
+    from benchmarks import harness
     from kubeflow_tpu.serving import engine as engine_lib
     from kubeflow_tpu.serving.continuous import ContinuousEngine
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    config = harness.load_cell(root, "mistral-7b.steady").config
-    cfg = model.program_config(dict(config, num_hidden_layers=2))
+    config = harness.load_cell(root, cell).config
+    cfg, init, family = build(config)
     batcher = config["batcher"]
     slots, block = batcher["max_slots"], batcher["kv_block_size"]
+    if num_blocks is None:
+        num_blocks = 1 + 2 * config["engine"]["max_len"] // block
 
     def described(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
@@ -103,29 +133,29 @@ def compile_serving_step(sharding, case):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     rng = described(jax.eval_shape(lambda: jax.random.key(0)))
-    params = described(jax.eval_shape(lambda k: llama.init(k, cfg), rng))
+    params = described(jax.eval_shape(lambda k: init(k, cfg), rng))
     sp = engine_lib.SamplingParams(
         of((slots,), jnp.float32), of((slots,)), of((slots,), jnp.float32))
-    # "auto" picks the Pallas kernels where the backend is a TPU, and
-    # the chip's programs hold them compiled, where the suite runs them
-    # interpreted (conftest.py)
+    # "auto" picks the Pallas kernels where the backend is a TPU and the
+    # heads are a size they copy, and the chip's programs hold them
+    # compiled, where the suite runs them interpreted (conftest.py)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
             force_interpret(False):
         ce = ContinuousEngine(
             engine_lib.InferenceEngine(
-                params, cfg, engine_lib.LLAMA_FAMILY,
+                params, cfg, family,
                 engine_lib.EngineConfig(**config["engine"])),
-            max_slots=slots, block_size=block,
-            num_blocks=1 + 2 * config["engine"]["max_len"] // block)
+            max_slots=slots, block_size=block, num_blocks=num_blocks)
         st = described(jax.eval_shape(ce.init_slots))
         if case == "decode-step":
-            lowered = ce._step_jit.lower(params, None, st, sp, rng, steps=4)
+            lowered = ce._step_jit.lower(
+                params, None, st, sp, rng, steps=steps)
         else:
             lowered = ce._append_jit.lower(
                 params, None, st, of((1,)),
                 of((1, batcher["prefill_chunk_tokens"])), of((1,)),
                 of((1,), jnp.bool_), sp, rng)
-        return lowered.compile().as_text(), cfg
+        return lowered.compile(), cfg, ce, st
 
 
 @pytest.mark.parametrize("case", ["decode-step", "prefill-slice"])
@@ -136,9 +166,13 @@ def test_qkv_weights_are_read_where_they_lie(one_chip, case):
     before PR 35), the decode program transposed the three stacks whole
     once a dispatch and both programs copied a transposed slice a layer
     before the product could start (PERF.md section 6, PR 35)."""
-    text, cfg = compile_serving_step(one_chip, case)
+    compiled, cfg, ce, st = compile_serving_step(
+        one_chip, "mistral-7b.steady", mistral_model, case, steps=4)
+    text = compiled.as_text()
     layers, d = cfg.num_layers, cfg.hidden_size
     kv = cfg.num_kv_heads * cfg.head_dim
+    # heads of 128: a pool cell lies a head a row, as the kernels take it
+    assert st.k.shape == st.v.shape == (layers, ce.num_blocks, 64, 8, 128)
     assert "tpu_custom_call" in text        # the Pallas kernels are in it
     # the parameters' names and layout, which the rest reads by
     assert re.search(rf"%params__blocks____wq__\S* = bf16\[{layers},{d},{d}\]"
@@ -162,45 +196,13 @@ def test_a_recurrent_state_is_updated_where_it_lies(one_chip):
     donated carry and is read and written in place. A `copy` of the
     stack, or of one layer's `[64, 64, 64, 128]`, would be a pass over
     it that the update does not need."""
-    from benchmarks import harness
-    from benchmarks.models import granite_hybrid as model
-    from kubeflow_tpu.models import granite_hybrid
-    from kubeflow_tpu.serving import engine as engine_lib
-    from kubeflow_tpu.serving.continuous import ContinuousEngine
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    config = harness.load_cell(
-        root, "granite-4.0-h-micro.decode-heavy").config
-    cfg = model.program_config(
-        dict(config, layer_types=config["layer_types"][:10]))
-    batcher = config["batcher"]
-    slots, block = batcher["max_slots"], batcher["kv_block_size"]
-
-    def described(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
-
-    def of(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    rng = described(jax.eval_shape(lambda: jax.random.key(0)))
-    params = described(jax.eval_shape(
-        lambda k: granite_hybrid.init(k, cfg), rng))
-    sp = engine_lib.SamplingParams(
-        of((slots,), jnp.float32), of((slots,)), of((slots,), jnp.float32))
-    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
-            force_interpret(False):
-        ce = ContinuousEngine(
-            engine_lib.InferenceEngine(
-                params, cfg, engine_lib.granite_hybrid_family(cfg),
-                engine_lib.EngineConfig(**config["engine"])),
-            max_slots=slots, block_size=block,
-            num_blocks=1 + 2 * config["engine"]["max_len"] // block)
-        # heads of 64: the resolver answers from the shape
-        assert (ce.attention_impl, ce.prefill_impl) == ("xla", "xla")
-        st = described(jax.eval_shape(ce.init_slots))
-        text = ce._step_jit.lower(
-            params, None, st, sp, rng, steps=4).compile().as_text()
+    compiled, cfg, ce, st = compile_serving_step(
+        one_chip, "granite-4.0-h-micro.decode-heavy",
+        functools.partial(granite_model, periods=1), "decode-step", steps=4)
+    text = compiled.as_text()
+    # heads of 64: the resolver answers from the shape
+    assert (ce.attention_impl, ce.prefill_impl) == ("xla", "xla")
+    slots = ce.S
     heads, hd, state = (cfg.mamba_n_heads, cfg.mamba_d_head,
                         cfg.mamba_d_state)
     assert st.rec.ssm.shape == (9, slots, heads, hd, state)
@@ -211,6 +213,48 @@ def test_a_recurrent_state_is_updated_where_it_lies(one_chip):
         rf"= (bf16|f32)\[(9,)?{slots},{heads},{hd},{state}\]\S* copy\(",
         line)]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("case", ["decode-step", "prefill-slice"])
+def test_the_kv_pool_is_written_and_read_where_it_lies(one_chip, case):
+    """`granite-4.0-h-micro.decode-heavy`'s two step programs at the
+    cell's widths, slots, `chunk` and pool of 2049 blocks, two periods
+    of the layer pattern (two attention layers, so a layer's slice of
+    the pool and the whole stack differ): the pool, 268 MB a layer for K
+    and as much for V, is a parameter and an aliased result, rides the
+    loops in the layout it rests in, and no `copy` or `transpose` yields
+    an array of its size. While a cell was `[8, 64]` (heads of 64: half
+    a lane tile) the pool rested with the block index minor, and both
+    programs copied it whole on entry and on exit, the slice program
+    once more after each layer's scatter: 27 % of the device's time in
+    the cell (PERF.md section 6, PR 37)."""
+    compiled, cfg, ce, st = compile_serving_step(
+        one_chip, "granite-4.0-h-micro.decode-heavy",
+        functools.partial(granite_model, periods=2), case, steps=2,
+        num_blocks=2049)
+    text = compiled.as_text()
+    assert st.k.shape == st.v.shape == (2, 2049, 64, 1, 512)
+    pool = "2,2049,64,1,512"
+    # the two pools are parameters of the program and aliased results
+    params = re.findall(
+        rf"%(\S+) = bf16\[{pool}\]\S* parameter\((\d+)\)", text)
+    assert len(params) == 2, params
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    for _, number in params:
+        assert re.search(rf"\({number}, \{{\}}, may-alias\)",
+                         aliased.group(1)), (number, aliased.group(1))
+    # no copy or transpose of an array as large as a layer's pool or the
+    # stack's, whatever its shape
+    per_layer = math.prod(st.k.shape[1:])
+    moved = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) in (
+                per_layer, 2 * per_layer):
+            moved.append(line.strip()[:200])
+    assert not moved, moved
+    plan = compiled.memory_analysis()
+    assert plan.temp_size_in_bytes < 1e9, plan.temp_size_in_bytes
 
 
 @pytest.mark.slow
