@@ -60,6 +60,7 @@ PROFILE_FAMILIES = (
     "serving_bubble_fraction",
     "serving_kv_blocks_high_water",
     "serving_recompiles_total",
+    "serving_startup_seconds",
 )
 
 
@@ -235,6 +236,13 @@ async def run_profile_check() -> list[str]:
             failures.append(
                 f"serving_recompiles_total not zero-seeded for fns "
                 f"{sorted(missing)}")
+        started = families.get("serving_startup_seconds", {"samples": {}})
+        missing = set(obs_lib.STARTUP_PHASES) - {
+            dict(labels).get("phase") for (_s, labels) in started["samples"]}
+        if missing:
+            failures.append(
+                f"serving_startup_seconds not zero-seeded for phases "
+                f"{sorted(missing)}")
 
         # 2. /debug/profile: the rolling anatomy
         resp = await client.get("/debug/profile")
@@ -258,9 +266,18 @@ async def run_profile_check() -> list[str]:
                     "/debug/profile: no decode phase samples after a "
                     "generate — is the batcher instrumented?")
             for fn in obs_lib.WATCHED_SERVING_FNS:
-                if fn not in m.get("recompiles", {}):
+                # this app has no draft model: nothing to watch there
+                if fn not in m.get("recompiles", {}) \
+                        and not fn.startswith("spec_"):
                     failures.append(
                         f"/debug/profile missing recompile fn {fn!r}")
+        # the process's compile ledger and start-up spans ride along
+        for key, inner in (("compiles", "programs"), ("startup", "spans")):
+            if inner not in prof.get(key, {}):
+                failures.append(f"/debug/profile missing {key}.{inner}")
+        if "startup.batcher" not in prof.get("startup", {}).get("spans", {}):
+            failures.append("/debug/profile startup has no startup.batcher "
+                            "span after a batcher was built")
 
         # 3. /debug/traces: spans + the profiler's counter tracks
         payload = json.loads(

@@ -33,6 +33,13 @@ from kubeflow_tpu.obs.cachestats import (
     prefix_hash,
 )
 from kubeflow_tpu.obs.cardinality import OVERFLOW_LABEL, LabelGuard
+from kubeflow_tpu.obs.compiles import (
+    STARTUP_PHASES,
+    STARTUP_SPANS,
+    CompileLedger,
+    bind_startup_gauge,
+    startup_span,
+)
 from kubeflow_tpu.obs.decisions import (
     OUTCOMES as DECISION_OUTCOMES,
     VERDICTS as DECISION_VERDICTS,
@@ -60,7 +67,6 @@ from kubeflow_tpu.obs.profiling import (
     WATCHED_TRAIN_FNS,
     CompileWatch,
     PhaseProfiler,
-    abstract_signature,
     merge_counter_tracks,
 )
 from kubeflow_tpu.obs.slo import (
@@ -90,6 +96,8 @@ __all__ = [
     "PREFILL_SOURCES",
     "REUSE_BUCKETS",
     "SIZE_BUCKETS",
+    "STARTUP_PHASES",
+    "STARTUP_SPANS",
     "TOKEN_BUCKETS",
     "SERVING_PHASES",
     "TRAIN_PHASES",
@@ -97,6 +105,7 @@ __all__ = [
     "WATCHED_SERVING_FNS",
     "WATCHED_TRAIN_FNS",
     "CacheLedger",
+    "CompileLedger",
     "CompileWatch",
     "DECISION_OUTCOMES",
     "DECISION_VERDICTS",
@@ -114,8 +123,9 @@ __all__ = [
     "TimelineStore",
     "Tracer",
     "DEFAULT_TRACER",
-    "abstract_signature",
+    "bind_startup_gauge",
     "canonical_prefix",
+    "compile_ledger",
     "default_registry",
     "federate",
     "format_float",
@@ -129,6 +139,7 @@ __all__ = [
     "register_budget_gauge",
     "render_families",
     "sample_quantile",
+    "startup_span",
     "traces_response_payload",
 ]
 
@@ -136,6 +147,17 @@ __all__ = [
 # (Trainer, ad-hoc scripts) share it, so one /debug/traces view can
 # correlate them.
 DEFAULT_TRACER = Tracer()
+
+
+
+def compile_ledger() -> CompileLedger:
+    """The process's one compile ledger (`obs/compiles.py`): it holds
+    what JAX built and the start-up spans once
+    `compile_cache.enable()` has installed it, nothing before."""
+    from kubeflow_tpu.obs import compiles
+
+    return compiles.LEDGER
+
 
 _default_registry = None
 

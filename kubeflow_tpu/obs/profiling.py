@@ -49,30 +49,32 @@ the same `.xplane.pb`, on the same clock, as the device's operations;
 `host_gap` needs none, being the iteration's self time there. Without a
 profiler session an annotation records nothing.
 
-The compile-watch wraps jitted callables and keys every call by the
-ABSTRACT signature of its arguments (shape/dtype for arrays, value for
-python scalars — matching `static_argnames` semantics for the wrapped
-fns here, whose only scalar args are static). A signature never seen
-before, beyond the fn's first (the expected initial compile), is a
-retrace: the counter hook fires (`serving_recompiles_total{fn}` /
-`train_recompiles_total{fn}`) and a `recompile` span records the
-offending signature. Steady-state decode repeats one signature, so a
-nonzero rate is always news.
+The compile-watch (`CompileWatch`) is a view of what JAX itself holds
+about a few jitted callables of the hot path: each function's own
+dispatch cache says how many signatures it was built for, and the
+compile ledger's events (`obs/compiles.py`) say when to look and how
+long the build took. It wraps nothing: the watched function is called
+as it is, and nothing of the watch runs on a dispatch. An entry past a
+function's first is a retrace: the counter hook fires
+(`serving_recompiles_total{fn}` / `train_recompiles_total{fn}`) and a
+`recompile` span names the program with the seconds of its trace,
+lowering and backend stage. Steady-state decode repeats one signature,
+so a nonzero rate is always news.
 
-No jax import here: obs stays importable in jax-free processes, and
-signatures duck-type ``.shape``/``.dtype`` instead of tracing.
+No jax import here: obs stays importable in jax-free processes.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
-import functools
 import threading
 import time
+import weakref
 from typing import Any, Callable
 
 from kubeflow_tpu.obs.cardinality import LabelGuard
+from kubeflow_tpu.obs.compiles import LEDGER, CompileLedger
 from kubeflow_tpu.obs.metrics import sample_quantile
 
 # The serving step anatomy (ContinuousBatcher worker loop).
@@ -98,32 +100,6 @@ WATCHED_SERVING_FNS = ("decode_step", "reset_slots", "prefill_append",
 WATCHED_TRAIN_FNS = ("train_step",)
 
 _MAX_COUNTER_EVENTS = 2048
-
-
-def abstract_signature(args: tuple, kwargs: dict) -> str:
-    """Compact hashable key for a call's abstract shapes: arrays render
-    as `dtype[d0,d1,...]` (duck-typed — works for jax/numpy arrays and
-    ShapeDtypeStructs without importing either), python scalars by
-    value (static-arg semantics), containers structurally."""
-
-    def sig(x: Any) -> str:
-        shape = getattr(x, "shape", None)
-        dtype = getattr(x, "dtype", None)
-        if shape is not None and dtype is not None:
-            return f"{dtype}[{','.join(str(d) for d in shape)}]"
-        if isinstance(x, (bool, int, float, str, bytes)) or x is None:
-            return repr(x)
-        if isinstance(x, (tuple, list)):
-            return "(" + ",".join(sig(v) for v in x) + ")"
-        if isinstance(x, dict):
-            items = sorted(x.items(), key=lambda kv: str(kv[0]))
-            return "{" + ",".join(f"{k}:{sig(v)}" for k, v in items) + "}"
-        # opaque leaves (pytree nodes the duck-typing missed) key by
-        # TYPE only: better to miss a retrace than to invent one per
-        # object identity
-        return type(x).__name__
-
-    return sig(args) + sig(kwargs) if kwargs else sig(args)
 
 
 class _PhaseStats:
@@ -419,80 +395,151 @@ class PhaseProfiler:
                 "wall_s": round(self.wall_s(), 6)}
 
 
+class _Watched:
+    """One jitted function under a label: a weak reference, the
+    program's name in JAX's events, and how many entries of its
+    dispatch cache have been accounted for."""
+
+    __slots__ = ("ref", "program", "seen", "events", "by_events")
+
+    def __init__(self, fn):
+        self.ref = weakref.ref(fn)
+        self.program = getattr(fn, "__name__", "")
+        self.events = 0             # backend stages under the name
+        # another JAX, without `_cache_size`: count those, by name
+        self.by_events = not callable(getattr(fn, "_cache_size", None))
+        # what the function was built for before the watch: not news
+        self.seen = self.size(fn)
+
+    def size(self, fn) -> int:
+        """Entries of the function's own dispatch cache, one a
+        signature it was traced for."""
+        return self.events if self.by_events else int(fn._cache_size())
+
+
 class CompileWatch:
-    """Retrace detector over jitted callables.
+    """Retrace detector over jitted callables, without a wrapper.
 
-    `watch(fn, name)` returns a wrapper that keys every call by
-    `abstract_signature(args, kwargs)`. The FIRST signature per fn is
-    the expected initial compile; every novel signature after it is a
-    retrace: the local ledger increments, `on_recompile(fn, sig)` fires
-    (the server binds the `*_recompiles_total{fn}` counter there), and
-    when a tracer is attached a `recompile` span records the offending
-    signature. Calls repeating a seen signature cost one string build
-    and a set lookup.
+    `watch(fn, name)` keeps a weak reference to the jitted `fn` under
+    the label `name` and returns `fn` itself. `counts()` reads, when
+    asked, how far each function's own dispatch cache
+    (`fn._cache_size()`, JAX 0.9.0) has grown past its first entry: a
+    new shape, dtype, static value, weak type, sharding or commitment
+    of an argument, of the pool and the cursors as of the batch, is an
+    entry, and the count is THIS function's, whatever else in the
+    process compiles a program of the same name (a reload, a draft
+    engine, a second model). An entry is a trace and nearly always a
+    compile; two that differ only in whether an argument is committed
+    to a device can share one program. The ledger's events are the
+    trigger and the timing: the end of a backend stage under a watched
+    function's name marks the watch, and the next event, or the next
+    `counts()`, re-reads the caches (the entry is there once the call
+    that compiled has returned), bumps the label, calls
+    `on_recompile(label, program)` and opens the `recompile` span with
+    the program's name and the seconds of its three stages. Without a
+    listening ledger the counts are as exact and only the hooks wait
+    for the next `counts()`.
 
-    fn names are a closed set behind a LabelGuard (seeded by `watch`),
-    so the label space cannot grow past the wrapped callables.
+    Label names are a closed set behind a LabelGuard (seeded by
+    `watch`), so the label space cannot grow past the watched
+    callables. The watch holds its functions weakly and the ledger
+    holds the watch weakly: both go with the batcher or trainer.
     """
 
     def __init__(self, *, tracer=None,
-                 on_recompile: Callable[[str, str], None] | None = None):
+                 on_recompile: Callable[[str, str], None] | None = None,
+                 ledger: CompileLedger | None = None):
         self.tracer = tracer
         self.on_recompile = on_recompile
         self.guard = LabelGuard()
-        self._seen: dict[str, set[str]] = {}
+        self._watched: dict[str, list[_Watched]] = {}
         self._recompiles: dict[str, int] = {}
+        self._stages: dict[str, dict[str, float]] = {}  # by program
+        self._marked = False
         self._lock = threading.Lock()
+        (LEDGER if ledger is None else ledger).attach(self)
 
     def watch(self, fn: Callable, name: str) -> Callable:
+        if not callable(getattr(fn, "lower", None)):
+            raise TypeError(
+                f"CompileWatch.watch takes a jitted function (jax.jit's "
+                f"result), got {type(fn).__name__}: there is no compile "
+                f"to watch on a plain callable")
         name = self.guard.admit(name)
         with self._lock:
-            self._seen.setdefault(name, set())
+            self._watched.setdefault(name, []).append(_Watched(fn))
             self._recompiles.setdefault(name, 0)
+        return fn
 
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            try:
-                sig = abstract_signature(args, kwargs)
-            except Exception:  # noqa: BLE001 — watch must not break fn
-                return fn(*args, **kwargs)
+    def on_stage(self, stage: str, program: str, seconds: float) -> None:
+        """The ledger's call at the end of a program's stage (compile
+        time only). Nothing here may raise into JAX's compile path."""
+        try:
+            if self._marked:
+                self._refresh()
             with self._lock:
-                seen = self._seen[name]
-                novel = sig not in seen
-                first = novel and not seen
-                if novel:
-                    seen.add(sig)
-                    if not first:
-                        self._recompiles[name] += 1
-            if novel and not first:
-                self._note_recompile(name, sig)
-            return fn(*args, **kwargs)
+                mine = [w for ws in self._watched.values() for w in ws
+                        if w.program == program]
+                if not mine:
+                    return
+                self._stages.setdefault(program, {})[stage] = seconds
+                if stage == "backend":
+                    self._marked = True
+                    for w in mine:
+                        w.events += 1
+        except Exception:  # noqa: BLE001
+            pass
 
-        return wrapped
+    def _refresh(self) -> None:
+        """Re-read every watched function's cache; book what grew."""
+        grown: list[tuple[str, _Watched]] = []
+        with self._lock:
+            self._marked = False
+            for label, watched in self._watched.items():
+                for w in list(watched):
+                    fn = w.ref()
+                    if fn is None:
+                        watched.remove(w)
+                        continue
+                    size = w.size(fn)
+                    new = size - max(w.seen, 1)   # the first is expected
+                    w.seen = size                 # (also after a clear)
+                    if new > 0:
+                        self._recompiles[label] += new
+                        grown += [(label, w)] * new
+            stages = {p: dict(s) for p, s in self._stages.items()}
+        for label, w in grown:
+            self._note_recompile(label, w, stages.get(w.program, {}))
 
-    def _note_recompile(self, name: str, sig: str) -> None:
+    def _note_recompile(self, label: str, w: _Watched,
+                        stages: dict[str, float]) -> None:
         if self.tracer is not None:
             try:
-                with self.tracer.span("recompile", fn=name,
-                                      signature=sig[:512]):
+                with self.tracer.span(
+                        "recompile", fn=label, program=w.program,
+                        counted_by=("events_by_name" if w.by_events
+                                    else "dispatch_cache"),
+                        **{f"{k}_s": round(v, 6)
+                           for k, v in stages.items()}):
                     pass
             except Exception:  # noqa: BLE001
                 pass
         if self.on_recompile is not None:
             try:
-                self.on_recompile(name, sig)
+                self.on_recompile(label, w.program)
             except Exception:  # noqa: BLE001 — metrics hook
                 pass
 
     def counts(self) -> dict[str, int]:
-        """Per-fn retrace counts (the `/debug/profile` `recompiles`
+        """Per-label retrace counts (the `/debug/profile` `recompiles`
         block; mirrors the `*_recompiles_total{fn}` counters)."""
+        self._refresh()
         with self._lock:
             return dict(self._recompiles)
 
     def watched(self) -> tuple[str, ...]:
         with self._lock:
-            return tuple(self._seen)
+            return tuple(self._watched)
 
 
 def merge_counter_tracks(payload: dict, events: list[dict]) -> dict:

@@ -78,6 +78,7 @@ from kubeflow_tpu.serving.engine import (
     transformer_block,
 )
 from kubeflow_tpu.obs.cachestats import CacheLedger
+from kubeflow_tpu.obs.compiles import startup_span
 from kubeflow_tpu.obs.profiling import CompileWatch, PhaseProfiler
 from kubeflow_tpu.obs.timeline import RequestTimeline, TimelineStore
 from kubeflow_tpu.serving import migration
@@ -211,6 +212,7 @@ class ContinuousEngine:
     device.
     """
 
+    @startup_span("startup.engine")
     def __init__(self, engine: InferenceEngine, max_slots: int = 8,
                  block_size: int = 64, num_blocks: int | None = None,
                  paged_attention_impl: str = "auto",
@@ -1087,6 +1089,7 @@ class ContinuousBatcher:
     exported as `.occupancy()`.
     """
 
+    @startup_span("startup.batcher")
     def __init__(self, engine: InferenceEngine, gpu_lock: asyncio.Lock,
                  *, max_slots: int = 8, chunk: int = 4,
                  prefill_chunk_tokens: int = PREFILL_CHUNK_TOKENS,
@@ -1254,27 +1257,23 @@ class ContinuousBatcher:
         # profiler session is open (docs/observability.md).
         self.profiler = PhaseProfiler(
             clock=self._clock, annotate=jax.profiler.TraceAnnotation)
-        # Compile-watch: every jitted callable on this batcher's hot
-        # path keys calls by abstract shape signature; a novel
-        # signature past each fn's first is a retrace — counted here,
-        # surfaced as serving_recompiles_total{fn} once the server
-        # binds compile_watch.on_recompile. (warmup() walks the bounded
-        # compile set through these wrappers, so the counters start at
-        # the warmed-shape count; steady state is flat — the alert is
-        # on the RATE.)
+        # Compile-watch: a view of the dispatch caches of the jitted
+        # callables on this batcher's hot path, which are called as
+        # they are (no wrapper, nothing per dispatch). An entry past a
+        # function's first is a retrace — counted when asked, surfaced
+        # as serving_recompiles_total{fn} once the server binds
+        # compile_watch.on_recompile. (warmup() walks the bounded
+        # compile set through these functions, so the counters start
+        # at the warmed-shape count; steady state is flat — the alert
+        # is on the RATE.)
         self.compile_watch = CompileWatch()
         ce = self.cengine
-        ce._step_jit = self.compile_watch.watch(
-            ce._step_jit, "decode_step")
-        ce._reset_jit = self.compile_watch.watch(
-            ce._reset_jit, "reset_slots")
-        ce._append_jit = self.compile_watch.watch(
-            ce._append_jit, "prefill_append")
+        self.compile_watch.watch(ce._step_jit, "decode_step")
+        self.compile_watch.watch(ce._reset_jit, "reset_slots")
+        self.compile_watch.watch(ce._append_jit, "prefill_append")
         if ce.draft is not None:
-            ce._spec_draft_jit = self.compile_watch.watch(
-                ce._spec_draft_jit, "spec_draft")
-            ce._spec_verify_jit = self.compile_watch.watch(
-                ce._spec_verify_jit, "spec_verify")
+            self.compile_watch.watch(ce._spec_draft_jit, "spec_draft")
+            self.compile_watch.watch(ce._spec_verify_jit, "spec_verify")
         # Shared prefixes (system prompts): token lists registered at
         # construction, prepended to a request that names one.
         self._prefixes = dict(prefixes or {})
@@ -1393,6 +1392,7 @@ class ContinuousBatcher:
             "heat": self._radix.heat_digest(16),
         }
 
+    @startup_span("startup.warmup")
     def warmup(self) -> int:
         """Blocking ahead-of-traffic compile of every program admission
         and decode run (call before serving traffic; the app's

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kubeflow_tpu.obs.compiles import startup_span
 from kubeflow_tpu.ops.attention import dot_product_attention
 from kubeflow_tpu.ops.embedding import embed_lookup
 from kubeflow_tpu.ops.norms import rms_norm
@@ -467,6 +468,7 @@ class InferenceEngine:
     Jitted entry points are cached per (batch, prompt_len, max_new).
     """
 
+    @startup_span("startup.engine")
     def __init__(self, params: Params, cfg, family: Family,
                  engine_config: EngineConfig = EngineConfig(),
                  adapter_pack=None):

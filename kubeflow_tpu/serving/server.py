@@ -291,10 +291,11 @@ class ServingObs:
             self.registry)
         self.recompiles = Counter(
             "serving_recompiles_total",
-            "Retraces of a watched jitted callable (a novel abstract "
-            "shape signature past the fn's first) — nonzero RATE in "
-            "steady state means the compile-shape bucketing leaked",
-            self.registry)
+            "Entries of a watched jitted callable's own dispatch cache "
+            "past its first (JAX traced it for a new signature: a "
+            "retrace) — nonzero RATE in steady state means the "
+            "compile-shape bucketing leaked", self.registry)
+        obs_lib.bind_startup_gauge(self.registry, "serving_startup_seconds")
         # KV-cache observatory (ISSUE 13): the block lifecycle ledger
         # (obs.cachestats.CacheLedger, attached to each batcher's
         # BlockPool) books every block death to a CAUSE; the cause set
@@ -1005,7 +1006,7 @@ def create_serving_app(engines: dict[str, InferenceEngine],
             b.profiler.on_phase = on_phase
             b.compile_watch.tracer = sobs.tracer
 
-            def on_recompile(fn, sig, _m=model_name):
+            def on_recompile(fn, program, _m=model_name):
                 sobs.recompiles.inc(model=_m, fn=fn)
 
             b.compile_watch.on_recompile = on_recompile
@@ -1018,6 +1019,9 @@ def create_serving_app(engines: dict[str, InferenceEngine],
                     sobs.kv_blocks.set(_b.kv_blocks_in_use(), model=_m)
                     sobs.kv_pool_cell_lanes.set(
                         _b.cengine.kv_cell[1], model=_m)
+                    # the watch re-reads its functions' caches, so
+                    # serving_recompiles_total is current in the scrape
+                    _b.compile_watch.counts()
                     sobs.ssm_state_bytes.set(_b.ssm_state_bytes(), model=_m)
                     # a counter can only inc: the delta since last scrape
                     sobs.ssm_state_resets.inc(
@@ -1158,7 +1162,10 @@ def create_serving_app(engines: dict[str, InferenceEngine],
                 snap["recompiles"] = _b.compile_watch.counts()
                 snap["cache"] = _b.cache_anatomy()
                 models[_m] = snap
-        return web.json_response({"models": models})
+        # the process's compile ledger: every program JAX built, the
+        # costliest first, and where the start went
+        return web.json_response(
+            {"models": models, **obs_lib.compile_ledger().snapshot()})
 
     async def spec_toggle(request: web.Request):
         """POST /v1/spec {"enabled": bool} — runtime kill switch for

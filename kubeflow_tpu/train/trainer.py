@@ -232,6 +232,7 @@ class Trainer:
     `logical_axes`: pytree of logical axis tuples matching params.
     """
 
+    @obs.startup_span("startup.trainer")
     def __init__(
         self,
         *,
@@ -359,20 +360,23 @@ class Trainer:
         self.profiler.on_phase = _on_phase
         # Compile-watch over the jitted step: a batch/seq shape change
         # mid-run is a retrace the owner should know about (it stalls
-        # every replica for the compile) — counted per fn, with a
-        # `recompile` span naming the offending signature.
+        # every replica for the compile) — counted per fn from the
+        # step's own dispatch cache, with a `recompile` span naming
+        # the program and the seconds of its stages. The step is
+        # called as it is: the watch wraps nothing.
         self.recompiles = reg.get("train_recompiles_total")
         if self.recompiles is None:
             self.recompiles = Counter(
                 "train_recompiles_total",
-                "Retraces of the jitted train step (novel abstract "
-                "batch shape past the first compile)", reg)
+                "Entries of the jitted train step's dispatch cache "
+                "past its first (a new batch shape: a retrace)", reg)
         self._compile_watch = obs.CompileWatch(
             tracer=self.tracer,
-            on_recompile=lambda fn, sig: self.recompiles.inc(fn=fn))
-        self._jit_step = self._compile_watch.watch(
-            self._jit_step, "train_step")
+            on_recompile=lambda fn, program: self.recompiles.inc(fn=fn))
+        self._compile_watch.watch(self._jit_step, "train_step")
         self.recompiles.inc(0, fn="train_step")
+        _scrape_refreshes(reg, self._compile_watch)
+        obs.bind_startup_gauge(reg, "train_startup_seconds")
         self._last_step_end: float | None = None
         # what the last step's loss_fn counted, still on the device
         self.last_aux: dict[str, jax.Array] = {}
@@ -463,10 +467,12 @@ class Trainer:
             params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss, aux
 
+    @obs.startup_span("startup.trainer")
     def init(self, rng: jax.Array) -> TrainState:
         with jax.set_mesh(self.mesh):
             return self._jit_init(rng)
 
+    @obs.startup_span("startup.trainer")
     def init_from_params(self, params: Params) -> TrainState:
         """Warm-start: fresh optimizer state around EXISTING params
         (fine-tuning from a checkpoint). Params are a jit argument, not
@@ -521,6 +527,17 @@ class Trainer:
             total += nbytes
         return total
 
+    def _dispatch(self, state, tokens, targets, mask, compiling):
+        with self.tracer.span("train.step", batch=int(tokens.shape[0]),
+                              compile=compiling):
+            with jax.set_mesh(self.mesh):
+                with self.profiler.phase(
+                        "step", tokens=int(tokens.shape[0])
+                        * int(tokens.shape[1])):
+                    state, loss, self.last_aux = self._jit_step(
+                        state, tokens, targets, mask)
+        return state, loss
+
     def step(self, state: TrainState, tokens, targets, mask=None):
         if mask is None:
             mask = jnp.ones_like(tokens, dtype=jnp.float32)
@@ -540,14 +557,13 @@ class Trainer:
             # pipeline, checkpoint writes, eval, logging — is the
             # trainer's host gap.
             self.profiler.record("host_gap", t0 - self._last_step_end)
-        with self.tracer.span("train.step", batch=int(tokens.shape[0]),
-                              compile=compiling):
-            with jax.set_mesh(self.mesh):
-                with self.profiler.phase(
-                        "step", tokens=int(tokens.shape[0])
-                        * int(tokens.shape[1])):
-                    state, loss, self.last_aux = self._jit_step(
-                        state, tokens, targets, mask)
+        if compiling:
+            with obs.compile_ledger().span("startup.first_step"):
+                state, loss = self._dispatch(state, tokens, targets, mask,
+                                             compiling)
+        else:
+            state, loss = self._dispatch(state, tokens, targets, mask,
+                                         compiling)
         if "moe_load" in self.last_aux:
             self._export_moe_load(self.last_aux["moe_load"])
         dt = time.perf_counter() - t0
@@ -557,6 +573,21 @@ class Trainer:
             self._stepped = True
             self.compile_seconds.observe(dt)
         return state, loss
+
+
+def _scrape_refreshes(reg, watch) -> None:
+    """A render of `reg` is when `watch` re-reads its function's cache,
+    so that `train_recompiles_total` is current in every scrape; the
+    collector leaves with its trainer."""
+    alive = weakref.ref(watch)
+
+    def collect():
+        watch = alive()
+        if watch is not None:
+            watch.counts()
+        return watch is not None
+
+    reg.register_collector(collect)
 
 
 def _opt_state_shardings(opt_shapes, params_shapes, param_shardings, mesh):

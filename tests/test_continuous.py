@@ -1009,10 +1009,8 @@ async def test_async_device_failure_in_drain_path_fails_cleanly():
 
 
 def _jit_cache_sizes(ce):
-    """Signatures each admission and decode jit has met (the batcher
-    wraps three of them in its compile watch: `__wrapped__`)."""
-    return {name: (fn if hasattr(fn, "_cache_size")
-                   else fn.__wrapped__)._cache_size()
+    """Signatures each admission and decode jit has met."""
+    return {name: fn._cache_size()
             for name, fn in (("append_rows", ce._append_jit),
                              ("adopt_slot", ce._adopt_jit),
                              ("copy_cells", ce._copy_cells_jit),

@@ -1,5 +1,5 @@
 """Step-anatomy profiling plane (ISSUE 8): PhaseProfiler attribution
-invariants, quantile-interpolation pins, CompileWatch retrace
+invariants, quantile-interpolation pins, compile-watch
 semantics, and the batcher/server integration.
 
 The attribution contract under test everywhere: phase durations are
@@ -25,9 +25,7 @@ from kubeflow_tpu.obs.metrics import Histogram, sample_quantile
 from kubeflow_tpu.obs.profiling import (
     SERVING_PHASES,
     WATCHED_SERVING_FNS,
-    CompileWatch,
     PhaseProfiler,
-    abstract_signature,
     merge_counter_tracks,
 )
 from kubeflow_tpu.utils.profiling import StepTimer
@@ -335,51 +333,7 @@ def test_histogram_seed_renders_zero_row():
     assert fams["seeded_seconds"]["samples"][key] == 0
 
 
-# -- CompileWatch ----------------------------------------------------------
-
-
-def test_abstract_signature_shapes_scalars_containers():
-    sig = abstract_signature(
-        (jnp.ones((2, 3)), 5, "mode"), {"flag": None})
-    assert "float32[2,3]" in sig and "5" in sig and "'mode'" in sig
-    # same abstract shapes, different values -> same signature
-    a = abstract_signature((jnp.zeros((4,)),), {})
-    b = abstract_signature((jnp.ones((4,)),), {})
-    assert a == b
-    assert abstract_signature((jnp.ones((5,)),), {}) != a
-
-
-def test_compile_watch_counts_retrace_exactly_once():
-    tracer = obs.Tracer()
-    fired = []
-    watch = CompileWatch(tracer=tracer,
-                         on_recompile=lambda fn, sig: fired.append(fn))
-    f = watch.watch(jax.jit(lambda x: x * 2), "fn")
-    f(jnp.ones((2,)))            # initial compile: expected, free
-    f(jnp.ones((2,)))            # steady state
-    assert watch.counts() == {"fn": 0}
-    assert fired == []
-    f(jnp.ones((3,)))            # novel shape: ONE retrace
-    assert watch.counts() == {"fn": 1}
-    assert fired == ["fn"]
-    f(jnp.ones((3,)))            # now steady again
-    f(jnp.ones((2,)))            # seen before: still no new retrace
-    assert watch.counts() == {"fn": 1}
-    # the recompile span names the offending signature
-    traces = tracer.traces(name="recompile")
-    assert len(traces) == 1
-    span = traces[0]["spans"][0]
-    assert span["attrs"]["fn"] == "fn"
-    assert "float32[3]" in span["attrs"]["signature"]
-
-
-def test_compile_watch_wrapper_is_transparent():
-    watch = CompileWatch()
-    f = watch.watch(jax.jit(lambda x: x + 1), "inc")
-    out = f(jnp.zeros((2,)))
-    np.testing.assert_allclose(np.asarray(out), np.ones((2,)))
-    assert watch.watched() == ("inc",)
-
+# -- CompileWatch: tests/test_compile_ledger.py ----------------------------
 
 # -- batcher / trainer / server integration --------------------------------
 
@@ -597,12 +551,14 @@ def test_trainer_compile_watch_and_phase_histograms():
                        jnp.int32)
     state, _ = tr.step(state, tok2, jnp.roll(tok2, -1, axis=1))
     assert tr._compile_watch.counts() == {"train_step": 1}
-    # the retrace fires inside the `train.step` root span, so the
-    # recompile span rides that trace as a child
-    spans = [s for t in tr.tracer.traces(name="train.step")
-             for s in t["spans"] if s["name"] == "recompile"]
+    # the watch wraps nothing: the retrace is booked when the step's
+    # own cache is next read, in a `recompile` span that names the
+    # program
+    spans = [s for t in tr.tracer.traces(name="recompile")
+             for s in t["spans"]]
     assert len(spans) == 1
-    assert "int32[4,32]" in spans[0]["attrs"]["signature"]
+    assert spans[0]["attrs"]["fn"] == "train_step"
+    assert spans[0]["attrs"]["program"] == "_step"
 
     t = tr.profiler.totals()
     assert t["step"] > 0 and tr.profiler.phase_tokens()["step"] > 0
