@@ -5,11 +5,11 @@ none of the work."""
 
 from __future__ import annotations
 
-import json
 import os
 
 import pytest
 
+import manifest_rules
 from benchmarks import devtrace, harness, trafficgen
 from benchmarks.kinds import train as train_kind
 from benchmarks.models import llama as model
@@ -231,24 +231,4 @@ def test_percentile_is_the_nearest_rank():
 # -- the manifest against its data files ----------------------------------
 
 def test_every_manifest_entry_finds_its_files_and_readers():
-    import importlib
-
-    manifest = harness.read_json(os.path.join(REPO, "BENCHMARK.json"))
-    e2e = {m["name"] for m in manifest["end_to_end"]}
-    for w in manifest["workloads"]:
-        cell = harness.load_cell(REPO, w["name"])
-        assert importlib.import_module(
-            f"benchmarks.kinds.{cell.traffic['kind']}").run
-        names = {m["name"] for m in cell.metrics("end_to_end")}
-        assert "setup_s" in names and len(names) >= 2
-        assert cell.metrics("per_layer")
-        for m in cell.metrics("per_layer"):
-            assert m["moves"] in names, (m["name"], w["name"])
-    for m in manifest["per_layer"]:
-        assert m["moves"] in e2e
-        spec = harness.read_json(os.path.join(
-            REPO, "benchmarks", "layers", m["name"] + ".json"))
-        module, _, fn = spec["reader"].rpartition(".")
-        assert callable(getattr(importlib.import_module(
-            f"benchmarks.readers.{module}"), fn))
-    assert len(json.dumps(manifest)) < 64 * 1024
+    manifest_rules.check_files_and_readers(REPO)

@@ -82,8 +82,8 @@ def test_every_new_reader_is_silent_where_there_is_nothing_to_read(
     """Nothing off the chip; and nothing on it from a profile that
     holds neither the scopes nor the spans, as the parent's program
     writes none, nor for a model class without the counting functions
-    (`tiny_cells` hands every metric it does not know to its `llama`
-    cells, and the driver lays these files over the parent)."""
+    (a later cell of another model class may list these metrics, and
+    the files are laid over a parent that has none of them)."""
     from benchmarks.models import llama
 
     counters = {"traced_decode_steps": 8, "decode_steps": 8,

@@ -54,9 +54,9 @@ def test_reference_check_fails_on_one_perturbed_weight(checkout):
 
 def test_the_new_readers_say_nothing_for_a_model_class_without_them(
         tmp_path):
-    """`tiny_cells` gives every metric it does not know to its serving
-    cells: the readers ask a `llama` class, find no such function and
-    leave the metric out."""
+    """A cell of another model class may list these metrics: the
+    readers ask a `llama` class, find no such function and leave the
+    metric out."""
     from benchmarks.models import llama
     from benchmarks.readers import flops
 

@@ -14,7 +14,9 @@ import sys
 
 import pytest
 
+import manifest_rules
 import tiny_cells
+from benchmarks import harness
 
 LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
@@ -49,18 +51,20 @@ def test_serve_cell_runs_end_to_end(checkout, cell):
 
 
 def test_traced_serve_run_reports_the_counted_layer_metrics(checkout):
-    root, manifest = checkout
-    line = tiny_cells.run(root, "tiny.closed", trace=True)
-    assert line["correct"] is True, line["problems"]
-    got = set(line["metrics"])
-    # the CPU has no device plane: the trace readers find nothing and
-    # their metrics are left out, the counted ones are there
-    assert got == {"sched.occupancy", "sched.recompiles",
-                   "kv.peak_in_use_share"}
-    assert got <= _names(manifest, "per_layer", "tiny.closed")
-    assert 0 < line["metrics"]["sched.occupancy"]["value"] <= 100
-    assert line["metrics"]["sched.recompiles"]["value"] == 0
-    assert "busy_s" not in line["device"]
+    root, _ = checkout
+    manifest_rules.check_traced_serve_run(root)
+
+
+def test_the_tail_of_first_tokens_is_read_per_layer_from_the_window():
+    """`sched.ttft_p90_ms` is what the kind took on the host's clock, and
+    nothing where the kind took none."""
+    fn, args = manifest_rules.reader(tiny_cells.REPO, "sched.ttft_p90_ms")
+    run = harness.Run(end_to_end={"ttft_p90_ms": 712.5, "setup_s": 40.0},
+                      counters={}, attempted=1, failed=0, problems=[])
+    ctx = harness.Context(run=run, cell=None, model=None, peaks=None)
+    assert fn(ctx, **args) == 712.5
+    run.end_to_end.pop("ttft_p90_ms")
+    assert fn(ctx, **args) is None
 
 
 def test_reference_check_fails_on_one_perturbed_weight(checkout):
@@ -108,7 +112,7 @@ def test_a_cell_a_config_and_a_layer_metric_are_added_by_files_alone(
         "source": "program_counter", "layer": "scheduler",
         "moves": "serve_tok_s", "workloads": ["shallow.short"]})
     for m in manifest["end_to_end"]:
-        if m["name"] in ("serve_tok_s", "ttft_p90_ms"):
+        if m["name"] in ("serve_tok_s", "itl_p95_ms"):
             m["workloads"].append("shallow.short")
     tiny_cells.write_json(f"{root}/BENCHMARK.json", manifest)
 
@@ -117,7 +121,7 @@ def test_a_cell_a_config_and_a_layer_metric_are_added_by_files_alone(
     assert set(line["metrics"]) == {"sched.tokens_per_step"}
     assert 0 < line["metrics"]["sched.tokens_per_step"]["value"] <= 2
     line = tiny_cells.run(root, "shallow.short", seconds=0.5)
-    assert set(line["metrics"]) == {"serve_tok_s", "ttft_p90_ms", "setup_s"}
+    assert set(line["metrics"]) == {"serve_tok_s", "itl_p95_ms", "setup_s"}
     for p, content in before.items():
         if not p.endswith("BENCHMARK.json"):
             assert open(p, "rb").read() == content, p

@@ -1,16 +1,14 @@
 """The six `setup.*` per-layer metrics: the manifest's entries against
 their data files and readers, and each reader against a hand-made
-ledger, on the training kind directly (the tiny checkout sends a
-metric whose name does not start with `train` to its serve cells).
-Seconds are the hand-made events' own: no clock is read."""
+ledger, on the training kind directly (the tiny checkout gives them to
+its serve cells, as they list `mistral-7b.steady`). Seconds are the
+hand-made events' own: no clock is read."""
 
 from __future__ import annotations
 
-import importlib
-import os
-
 import pytest
 
+import manifest_rules
 import tiny_cells
 from benchmarks import harness
 from benchmarks.readers import setup as reader
@@ -19,46 +17,21 @@ from kubeflow_tpu.obs.compiles import (CACHE_READ_EVENT, STAGE_EVENTS,
                                        CompileLedger)
 
 REPO = tiny_cells.REPO
-SETUP_METRICS = {"setup.before_first_program_s": "program_span",
-                 "setup.trace_lower_s": "program_span",
-                 "setup.backend_s": "program_span",
-                 "setup.cache_misses": "program_counter",
-                 "setup.programs": "program_counter",
-                 "setup.init_s": "program_span"}
-CELLS = ["mistral-7b.steady", "mistral-7b.train", "kimi-linear-48b.train-8k",
-         "granite-4.0-h-micro.decode-heavy"]
+SETUP_METRICS = manifest_rules.SETUP_METRICS
 TRACE, LOWER, BACKEND = STAGE_EVENTS
 REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
 HIT = "/jax/compilation_cache/cache_hits"
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
-# -- the manifest's rule, for the entries this PR adds --------------------
+# -- the manifest's rule --------------------------------------------------
 
 def _spec(name: str) -> dict:
-    return harness.read_json(os.path.join(
-        REPO, "benchmarks", "layers", name + ".json"))
+    return manifest_rules.layer_spec(REPO, name)
 
 
-def test_each_new_metric_moves_setup_s_in_the_four_cells_that_report_it():
-    manifest = harness.read_json(os.path.join(REPO, "BENCHMARK.json"))
-    moved = [m for m in manifest["per_layer"] if m["moves"] == "setup_s"]
-    assert [m["name"] for m in moved] == list(SETUP_METRICS)   # and no other
-    assert moved == manifest["per_layer"][-len(moved):]        # appended
-    assert CELLS == [w["name"] for w in manifest["workloads"]]
-    for m in moved:
-        assert m["workloads"] == CELLS, m["name"]
-        assert m["layer"] == "start-up" and m["better"] == "lower"
-        assert m["source"] == SETUP_METRICS[m["name"]]
-        assert m["unit"] == ("s" if m["name"].endswith("_s") else "count")
-        module, _, fn = _spec(m["name"])["reader"].rpartition(".")
-        assert module == "setup"
-        assert callable(getattr(importlib.import_module(
-            f"benchmarks.readers.{module}"), fn))
-        for w in m["workloads"]:
-            reported = {e["name"] for e in
-                        harness.load_cell(REPO, w).metrics("end_to_end")}
-            assert m["moves"] in reported, (m["name"], w)
+def test_the_setup_metrics_move_setup_s_in_every_cell():
+    manifest_rules.check_setup_metrics(REPO)
 
 
 # -- each reader against a hand-made ledger -------------------------------

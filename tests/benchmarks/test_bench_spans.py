@@ -6,25 +6,17 @@ adding up."""
 
 from __future__ import annotations
 
-import importlib
 import os
 
 import pytest
 
+import manifest_rules
 import tiny_cells
 from benchmarks import harness, spans
 from benchmarks.models import llama as model
+from manifest_rules import SCOPE_METRICS, SPAN_METRICS
 
 REPO = tiny_cells.REPO
-
-# the per-layer metrics this file's readers serve
-SPAN_METRICS = {"sched.iter_host_ms", "sched.queue_wait_ms",
-                "sched.prefill_wait_share", "device.idle_host_share",
-                "train.host_gap_ms"}
-SCOPE_METRICS = {"step.kv_write_ms", "step.prefill_kv_write_ms",
-                 "kernel.paged_attention_ms",
-                 "kernel.paged_attention_bw_share",
-                 "kernel.prefill_append_ms", "train.flash_attention_ms"}
 
 
 def span(name, start, dur, **stats):
@@ -379,11 +371,7 @@ def test_the_timeline_and_the_trace_say_the_same_parts():
 # -- the readers --------------------------------------------------------
 
 def _reader(name):
-    spec = harness.read_json(os.path.join(
-        REPO, "benchmarks", "layers", name + ".json"))
-    module, _, fn = spec["reader"].rpartition(".")
-    return getattr(importlib.import_module(
-        f"benchmarks.readers.{module}"), fn), spec.get("args", {})
+    return manifest_rules.reader(REPO, name)
 
 
 @pytest.mark.parametrize("name", sorted(SPAN_METRICS | SCOPE_METRICS))
@@ -446,7 +434,6 @@ def test_the_readers_read_a_profile_where_there_are_peaks(
     assert read("step.kv_write_ms") == pytest.approx(1e3 * 4.0 / 8)
     assert read("kernel.paged_attention_ms") == pytest.approx(1e3 * 2.0 / 8)
     assert read("kernel.prefill_append_ms") == pytest.approx(1e3 * 1.0)
-    assert read("step.prefill_kv_write_ms") is None   # no such scope there
     assert read("train.flash_attention_ms") is None
     assert read("train.host_gap_ms") is None          # no train.step span
     # 12 slots x 500 tokens x 65 536 B over 819 GB/s over 250 ms
@@ -458,22 +445,7 @@ def test_the_readers_read_a_profile_where_there_are_peaks(
     assert read("device.idle_host_share") == pytest.approx(100 * 5.0 / 20.0)
 
 
-# -- the manifest's rule, for the entries this PR adds --------------------
+# -- the manifest's rule ---------------------------------------------------
 
 def test_each_new_metric_moves_a_metric_its_cells_report():
-    manifest = harness.read_json(os.path.join(REPO, "BENCHMARK.json"))
-    added = {m["name"]: m for m in manifest["per_layer"]
-             if m["name"] in SPAN_METRICS | SCOPE_METRICS}
-    assert set(added) == SPAN_METRICS | SCOPE_METRICS
-    for name, m in added.items():
-        assert m["source"] == ("program_span" if name in SPAN_METRICS
-                               else "device_trace")
-        assert m["workloads"], name
-        for w in m["workloads"]:
-            cell = harness.load_cell(REPO, w)
-            reported = {e["name"] for e in cell.metrics("end_to_end")}
-            assert m["moves"] in reported, (name, w)
-        assert callable(_reader(name)[0])
-        # the tiny checkout sends a metric to its cell by this prefix
-        assert name.startswith("train") == (
-            m["workloads"] == ["mistral-7b.train"])
+    manifest_rules.check_span_and_scope_metrics(REPO)

@@ -51,11 +51,16 @@ def write_json(path: str, obj) -> None:
         json.dump(obj, f, indent=1)
 
 
-def make_checkout(root: str) -> dict:
-    """-> the manifest written to `root/BENCHMARK.json`."""
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+def make_checkout(root: str, source: str = REPO) -> dict:
+    """-> the manifest written to `root/BENCHMARK.json`: `source`'s
+    manifest and per-layer metric files, with the tiny cells in place of
+    its cells. A tiny cell takes the per-layer metrics its real cell
+    lists: the serve cells those of `mistral-7b.steady`, `tiny.job` the
+    `train.*` ones of `mistral-7b.train`."""
+    with open(os.path.join(source, "BENCHMARK.json"),
+              encoding="utf-8") as f:
         manifest = json.load(f)
-    shutil.copytree(os.path.join(REPO, "benchmarks", "layers"),
+    shutil.copytree(os.path.join(source, "benchmarks", "layers"),
                     os.path.join(root, "benchmarks", "layers"))
     write_json(os.path.join(root, "benchmarks/configs/tiny.json"), TINY)
     for name, mix in TRAFFIC.items():
@@ -66,18 +71,27 @@ def make_checkout(root: str) -> dict:
     manifest["workloads"] = [
         {"name": f"tiny.{t}", "config": "tiny", "traffic": t, "chips": 1,
          "why": "CPU rehearsal"} for t in TRAFFIC]
-    for section in ("end_to_end", "per_layer"):
-        for m in manifest[section]:
-            if "workloads" not in m:
-                continue
-            if m["name"].startswith("train"):
-                m["workloads"] = ["tiny.job"]
-            elif m["name"] == "serve_tok_s":
-                # at a fixed rate it is only the offered load: the
-                # open-loop cell reports the two latencies alone
-                m["workloads"] = ["tiny.closed"]
-            else:
-                m["workloads"] = list(SERVE_CELLS)
+    for m in manifest["end_to_end"]:
+        if "workloads" not in m:
+            continue
+        if m["name"].startswith("train"):
+            m["workloads"] = ["tiny.job"]
+        elif m["name"] == "serve_tok_s":
+            # at a fixed rate it is only the offered load: the
+            # open-loop cell reports the latency alone
+            m["workloads"] = ["tiny.closed"]
+        else:
+            m["workloads"] = list(SERVE_CELLS)
+    for m in manifest["per_layer"]:
+        if "workloads" not in m:
+            continue
+        listed = m["workloads"]
+        if "mistral-7b.steady" in listed:
+            m["workloads"] = list(SERVE_CELLS)
+        elif m["name"].startswith("train.") and "mistral-7b.train" in listed:
+            m["workloads"] = ["tiny.job"]
+        else:
+            m["workloads"] = []
     write_json(os.path.join(root, "BENCHMARK.json"), manifest)
     return manifest
 
